@@ -6,7 +6,9 @@ Three sections, one JSON:
     the same synthetic weight tree: wall-clock throughput and peak RSS
     growth (``ru_maxrss`` delta across the measured phase). Each path runs
     in a fresh subprocess (``--_child``) so one path's peak cannot shadow
-    the other's. The streaming path is measured both with fsync group
+    the other's. These are host-memory measurements: the children are
+    pinned to the CPU platform, so they never contend with this process
+    (or another) for an accelerator. The streaming path is measured both with fsync group
     commit (``stream``, the default: fsync every N tensors, manifest only
     advancing after the fsync) and with PR-3's per-tensor fsync
     (``stream_fsync1``) — the delta is the write path's durability
@@ -127,7 +129,7 @@ def _bench_write(rows, log, quick):
     from repro.artifacts.writer import ArtifactWriter
 
     n_kernels, d = (6, 256) if quick else (16, 1024)
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # host RSS, never the chip
     env["PYTHONPATH"] = f"{ROOT / 'src'}" + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     for mode in ("inmem", "stream", "stream_fsync1"):

@@ -285,11 +285,9 @@ def write_artifact(out_dir: str | Path, *, arch: str, model_cfg, ptqtp_cfg,
     ``commit_every`` sets the fsync group-commit size (1 → per-tensor
     durability, default ``ArtifactWriter.DEFAULT_COMMIT_EVERY``).
     """
-    import jax.numpy as jnp
-
     from repro.core import ptqtp as ptqtp_mod
-    from repro.core.quantize_model import (default_predicate,
-                                           dequantize_kernel, quantize_kernel)
+    from repro.core.quantize_model import (default_predicate, quantize_kernel,
+                                           relative_error)
 
     cfg = ptqtp_cfg or ptqtp_mod.PTQTPConfig()
     predicate = predicate or default_predicate
@@ -314,10 +312,7 @@ def write_artifact(out_dir: str | Path, *, arch: str, model_cfg, ptqtp_cfg,
             qk = quantize_kernel(leaf, cfg)
             error = None
             if compute_error:
-                w_hat = dequantize_kernel(qk, jnp.float32)
-                rel = float(jnp.linalg.norm(leaf - w_hat)
-                            / jnp.maximum(jnp.linalg.norm(leaf), 1e-30))
-                error = {"rel_fro_error": rel}
+                error = {"rel_fro_error": float(relative_error(leaf, qk))}
             writer.add_quantized(
                 path, qk, source_shape=tuple(np.shape(leaf)),
                 source_dtype=str(getattr(leaf, "dtype", "float32")),

@@ -144,27 +144,33 @@ def _ridge_solve(t1, t2, w, lam):
     return jnp.stack([alpha1, alpha2], axis=-1), kappa
 
 
-def _trit_search(w, alpha, candidates):
+def _trit_search(w, alpha):
     """Per-element exhaustive search over the 9 ternary pairs (Eq. 5).
+
+    A compare-select chain over ``CANDIDATES`` in order: the strict ``<``
+    keeps the first minimal pair, as an argmin would, while every step
+    stays elementwise (an argmin followed by a table gather runs as a
+    per-element gather on a TPU and dominated quantization time there).
 
     Args:
       w: (R, G) float32.
       alpha: (R, 2) float32.
-      candidates: (9, 2) float32.
     Returns:
       t1, t2: (R, G) float32 in {-1, 0, 1}.
     """
-    # vals[r, m] = alpha1[r]*c1[m] + alpha2[r]*c2[m]
-    vals = alpha @ candidates.T  # (R, 9)
-    err = (w[:, :, None] - vals[:, None, :]) ** 2  # (R, G, 9)
-    best = jnp.argmin(err, axis=-1)  # (R, G)
-    c = jnp.asarray(candidates)
-    t1 = c[best, 0]
-    t2 = c[best, 1]
+    a1, a2 = alpha[:, 0:1], alpha[:, 1:2]
+    best = jnp.full_like(w, jnp.inf)
+    t1 = t2 = jnp.zeros_like(w)
+    for c1, c2 in CANDIDATES.tolist():
+        err = (w - (a1 * c1 + a2 * c2)) ** 2
+        take = err < best
+        best = jnp.where(take, err, best)
+        t1 = jnp.where(take, c1, t1)
+        t2 = jnp.where(take, c2, t2)
     return t1, t2
 
 
-def _trit_search_kernel(w, alpha, candidates):
+def _trit_search_kernel(w, alpha):
     """Same as _trit_search but routed through the Pallas ptqtp_search kernel."""
     from repro.kernels.ptqtp_search import ops as search_ops
 
@@ -190,7 +196,6 @@ def _quantize_grouped(
     """Run Alg. 1/2 on group-rows wg (R, G). Returns (t1, t2, alpha, iters)."""
     wg = wg.astype(jnp.float32)
     R, G = wg.shape
-    cand = jnp.asarray(CANDIDATES)
 
     # Alg. 2 line 2: sign init with 0 -> 1 replacement.
     sgn = jnp.where(wg >= 0.0, 1.0, -1.0)
@@ -213,7 +218,7 @@ def _quantize_grouped(
         )
         alpha, _ = _ridge_solve(t1, t2, wg, lam_new)
         # --- discrete step: 9-candidate exhaustive search (lines 14-21) ---
-        t1n, t2n = search(wg, alpha, cand)
+        t1n, t2n = search(wg, alpha)
         # --- convergence (lines 22-25) ---
         delta = jnp.max(jnp.sqrt(jnp.sum((alpha - alpha_prev) ** 2, axis=-1)))
         converged = delta < eps
@@ -294,7 +299,6 @@ def quantize_with_history(w: jax.Array, cfg: Optional[PTQTPConfig] = None):
     cfg = cfg or PTQTPConfig()
     n, d = w.shape
     wg = _reshape_groups(w.astype(jnp.float32), cfg.group_size)
-    cand = jnp.asarray(CANDIDATES)
 
     sgn = jnp.where(wg >= 0.0, 1.0, -1.0)
     t1, t2 = sgn, sgn
@@ -315,7 +319,7 @@ def quantize_with_history(w: jax.Array, cfg: Optional[PTQTPConfig] = None):
             lam,
         )
         alpha_new, _ = _ridge_solve(t1, t2, wg, lam)
-        t1, t2 = _trit_search(wg, alpha_new, cand)
+        t1, t2 = _trit_search(wg, alpha_new)
         errors.append(err(t1, t2, alpha_new))
         delta = jnp.max(jnp.sqrt(jnp.sum((alpha_new - alpha) ** 2, axis=-1)))
         alpha = alpha_new

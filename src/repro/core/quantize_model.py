@@ -59,12 +59,13 @@ class QuantizedKernel:
 
 def quantize_kernel(kernel: jax.Array, cfg: ptqtp.PTQTPConfig) -> QuantizedKernel:
     """Quantize a (d_in, d_out) kernel; any leading dims (scan-stacked layers,
-    MoE experts — e.g. (L, E, d_in, d_out)) are vmapped over."""
+    MoE experts — e.g. (L, E, d_in, d_out)) are mapped over one matrix at a
+    time, so the quantizer's working set is one matrix's, not the stack's."""
     lead = kernel.shape[:-2]
     d_in, d_out = kernel.shape[-2:]
     if lead:
         flat = kernel.reshape((-1,) + kernel.shape[-2:])
-        t1p, t2p, alpha = jax.vmap(lambda k: _quantize_2d(k, cfg))(flat)
+        t1p, t2p, alpha = jax.lax.map(lambda k: _quantize_2d(k, cfg), flat)
         t1p = t1p.reshape(lead + t1p.shape[1:])
         t2p = t2p.reshape(lead + t2p.shape[1:])
         alpha = alpha.reshape(lead + alpha.shape[1:])
@@ -101,6 +102,25 @@ def dequantize_kernel(qk: QuantizedKernel, dtype=jnp.float32) -> jax.Array:
             qk.alpha.reshape((-1,) + qk.alpha.shape[-3:]))
         return flat.reshape(lead + flat.shape[1:]).astype(dtype)
     return deq(qk.t1p, qk.t2p, qk.alpha).astype(dtype)
+
+
+@jax.jit
+def relative_error(kernel: jax.Array, qk: QuantizedKernel) -> jax.Array:
+    """||W − Ŵ||_F / ||W||_F, summed one matrix at a time so that a stacked
+    kernel's dequantized copy is never whole in device memory."""
+    def sums(args):
+        w, t1p, t2p, alpha = args
+        w = w.astype(jnp.float32)
+        d = w - dequantize_kernel(
+            dataclasses.replace(qk, t1p=t1p, t2p=t2p, alpha=alpha),
+            jnp.float32)
+        return jnp.sum(d * d), jnp.sum(w * w)
+
+    flat = [a.reshape((-1,) + a.shape[a.ndim - k:]) for a, k in
+            ((kernel, 2), (qk.t1p, 2), (qk.t2p, 2), (qk.alpha, 3))]
+    err2, w2 = jax.lax.map(sums, flat)
+    return (jnp.sqrt(jnp.sum(err2))
+            / jnp.maximum(jnp.sqrt(jnp.sum(w2)), 1e-30))
 
 
 def default_predicate(path: str, leaf: Any, group_size: int) -> bool:

@@ -13,7 +13,7 @@ Op contract (stable; ``ops.chunk_attention``)
 
   chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
                   pos_buf, positions, lengths, *, window=None,
-                  backend="auto", tile=None, interpret=None)
+                  backend="auto", tile=None)
       -> (B, L, KV, G, hd) float32
 
 Inputs:
@@ -44,11 +44,11 @@ bitwise on the visible set; floats may reorder):
   covers ring wrap and per-row chunk offsets with no special cases.
 
 Backends:
-  * ``pallas``       — one grid program per (batch, kv-head); the ring
-                       stays int8 in VMEM and is dequantized per ``tile``
-                       on the VPU inside an online-softmax ``fori_loop``
-                       (validated in interpret mode off-TPU, like
-                       ``ternary_matvec_pallas``).
+  * ``pallas``       — grid (batch, kv-head, tile); int8 tiles stream
+                       HBM→VMEM and their scales apply to the scores, with
+                       the online-softmax state carried across the tile
+                       axis in VMEM (run by the Pallas interpreter
+                       off-TPU, like ``ternary_matmul_pallas``).
   * ``stream``       — CPU/XLA fallback: a jitted ``fori_loop`` over
                        fixed-size ring tiles (sliced from the cache in
                        place) carrying running (max, sum, acc) state. Peak
@@ -70,8 +70,7 @@ Paged variant (stable; ``ops.chunk_attention_paged``)
 
   chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
                         pos_pool, table, positions, lengths, *,
-                        window=None, backend="auto", tile=None,
-                        interpret=None)
+                        window=None, backend="auto", tile=None)
       -> (B, L, KV, G, hd) float32
 
 The KV ring virtualized into fixed-size pages: ``k_pool``/``v_pool`` are

@@ -1,23 +1,32 @@
 """Fused int8-KV flash chunk-prefill attention (Pallas, TPU target).
 
-One grid program per (batch, kv-head) — the GQA grouping: all G query
-heads that share a kv head ride in one program, so the int8 ring block is
-read once per kv head, not once per query head. The kernel walks the ring
-in ``tile``-slot chunks with an online-softmax accumulator, dequantizing
-int8→f32 **in-register** per tile (HBM traffic = packed int8 bytes +
-scales + q/chunk/out — the attention analogue of the ternary-matmul
-streaming floor), then folds the chunk's own keys in as a final tile. The
-(G·L, cap) score block never exists: scores live as (G·L, tile) in VMEM.
+Grid (batch, kv-head, tile): one program per ``tile`` ring slots of one
+(batch, kv head) pair. All G query heads that share the kv head ride in
+one program — the GQA grouping — so a KV tile is read once per kv head,
+not once per query head. The tile axis runs in order and carries the
+online-softmax state (running max, sum, accumulator) in VMEM scratch; the
+last tile folds the chunk's own keys in and writes the output. The
+(G·L, cap) score block never exists: scores live as (G·L, tile).
+
+Layout. The wrapper hands the kernel lane-dense views, so every block
+obeys the TPU's (8, 128) rule at serving widths (hd a multiple of 128):
+the cache (B, cap, KV, hd) is viewed as (B, cap, KV·hd) and the BlockSpec
+takes lane block ``kv`` of width hd; int8 scales are transposed to
+(KV·B, 1, cap) rows and applied to the scores and the softmax weights
+(q·(s k) = s (q·k)), so no per-slot column ever needs a lane→sublane
+relayout; positions come in both orientations (queries as a (G·L, 1)
+column, keys as a (1, tile) row) so the mask is a plain broadcast.
+Lengths (and the page table) ride in SMEM as scalar prefetch.
 
 Ring wrap, sliding windows, and right-padding are all mask regions of the
 same rule (see the package docstring): visible iff 0 <= qpos - kpos <
 reach, ring slots additionally pos >= 0, chunk keys additionally
-j < length. Like ``ternary_matvec_pallas`` this is validated in interpret
-mode off-TPU; compiled-TPU runs only reshape leading (sublane) dims.
+j < length.
 
-VMEM budget per program (hd=128, L=64, tile=512): resident int8 ring
-blocks 2·cap·hd B (8 MB at cap=32k) + (G·L, tile) f32 scores — inside the
-~16 MB v5e VMEM; longer rings shard over the mesh first.
+The paged variant is the same kernel over a different index map: tile t
+of row b is read from physical page ``table[b, t // tpp]`` (tile divides
+page_size), so with matching tiles the two walks see the same (k, v,
+mask) sequence and are bit-identical.
 """
 
 from __future__ import annotations
@@ -27,211 +36,196 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_NT = (((1,), (1,)), ((), ()))  # contract the last dim of both operands
 
 
-def _online_update(q2, k, v, valid, m, l, acc):
-    """One online-softmax step. q2: (G·L, hd); k/v: (C, hd) f32;
-    valid: (G·L, C) bool; carry m/l: (G·L,), acc: (G·L, hd)."""
-    logits = jnp.dot(q2, k.T, preferred_element_type=jnp.float32)
-    logits = jnp.where(valid, logits, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+def _online_update(q2, k, v, valid, ks, vs, m, l, acc):
+    """One online-softmax step. q2: (G·L, hd) pre-scaled f32; k/v:
+    (C, hd); valid: (G·L, C) bool; ks/vs: (1, C) per-key scales or None;
+    carry m/l: (G·L, 1), acc: (G·L, hd)."""
+    s = jax.lax.dot_general(q2, k.astype(jnp.float32), _NT,
+                            preferred_element_type=jnp.float32)
+    if ks is not None:
+        s = s * ks
+    s = jnp.where(valid, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m - m_new)
     # explicit re-mask: when a row has seen nothing yet (m_new == NEG_INF)
     # the subtraction cancels and exp() would emit 1s for masked slots
-    p = jnp.where(valid, jnp.exp(logits - m_new[:, None]), 0.0)
-    acc = acc * alpha[:, None] + jnp.dot(p, v,
-                                         preferred_element_type=jnp.float32)
-    l = l * alpha + jnp.sum(p, axis=-1)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    pv = p if vs is None else p * vs
+    acc = acc * alpha + jnp.dot(pv, v.astype(jnp.float32),
+                                preferred_element_type=jnp.float32)
+    l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
     return m_new, l, acc
 
 
-def _kernel(q_ref, kn_ref, vn_ref, k8_ref, ks_ref, v8_ref, vs_ref,
-            posb_ref, pos_ref, len_ref, o_ref, *, tile: int, scale: float,
-            reach: int, scaled: bool):
-    # block shapes carry leading singleton (batch, kv) dims — index them away
-    g, L, hd = q_ref.shape[-3:]
-    cap = k8_ref.shape[1]
+def _kernel(len_ref, *refs, paged: bool, scale: float, reach: int,
+            scaled: bool):
+    if paged:
+        refs = refs[1:]  # the page table is only read by the index maps
+    (q_ref, kn_ref, vn_ref, k_ref, ks_ref, v_ref, vs_ref, pb_ref, qp_ref,
+     kp_ref, o_ref, m_sc, l_sc, acc_sc) = refs
+    t = pl.program_id(2)
+    length = len_ref[pl.program_id(0)]
+    last = pl.num_programs(2) - 1
+
+    @pl.when(t == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    q2 = q_ref[0, 0].astype(jnp.float32) * scale             # (G·L, hd)
+    qpos = qp_ref[0]                                         # (G·L, 1)
+    pb = pb_ref[0]                                           # (1, tile)
+    d = qpos - pb
+    valid = (pb >= 0) & (d >= 0) & (d < reach)
+    ks, vs = (ks_ref[0], vs_ref[0]) if scaled else (None, None)
+    m, l, acc = _online_update(q2, k_ref[0], v_ref[0], valid, ks, vs,
+                               m_sc[...], l_sc[...], acc_sc[...])
+    m_sc[...], l_sc[...], acc_sc[...] = m, l, acc
+
+    @pl.when(t == last)
+    def _finish():
+        # the chunk's own keys: one final (G·L, L) tile at activation
+        # precision
+        kpos = kp_ref[0]                                     # (1, L)
+        jidx = jax.lax.broadcasted_iota(jnp.int32, kpos.shape, 1)
+        dn = qpos - kpos
+        valid_n = (jidx < length) & (dn >= 0) & (dn < reach)
+        _, ln, accn = _online_update(q2, kn_ref[0], vn_ref[0], valid_n,
+                                     None, None, m_sc[...], l_sc[...],
+                                     acc_sc[...])
+        o_ref[0, 0] = accn / jnp.maximum(ln, 1e-30)          # 0s if unseen
+
+
+def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, *,
+          window, tile, cap, page_table, interpret):
+    """Shared pallas_call. ``kc``/``vc`` are lane-dense (rows, slots,
+    KV·hd) views, ``ks``/``vs`` (rows·KV, 1, slots) scale rows (None for
+    float caches) and ``pos`` (rows, 1, slots); rows are batch rows for
+    the ring and physical pages for the paged pool, where ``page_table``
+    (B, n_pages) maps tile t of row b to its page."""
+    b, kv, g, L, hd = q.shape
     n_tiles = cap // tile
-    q2 = (q_ref[0, 0].astype(jnp.float32) * scale).reshape(g * L, hd)
-    qpos = pos_ref[0]                                        # (L,)
-    length = len_ref[0]
+    reach = min(window, cap) if window else cap
+    scaled = ks is not None
+    paged = page_table is not None
 
-    def ring_tile(i, carry):
-        off = i * tile
-        k = k8_ref[0, pl.dslice(off, tile), 0, :].astype(jnp.float32)
-        v = v8_ref[0, pl.dslice(off, tile), 0, :].astype(jnp.float32)
-        if scaled:  # int8 ring: per-(slot, kv-head) absmax in-reg dequant
-            k = k * ks_ref[0, pl.dslice(off, tile), 0][:, None]
-            v = v * vs_ref[0, pl.dslice(off, tile), 0][:, None]
-        pb = posb_ref[0, pl.dslice(off, tile)]
-        d = qpos[:, None] - pb[None, :]                      # (L, tile)
-        valid = (pb[None, :] >= 0) & (d >= 0) & (d < reach)
-        validg = jnp.broadcast_to(valid[None], (g, L, tile)).reshape(
-            g * L, tile)
-        return _online_update(q2, k, v, validg, *carry)
+    rows = kc.shape[0]
+    if paged:
+        tpp = kc.shape[1] // tile                            # tiles per page
 
-    m0 = jnp.full((g * L,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g * L,), jnp.float32)
-    acc0 = jnp.zeros((g * L, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_tiles, ring_tile, (m0, l0, acc0))
+        def slot_block(i, t, tbl):
+            return tbl[i, t // tpp], t % tpp
+    else:
+        def slot_block(i, t, tbl):
+            return i, t
 
-    # the chunk's own keys: one final (G·L, L) tile at activation precision
-    kn = kn_ref[0, :, 0, :].astype(jnp.float32)              # (L, hd)
-    vn = vn_ref[0, :, 0, :].astype(jnp.float32)
-    jidx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    d = qpos[:, None] - qpos[None, :]
-    valid = (jidx < length) & (d >= 0) & (d < reach)
-    validg = jnp.broadcast_to(valid[None], (g, L, L)).reshape(g * L, L)
-    m, l, acc = _online_update(q2, kn, vn, validg, m, l, acc)
+    def scale_index(i, j, t, _lens, tbl=None):
+        row, off = slot_block(i, t, tbl)
+        return j * rows + row, 0, off
 
-    out = acc / jnp.maximum(l, 1e-30)[:, None]               # 0s if unseen
-    o_ref[0, 0] = out.reshape(g, L, hd)
+    def kv_index(i, j, t, _lens, tbl=None):
+        row, off = slot_block(i, t, tbl)
+        return row, off, j
+
+    def pos_index(i, j, t, _lens, tbl=None):
+        row, off = slot_block(i, t, tbl)
+        return row, 0, off
+
+    def per_row(i, j, t, *_):
+        return i, 0, 0
+
+    if scaled:
+        scale_spec = pl.BlockSpec((1, 1, tile), scale_index)
+    else:  # float cache: 1-entry placeholders, never read
+        ks = vs = jnp.ones((1, 1, 1), jnp.float32)
+        scale_spec = pl.BlockSpec((1, 1, 1), lambda *_: (0, 0, 0))
+    head_spec = pl.BlockSpec((1, 1, g * L, hd),
+                             lambda i, j, t, *_: (i, j, 0, 0))
+    new_spec = pl.BlockSpec((1, L, hd), lambda i, j, t, *_: (i, 0, j))
+    kv_spec = pl.BlockSpec((1, tile, hd), kv_index)
+
+    prefetch = (lengths,) + ((page_table,) if paged else ())
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, kv, n_tiles),
+        in_specs=[
+            head_spec,                                       # q
+            new_spec, new_spec,                              # k_new, v_new
+            kv_spec, scale_spec, kv_spec, scale_spec,        # k, ks, v, vs
+            pl.BlockSpec((1, 1, tile), pos_index),           # slot positions
+            pl.BlockSpec((1, g * L, 1), per_row),            # query positions
+            pl.BlockSpec((1, 1, L), per_row),                # chunk keys' pos
+        ],
+        out_specs=head_spec,
+        scratch_shapes=[pltpu.VMEM((g * L, 1), jnp.float32),
+                        pltpu.VMEM((g * L, 1), jnp.float32),
+                        pltpu.VMEM((g * L, hd), jnp.float32)],
+    )
+    kern = functools.partial(_kernel, paged=paged, scale=hd ** -0.5,
+                             reach=reach, scaled=scaled)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, g * L, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, q.reshape(b, kv, g * L, hd),
+      k_new.reshape(b, L, kv * hd), v_new.reshape(b, L, kv * hd),
+      kc, ks, vc, vs, pos,
+      jnp.tile(positions, (1, g)).reshape(b, g * L, 1),     # row h·L + l
+      positions.reshape(b, 1, L))
+    return out.reshape(b, kv, g, L, hd)
 
 
-def _paged_kernel(q_ref, kn_ref, vn_ref, kp_ref, ks_ref, vp_ref, vs_ref,
-                  posp_ref, pt_ref, pos_ref, len_ref, o_ref, *, tile: int,
-                  scale: float, reach: int, scaled: bool):
-    """Page-gather variant of ``_kernel``: same grid (one program per
-    (batch, kv-head)), same online-softmax walk over *logical* tiles, but
-    each tile is loaded through the page table with a dynamic page index
-    (``pl.dslice`` start) instead of a contiguous ring offset. Tile divides
-    page_size, so a tile never spans two physical pages. The mask rule is
-    untouched — positions come from the gathered pos page, so ring wrap,
-    windows, and the null page (pos ≡ -1) all fall out of the one rule.
-    """
-    g, L, hd = q_ref.shape[-3:]
-    ps = kp_ref.shape[1]
-    n_pages = pt_ref.shape[1]
-    tpp = ps // tile
-    q2 = (q_ref[0, 0].astype(jnp.float32) * scale).reshape(g * L, hd)
-    qpos = pos_ref[0]                                        # (L,)
-    length = len_ref[0]
-
-    def page_tile(i, carry):
-        pidx = i // tpp
-        off = (i % tpp) * tile
-        pid = pt_ref[0, pl.dslice(pidx, 1)][0]
-        k = kp_ref[pl.dslice(pid, 1), pl.dslice(off, tile), 0, :][0]
-        v = vp_ref[pl.dslice(pid, 1), pl.dslice(off, tile), 0, :][0]
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-        if scaled:  # int8 pages: per-(entry, kv-head) absmax in-reg dequant
-            k = k * ks_ref[pl.dslice(pid, 1), pl.dslice(off, tile), 0][0][:, None]
-            v = v * vs_ref[pl.dslice(pid, 1), pl.dslice(off, tile), 0][0][:, None]
-        pb = posp_ref[pl.dslice(pid, 1), pl.dslice(off, tile)][0]
-        d = qpos[:, None] - pb[None, :]                      # (L, tile)
-        valid = (pb[None, :] >= 0) & (d >= 0) & (d < reach)
-        validg = jnp.broadcast_to(valid[None], (g, L, tile)).reshape(
-            g * L, tile)
-        return _online_update(q2, k, v, validg, *carry)
-
-    m0 = jnp.full((g * L,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g * L,), jnp.float32)
-    acc0 = jnp.zeros((g * L, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_pages * tpp, page_tile,
-                                  (m0, l0, acc0))
-
-    # the chunk's own keys: identical to the contiguous kernel's final tile
-    kn = kn_ref[0, :, 0, :].astype(jnp.float32)              # (L, hd)
-    vn = vn_ref[0, :, 0, :].astype(jnp.float32)
-    jidx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    d = qpos[:, None] - qpos[None, :]
-    valid = (jidx < length) & (d >= 0) & (d < reach)
-    validg = jnp.broadcast_to(valid[None], (g, L, L)).reshape(g * L, L)
-    m, l, acc = _online_update(q2, kn, vn, validg, m, l, acc)
-
-    out = acc / jnp.maximum(l, 1e-30)[:, None]               # 0s if unseen
-    o_ref[0, 0] = out.reshape(g, L, hd)
+def _scale_rows(scale):
+    """(rows, slots, KV) per-slot scales → (KV·rows, 1, slots) lane rows,
+    kv-major (row j·rows + r holds kv head j of ring row / page r)."""
+    if scale is None:
+        return None
+    r, s, kv = scale.shape
+    return scale.transpose(2, 0, 1).reshape(kv * r, 1, s).astype(jnp.float32)
 
 
 def chunk_attention_paged_pallas(q, k_new, v_new, k_pool, k_scale, v_pool,
                                  v_scale, pos_pool, table, positions,
-                                 lengths, *, window=None, tile: int = 512,
-                                 interpret: bool = True):
+                                 lengths, *, window=None, tile: int,
+                                 interpret: bool):
     """Paged Pallas chunk attention. q is (B, KV, G, L, hd) (grid layout);
     the public op transposes. Pools are (P, page_size, KV, hd) with
     (P, page_size, KV) scales (int8) or scales None (float); table is
-    (B, n_pages) physical page ids. Returns (B, KV, G, L, hd) f32.
+    (B, n_pages) physical page ids. ``tile`` divides page_size. Returns
+    (B, KV, G, L, hd) f32.
     """
     P, ps, kv, hd = k_pool.shape
-    b, n_pages = table.shape
-    g, L = q.shape[2], q.shape[3]
-    cap = n_pages * ps
-    t = min(tile, ps)
-    while ps % t:
-        t -= 1
-    reach = min(window, cap) if window else cap
-    scale = hd ** -0.5
-    scaled = k_scale is not None
-    if not scaled:  # float pages: 1-entry placeholder refs, never read
-        k_scale = v_scale = jnp.ones((1, 1, kv), jnp.float32)
-    sP, sps = (P, ps) if scaled else (1, 1)
-
-    kern = functools.partial(_paged_kernel, tile=t, scale=scale, reach=reach,
-                             scaled=scaled)
-    return pl.pallas_call(
-        kern,
-        grid=(b, kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, L, hd), lambda i, j: (i, j, 0, 0, 0)),  # q
-            pl.BlockSpec((1, L, 1, hd), lambda i, j: (i, 0, j, 0)),   # k_new
-            pl.BlockSpec((1, L, 1, hd), lambda i, j: (i, 0, j, 0)),   # v_new
-            pl.BlockSpec((P, ps, 1, hd), lambda i, j: (0, 0, j, 0)),  # k pool
-            pl.BlockSpec((sP, sps, 1), lambda i, j: (0, 0, j)),       # ks
-            pl.BlockSpec((P, ps, 1, hd), lambda i, j: (0, 0, j, 0)),  # v pool
-            pl.BlockSpec((sP, sps, 1), lambda i, j: (0, 0, j)),       # vs
-            pl.BlockSpec((P, ps), lambda i, j: (0, 0)),               # pos pool
-            pl.BlockSpec((1, n_pages), lambda i, j: (i, 0)),          # table
-            pl.BlockSpec((1, L), lambda i, j: (i, 0)),                # positions
-            pl.BlockSpec((1,), lambda i, j: (i,)),                    # lengths
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, L, hd), lambda i, j: (i, j, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, L, hd), jnp.float32),
-        interpret=interpret,
-    )(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale, pos_pool,
-      table.astype(jnp.int32), positions, lengths)
+    n_pages = table.shape[1]
+    assert ps % tile == 0, (ps, tile)
+    return _call(q, k_new, v_new, k_pool.reshape(P, ps, kv * hd),
+                 _scale_rows(k_scale), v_pool.reshape(P, ps, kv * hd),
+                 _scale_rows(v_scale), pos_pool.reshape(P, 1, ps),
+                 positions, lengths, window=window, tile=tile,
+                 cap=n_pages * ps, page_table=table.astype(jnp.int32),
+                 interpret=interpret)
 
 
 def chunk_attention_pallas(q, k_new, v_new, k_cache, k_scale, v_cache,
                            v_scale, pos_buf, positions, lengths, *,
-                           window=None, tile: int = 512,
-                           interpret: bool = True):
+                           window=None, tile: int, interpret: bool):
     """Pallas chunk attention. q here is (B, KV, G, L, hd) (grid layout);
-    the public op transposes. Returns (B, KV, G, L, hd) f32.
+    the public op transposes. ``tile`` divides cap. Returns
+    (B, KV, G, L, hd) f32.
     """
     b, cap, kv, hd = k_cache.shape
-    g, L = q.shape[2], q.shape[3]
-    t = min(tile, cap)
-    while cap % t:
-        t -= 1
-    reach = min(window, cap) if window else cap
-    scale = hd ** -0.5
-    scaled = k_scale is not None
-    if not scaled:  # float ring: 1-slot placeholder refs, never read
-        k_scale = v_scale = jnp.ones((b, 1, kv), jnp.float32)
-    scap = cap if scaled else 1
-
-    kern = functools.partial(_kernel, tile=t, scale=scale, reach=reach,
-                             scaled=scaled)
-    return pl.pallas_call(
-        kern,
-        grid=(b, kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, L, hd), lambda i, j: (i, j, 0, 0, 0)),  # q
-            pl.BlockSpec((1, L, 1, hd), lambda i, j: (i, 0, j, 0)),   # k_new
-            pl.BlockSpec((1, L, 1, hd), lambda i, j: (i, 0, j, 0)),   # v_new
-            pl.BlockSpec((1, cap, 1, hd), lambda i, j: (i, 0, j, 0)), # k8
-            pl.BlockSpec((1, scap, 1), lambda i, j: (i, 0, j)),       # ks
-            pl.BlockSpec((1, cap, 1, hd), lambda i, j: (i, 0, j, 0)), # v8
-            pl.BlockSpec((1, scap, 1), lambda i, j: (i, 0, j)),       # vs
-            pl.BlockSpec((1, cap), lambda i, j: (i, 0)),              # pos_buf
-            pl.BlockSpec((1, L), lambda i, j: (i, 0)),                # positions
-            pl.BlockSpec((1,), lambda i, j: (i,)),                    # lengths
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, L, hd), lambda i, j: (i, j, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, L, hd), jnp.float32),
-        interpret=interpret,
-    )(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale, pos_buf,
-      positions, lengths)
+    assert cap % tile == 0, (cap, tile)
+    return _call(q, k_new, v_new, k_cache.reshape(b, cap, kv * hd),
+                 _scale_rows(k_scale), v_cache.reshape(b, cap, kv * hd),
+                 _scale_rows(v_scale), pos_buf.reshape(b, 1, cap),
+                 positions, lengths, window=window, tile=tile, cap=cap,
+                 page_table=None, interpret=interpret)

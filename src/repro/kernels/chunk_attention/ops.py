@@ -2,7 +2,9 @@
 
 Backends (see the package docstring for the full contract):
   * ``auto``         — ``pallas`` on TPU, ``stream`` elsewhere.
-  * ``pallas``       — the fused TPU kernel (interpret mode off-TPU).
+  * ``pallas``       — the fused TPU kernel, compiled on TPU and run by the
+                       Pallas interpreter elsewhere (the platform alone
+                       decides).
   * ``stream``       — XLA fallback: a jitted ``fori_loop`` over
                        fixed-size ring tiles carrying running (max, sum,
                        acc) online-softmax state; peak attention
@@ -239,8 +241,7 @@ def chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
                     pos_buf, positions, lengths, *,
                     window: Optional[int] = None,
                     backend: str = DEFAULT_BACKEND,
-                    tile: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    tile: Optional[int] = None):
     """Chunk-prefill attention vs (pre-write ring ∪ in-chunk keys).
 
     Shapes/masks: package docstring. Returns (B, L, KV, G, hd) float32.
@@ -264,13 +265,11 @@ def chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
         return _stream(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
                        pos_buf, positions, lengths, window=window, tile=t)
     if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         out = chunk_attention_pallas(
             q.transpose(0, 2, 3, 1, 4), k_new, v_new, k_cache, k_scale,
             v_cache, v_scale, pos_buf, positions,
             lengths.astype(jnp.int32), window=window, tile=t,
-            interpret=interpret)
+            interpret=jax.default_backend() != "tpu")
         return out.transpose(0, 3, 1, 2, 4)
     raise ValueError(f"unknown chunk-attention backend {backend!r}")
 
@@ -279,8 +278,7 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
                           pos_pool, table, positions, lengths, *,
                           window: Optional[int] = None,
                           backend: str = DEFAULT_BACKEND,
-                          tile: Optional[int] = None,
-                          interpret: Optional[bool] = None):
+                          tile: Optional[int] = None):
     """Chunk attention over a *paged* ring: identical semantics to
     ``chunk_attention`` on the virtual ring ``ref.gather_pages(pool,
     table)`` defines (mask rule unchanged, expressed in logical positions
@@ -323,12 +321,10 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
                              v_scale, pos_pool, table, positions, lengths,
                              window=window, tile=t)
     if backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         out = chunk_attention_paged_pallas(
             q.transpose(0, 2, 3, 1, 4), k_new, v_new, k_pool, k_scale,
             v_pool, v_scale, pos_pool, table, positions,
             lengths.astype(jnp.int32), window=window, tile=t,
-            interpret=interpret)
+            interpret=jax.default_backend() != "tpu")
         return out.transpose(0, 3, 1, 2, 4)
     raise ValueError(f"unknown chunk-attention backend {backend!r}")

@@ -62,7 +62,7 @@ def _kernel(q_ref, k8_ref, ks_ref, v8_ref, vs_ref, posb_ref, pos_ref,
 
 def decode_attention_pallas(q, k8, k_scale, v8, v_scale, pos_buf, pos, *,
                             window=None, chunk: int = 512,
-                            interpret: bool = True):
+                            interpret: bool):
     """Same contract as ref.decode_attention_ref; returns (B, KV, G, hd) f32.
 
     Grid (B, KV); per-program blocks: q (G, hd), cache (S, hd) int8 + (S,)

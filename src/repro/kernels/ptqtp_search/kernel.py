@@ -55,7 +55,7 @@ def ptqtp_search_pallas(
     alpha: jax.Array,
     *,
     block_rows: int = 256,
-    interpret: bool = False,
+    interpret: bool,
 ):
     """Fused trit search. w: (R, G); alpha: (R, 2) -> (t1, t2) f32 (R, G)."""
     r, g = w.shape

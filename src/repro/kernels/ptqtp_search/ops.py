@@ -1,4 +1,5 @@
-"""Jitted wrapper for the fused trit-search kernel (CPU: interpret mode)."""
+"""Jitted wrapper for the fused trit-search kernel: compiled on TPU, run by
+the Pallas interpreter elsewhere (the platform alone decides)."""
 
 from __future__ import annotations
 
@@ -7,6 +8,7 @@ import jax
 from repro.kernels.ptqtp_search.kernel import ptqtp_search_pallas
 
 
-def ptqtp_search(w: jax.Array, alpha: jax.Array, *, interpret: bool = True):
+def ptqtp_search(w: jax.Array, alpha: jax.Array):
     """(t1, t2) f32 planes for group-rows w (R, G) and scales alpha (R, 2)."""
-    return ptqtp_search_pallas(w, alpha, interpret=interpret)
+    return ptqtp_search_pallas(w, alpha,
+                               interpret=jax.default_backend() != "tpu")

@@ -1,42 +1,29 @@
-"""Pallas TPU kernels: fused unpack + grouped ternary matmul + per-group scale.
+"""Pallas TPU kernel: fused unpack + grouped ternary matmul + per-group scale.
 
 TPU adaptation of PTQTP's multiplication-free inference (DESIGN.md §2):
 packed 2-bit trit-planes stream HBM→VMEM (0.5 B/weight instead of 2 B),
-are unpacked with shifts/masks on the VPU, promoted to the activation dtype
-and fed to the MXU; the per-group α pair scales the partial sums before
-accumulation.
+are unpacked with shifts/masks on the VPU, scaled by their group's α pair
+and fed to the MXU in the activation dtype.
 
-Two variants share the unpack helper:
+Layout. Byte b of a packed row holds trits 4b..4b+3 (field f = bits
+2f..2f+1, see ``core/packing.py``), so unpacking a (bn, K) byte block
+field by field yields four lane-dense (bn, K) planes: field f carries
+weight columns 4b+f. Instead of interleaving those planes back into
+column order (a minor-dim reshape the TPU compiler refuses), the wrapper
+permutes the *activations* once per call, ``xp[f, :, b] = x[:, 4b+f]``,
+and the kernel sums four matmuls:
 
-``ternary_matmul_pallas`` — the prefill/training tile kernel.
-Grid layout: (M // bm, N // bn, D // G)  — the k axis steps one weight group
-(G = 128 = MXU tile edge) at a time, so each k step is exactly one scaled
-MXU pass per plane:
+    y = Σ_f  xp[f] @ (α¹ ∘ T¹_f + α² ∘ T²_f)ᵀ
 
-    acc += (x_g @ T¹_gᵀ) * α¹[:, g]  +  (x_g @ T²_gᵀ) * α²[:, g]
+Group g covers bytes [g·G/4, (g+1)·G/4) of a row, so the per-byte scales
+α^p[:, b] = α^p[:, b // (G/4)] are an exact 0/1 matmul of the row's
+(bn, 2·d/G) scale block with an expansion matrix built once in VMEM.
 
-BlockSpecs keep the working set in VMEM:
-  x      (bm, G)        activations tile
-  t1p/t2p(bn, G // 4)   packed trits (uint8)
-  alpha  (bn, 1, 2)     group scales
-  out    (bm, bn)       f32 accumulator (revisited across k steps)
-
-``ternary_matvec_pallas`` — the decode fast path (m < 128).  Decode batches
-are a handful of rows, so padding m to a 128-row tile wastes ≥ 96% of every
-MXU pass and the two-passes-per-plane schedule doubles the weight traffic's
-compute shadow.  The small-m kernel instead:
-
-  * keeps all m rows resident in VMEM for the whole kernel (no m padding,
-    no m grid axis);
-  * fuses both trit-planes into a *single* MXU pass per k step by
-    concatenating T¹/T² along the n axis and folding the α pair into one
-    (2·bn,) scale vector:
-
-        p = x_g @ [T¹_g ; T²_g]ᵀ            # one (m, 2·bn) pass
-        acc += (p ∘ [α¹ ; α²])[:, :bn] + (p ∘ [α¹ ; α²])[:, bn:]
-
-  * accumulates in a VMEM scratch ref and writes the output block exactly
-    once (the tile kernel revisits its HBM-backed output block every k step).
+Every block spans the whole contraction (d/4 bytes), which is what the
+TPU's block-shape rule allows for any d (d/4 need not be a multiple of
+128): grid (m / bm, n / bn), one output block written once.
+``block_m = m`` keeps a decode batch resident with no padding to MXU
+tiles; ``ops._select_tiles`` picks the blocks.
 """
 
 from __future__ import annotations
@@ -48,40 +35,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _unpack_block(packed_i32, bn: int, g: int):
-    """(bn, G//4) int32 packed bytes -> (bn, G) f32 trits in {-1,0,1}."""
-    fields = [(packed_i32 >> (2 * i)) & 0x3 for i in range(4)]
-    # field: 0 -> 0, 1 -> +1, 2 -> -1
-    trits = [
-        (f == 1).astype(jnp.float32) - (f == 2).astype(jnp.float32) for f in fields
-    ]
-    stacked = jnp.stack(trits, axis=-1)  # (bn, G//4, 4): trit j = byte j//4 field j%4
-    return stacked.reshape(bn, g)
+_NT = (((1,), (1,)), ((), ()))  # contract the last dim of both operands
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def _ternary_matmul_kernel(x_ref, t1_ref, t2_ref, a_ref, o_ref, *, bm, bn, g,
-                           acc_dtype):
-    k = pl.program_id(2)
+def _trits(packed_i32, field: int):
+    """Field ``field`` of every packed byte as f32 trits: 1 → +1, 2 → −1."""
+    f = (packed_i32 >> (2 * field)) & 0x3
+    return ((f & 1) - (f >> 1)).astype(jnp.float32)
 
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(acc_dtype)                      # (bm, G)
-    t1 = _unpack_block(t1_ref[...].astype(jnp.int32), bn, g).astype(acc_dtype)
-    t2 = _unpack_block(t2_ref[...].astype(jnp.int32), bn, g).astype(acc_dtype)
-    a = a_ref[...].astype(acc_dtype)                      # (bn, 1, 2)
-    a1 = a[:, 0, 0]                                       # (bn,)
-    a2 = a[:, 0, 1]
+def _ternary_kernel(x_ref, t1_ref, t2_ref, a_ref, o_ref, e_ref, *, gb):
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _build_expansion():
+        # e[p][2g + p, b] = 1 iff byte b lies in group g: scale column
+        # 2g + p of a row's (α¹, α²) pairs lands on that group's bytes
+        r = jax.lax.broadcasted_iota(jnp.int32, e_ref.shape[1:], 0)
+        b = jax.lax.broadcasted_iota(jnp.int32, e_ref.shape[1:], 1)
+        lo = (r >> 1) * gb
+        in_group = (b >= lo) & (b < lo + gb)
+        for p in range(2):
+            e_ref[p] = jnp.where(in_group & ((r & 1) == p), 1.0, 0.0)
 
-    p1 = jax.lax.dot_general(
-        x, t1, (((1,), (1,)), ((), ())), preferred_element_type=acc_dtype
-    )                                                     # (bm, bn)
-    p2 = jax.lax.dot_general(
-        x, t2, (((1,), (1,)), ((), ())), preferred_element_type=acc_dtype
-    )
-    o_ref[...] += p1 * a1[None, :] + p2 * a2[None, :]
+    a = a_ref[...].astype(jnp.float32)                  # (bn, 2·ng)
+    hi = jax.lax.Precision.HIGHEST                      # exact α copies
+    a1 = jnp.dot(a, e_ref[0], precision=hi,
+                 preferred_element_type=jnp.float32)    # (bn, d/4)
+    a2 = jnp.dot(a, e_ref[1], precision=hi,
+                 preferred_element_type=jnp.float32)
+    p1 = t1_ref[...].astype(jnp.int32)
+    p2 = t2_ref[...].astype(jnp.int32)
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for f in range(4):
+        w = (_trits(p1, f) * a1 + _trits(p2, f) * a2).astype(x_ref.dtype)
+        acc += jax.lax.dot_general(x_ref[f], w, _NT,
+                                   preferred_element_type=jnp.float32)
+    o_ref[...] = acc
 
 
 @functools.partial(
@@ -94,127 +83,53 @@ def ternary_matmul_pallas(
     t2p: jax.Array,
     alpha: jax.Array,
     *,
-    group_size: int = 128,
-    block_m: int = 128,
-    block_n: int = 128,
-    interpret: bool = False,
+    group_size: int,
+    block_m: int,
+    block_n: int,
+    interpret: bool,
 ) -> jax.Array:
     """y = x @ Ŵᵀ from packed trit-planes.
 
     Args:
-      x:     (m, d) activations (f32/bf16).
+      x:     (m, d) activations (f32/bf16); the MXU runs in this dtype.
       t1p:   (n, d // 4) uint8 packed plane 1.
       t2p:   (n, d // 4) uint8 packed plane 2.
-      alpha: (n, d // group_size, 2) f32.
+      alpha: (n, d // group_size, 2) float scales.
+      block_m / block_n: must divide m / n.
     Returns:
       (m, n) f32.
     """
     m, d = x.shape
     n = t1p.shape[0]
     g = group_size
-    assert d % g == 0, (d, g)
+    assert d % g == 0 and g % 4 == 0, (d, g)
     assert t1p.shape == (n, d // 4)
     assert alpha.shape == (n, d // g, 2)
-
-    bm = min(block_m, m)
-    bn = min(block_n, n)
+    bm, bn = block_m, block_n
     assert m % bm == 0 and n % bn == 0, (m, bm, n, bn)
+    ng, db = d // g, d // 4
 
-    grid = (m // bm, n // bn, d // g)
-    kernel = functools.partial(
-        _ternary_matmul_kernel, bm=bm, bn=bn, g=g, acc_dtype=jnp.float32
-    )
+    xp = x.reshape(m, db, 4).transpose(2, 0, 1)         # (4, m, d/4)
+    a = alpha.reshape(n, 2 * ng)                        # free: row-major
+    kernel = functools.partial(_ternary_kernel, gb=g // 4)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(m // bm, n // bn),
         in_specs=[
-            pl.BlockSpec((bm, g), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bn, g // 4), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bn, g // 4), lambda i, j, k: (j, k)),
-            pl.BlockSpec((bn, 1, 2), lambda i, j, k: (j, k, 0)),
+            pl.BlockSpec((4, bm, db), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((bn, db), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn, db), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn, 2 * ng), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, 2 * ng, db), jnp.float32)],
+        # the expansion scratch is built at the first grid step and read by
+        # every later one, so the grid must run in order on one core; a
+        # d = 8960 prefill block needs ~20 MB of VMEM, past the 16 MB
+        # default scoped limit (a v5e core has 128 MiB)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(x, t1p, t2p, alpha)
-
-
-# ---------------------------------------------------------------------------
-# decode fast path: small-m fused kernel
-# ---------------------------------------------------------------------------
-
-def _ternary_matvec_kernel(x_ref, t1_ref, t2_ref, a_ref, o_ref, acc_ref, *,
-                           bn, g):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[...].astype(jnp.float32)                       # (m, G)
-    t1 = _unpack_block(t1_ref[...].astype(jnp.int32), bn, g)
-    t2 = _unpack_block(t2_ref[...].astype(jnp.int32), bn, g)
-    tcat = jnp.concatenate([t1, t2], axis=0)                 # (2·bn, G)
-    a = a_ref[...].astype(jnp.float32)                       # (bn, 1, 2)
-    scale = jnp.concatenate([a[:, 0, 0], a[:, 0, 1]], axis=0)  # (2·bn,)
-
-    # One MXU pass covers both planes; α folds in on the VPU afterwards.
-    p = jax.lax.dot_general(
-        x, tcat, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale[None, :]                                       # (m, 2·bn)
-    acc_ref[...] += p[:, :bn] + p[:, bn:]
-
-    @pl.when(k == pl.num_programs(1) - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("group_size", "block_n", "interpret")
-)
-def ternary_matvec_pallas(
-    x: jax.Array,
-    t1p: jax.Array,
-    t2p: jax.Array,
-    alpha: jax.Array,
-    *,
-    group_size: int = 128,
-    block_n: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """Decode-shape y = x @ Ŵᵀ for small m (no padding of m to MXU tiles).
-
-    Args:
-      x:     (m, d) activations, m < 128 (decode batch).
-      t1p:   (n, d // 4) uint8 packed plane 1.
-      t2p:   (n, d // 4) uint8 packed plane 2.
-      alpha: (n, d // group_size, 2) f32.
-    Returns:
-      (m, n) f32.
-    """
-    m, d = x.shape
-    n = t1p.shape[0]
-    g = group_size
-    assert d % g == 0, (d, g)
-    assert t1p.shape == (n, d // 4)
-    assert alpha.shape == (n, d // g, 2)
-
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
-
-    grid = (n // bn, d // g)  # k innermost: the scratch acc stays live per j
-    kernel = functools.partial(_ternary_matvec_kernel, bn=bn, g=g)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((m, g), lambda j, k: (0, k)),
-            pl.BlockSpec((bn, g // 4), lambda j, k: (j, k)),
-            pl.BlockSpec((bn, g // 4), lambda j, k: (j, k)),
-            pl.BlockSpec((bn, 1, 2), lambda j, k: (j, k, 0)),
-        ],
-        out_specs=pl.BlockSpec((m, bn), lambda j, k: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
-        interpret=interpret,
-    )(x, t1p, t2p, alpha)
+    )(xp, t1p, t2p, a)

@@ -2,14 +2,12 @@
 
 Backends:
   * ``auto``    — platform-aware selection (the default): the Pallas hand
-                  kernel compiled on TPU, the XLA ``grouped`` path elsewhere
-                  (Pallas cannot lower for the CPU host platform; interpret
-                  mode is for validation only, never for serving).
-  * ``pallas``  — the fused TPU kernels.  Decode batches (m < 128) take the
-                  small-m fast path (`ternary_matvec_pallas`): no padding of
-                  m to MXU tiles, both trit-planes fused into a single MXU
-                  pass per k step, VMEM scratch accumulation.  Larger m uses
-                  the 128-aligned tile kernel.
+                  kernel compiled on TPU, the XLA ``grouped`` path elsewhere.
+  * ``pallas``  — the fused TPU kernel, compiled on TPU and run by the
+                  Pallas interpreter elsewhere (the platform alone decides;
+                  interpret mode is for validation only, never for serving).
+                  Decode batches (m < 128) stay resident in one m block
+                  with no padding to MXU tiles; larger m is tiled by 128.
   * ``grouped`` — XLA path over *packed* planes: unpack + grouped einsum.
                   This is what the multi-pod dry-run lowers, and is what XLA
                   itself would fuse on TPU absent the hand kernel.
@@ -35,15 +33,15 @@ import jax.numpy as jnp
 
 from repro.core.packing import pack_trits, unpack_trits
 from repro.kernels.ternary_matmul import ref as _ref
-from repro.kernels.ternary_matmul.kernel import (
-    ternary_matmul_pallas,
-    ternary_matvec_pallas,
-)
+from repro.kernels.ternary_matmul.kernel import ternary_matmul_pallas
 
 DEFAULT_BACKEND = "auto"
 # Below this m the batch is decode-shaped: padding to a 128-row MXU tile
-# would waste > (1 - m/128) of every pass, so take the matvec fast path.
+# would waste > (1 - m/128) of every pass, so all m rows form one block.
 SMALL_M_THRESHOLD = 128
+# Widest output block: the TPU needs a block's last dim to be a multiple of
+# 128 lanes (or the whole n), and each block holds bn · d/4 packed bytes.
+_MAX_BLOCK_N = 256
 
 
 def resolve_backend(backend: str | None = None, platform: str | None = None) -> str:
@@ -82,16 +80,17 @@ def _largest_divisor_at_most(n: int, cap: int) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _select_tiles(m: int, n: int) -> tuple:
-    """Per-shape (small_m, block_m, block_n) choice, memoized.
+    """Per-shape (block_m, block_n) choice, memoized.
 
     block_n divides n exactly (Pallas grids need exact tiling on the weight
-    axis); block_m is the MXU tile for the large-m kernel, with the residual
-    rows handled by padding in the caller.
+    axis) and is a multiple of 128 lanes, or the whole n when n has no such
+    divisor; block_m is all of a decode batch, else the 128-row MXU tile
+    with the residual rows padded by the caller.
     """
-    small = m < SMALL_M_THRESHOLD
-    bm = m if small else 128
-    bn = _largest_divisor_at_most(n, 128)
-    return small, bm, bn
+    bm = m if m < SMALL_M_THRESHOLD else 128
+    if n % 128:
+        return bm, n
+    return bm, 128 * _largest_divisor_at_most(n // 128, _MAX_BLOCK_N // 128)
 
 
 def _grouped(x, t1p, t2p, alpha, group_size):
@@ -116,28 +115,19 @@ def _grouped(x, t1p, t2p, alpha, group_size):
     return y.reshape(*lead, n)
 
 
-def _pallas(x, t1p, t2p, alpha, group_size, interpret):
+def _pallas(x, t1p, t2p, alpha, group_size):
     *lead, d = x.shape
     x2 = x.reshape(-1, d)
     m = x2.shape[0]
     n = t1p.shape[0]
-    small, bm, bn = _select_tiles(m, n)
-    if small:
-        y = ternary_matvec_pallas(
-            x2, t1p, t2p, alpha,
-            group_size=group_size, block_n=bn, interpret=interpret,
-        )
-    else:
-        pad = (-m) % bm
-        if pad:
-            x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-        y = ternary_matmul_pallas(
-            x2, t1p, t2p, alpha,
-            group_size=group_size, block_m=bm, block_n=bn, interpret=interpret,
-        )
-        if pad:
-            y = y[:m]
-    return y.reshape(*lead, n)
+    bm, bn = _select_tiles(m, n)
+    pad = (-m) % bm
+    if pad:
+        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+    y = ternary_matmul_pallas(
+        x2, t1p, t2p, alpha, group_size=group_size, block_m=bm, block_n=bn,
+        interpret=jax.default_backend() != "tpu")
+    return y[:m].reshape(*lead, n)
 
 
 def ternary_matmul(
@@ -148,14 +138,13 @@ def ternary_matmul(
     *,
     group_size: int = 128,
     backend: str = DEFAULT_BACKEND,
-    interpret: bool | None = None,
     out_dtype=None,
 ) -> jax.Array:
     """y = x @ Ŵᵀ. x: (..., d); packed planes (n, d//4); alpha (n, d//G, 2).
 
     ``backend='auto'`` selects Pallas (compiled) on TPU and the grouped XLA
-    path elsewhere.  ``interpret=None`` likewise resolves per platform, so an
-    explicit ``backend='pallas'`` still validates on CPU via the interpreter.
+    path elsewhere; an explicit ``backend='pallas'`` off-TPU validates
+    through the interpreter.
 
     Plane dtype doubles as the storage tag: uint8 means packed (4 trits per
     byte, what every backend expects), int8 means raw ±1/0 trits that a
@@ -179,9 +168,7 @@ def ternary_matmul(
     elif backend == "grouped":
         y = _grouped(x, t1p, t2p, alpha, group_size)
     elif backend == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        y = _pallas(x, t1p, t2p, alpha, group_size, interpret)
+        y = _pallas(x, t1p, t2p, alpha, group_size)
     else:
         raise ValueError(f"unknown backend {backend!r}")
     return y.astype(out_dtype) if out_dtype is not None else y

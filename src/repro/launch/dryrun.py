@@ -1,7 +1,11 @@
 import os
+# a CPU compile tool: 512 host devices stand in for the production mesh, and
+# the accelerator (if the machine has one) is left to the serving processes
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-# --- everything below may import jax (device count is locked above) --------
+# --- everything below may import jax (platform and device count are locked
+# above) ---------------------------------------------------------------------
 import argparse          # noqa: E402
 import json              # noqa: E402
 import sys               # noqa: E402

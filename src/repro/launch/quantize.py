@@ -24,6 +24,7 @@ from repro import configs
 from repro.artifacts import (iter_checkpoint_leaves, verify_artifact,
                              write_artifact)
 from repro.core.ptqtp import PTQTPConfig
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _progress_printer(every: int = 1):
@@ -48,6 +49,7 @@ def _progress_printer(every: int = 1):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=configs.ARCH_IDS, default="qwen2-1.5b")
     ap.add_argument("--out", required=True, help="artifact directory to write")

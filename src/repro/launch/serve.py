@@ -28,7 +28,10 @@ request a wall budget (expired requests retire with finish_reason
 with ``--admission-policy`` choosing shed-on-submit (``reject``, the
 default) vs progress-coupled blocking (``block``). The final line prints
 ``engine.health().summary()`` — the same one-line snapshot a monitor
-scrapes.
+scrapes. The batch path exits nonzero, printing each failure's detail,
+when any request retires ``"error"`` or no request produced a token —
+fault containment keeps the engine serving, but a run in which a kernel
+failed to compile must not report success.
 
 Paged KV (v1.2): ``--kv-layout paged`` serves from fixed-size physical KV
 pages (``--page-size``, pool ``--max-pages``) with copy-on-write prefix
@@ -78,6 +81,7 @@ from repro.core.ptqtp import PTQTPConfig
 from repro.core.quantize_model import quantize_tree
 from repro.data.tokenizer import ByteTokenizer
 from repro.models import init_params
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import (EngineConfig, SamplingParams, SerialAdmitEngine,
                            ServingEngine)
 from repro.serving.observability import TRACK_BOOT, Observability
@@ -268,7 +272,22 @@ def _stats_line(engine, t_serve0):
     return line
 
 
+def _check_batch(results, drained: bool):
+    """Exit nonzero when the batch run lost a request to a contained fault
+    or, unless a signal drained it, served no token at all (the engine
+    keeps stepping past both, so a clean return alone would hide a kernel
+    that never compiled)."""
+    errors = [r for r in results if r.finish_reason == "error"]
+    for r in errors:
+        print(f"[serve] ERROR request {r.uid}: {r.error}", flush=True)
+    if errors or not (drained or any(r.tokens for r in results)):
+        raise SystemExit(
+            f"[serve] FAILED: {len(errors)} of {len(results)} requests "
+            f"errored, {sum(len(r.tokens) for r in results)} tokens served")
+
+
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=configs.ARCH_IDS, default="qwen2-1.5b")
     ap.add_argument("--artifact", default=None, metavar="PATH",
@@ -594,6 +613,7 @@ def main(argv=None):
         print(f"[serve] drained: {len(results) - n_cancelled} finished, "
               f"{n_cancelled} cancelled in queue")
     _drain_report(results, engine, tok, args, dt, jsonl_f, jsonl_path)
+    _check_batch(results, drained=draining.is_set())
     return results
 
 

@@ -451,8 +451,11 @@ class ServingEngine:
         if pre is None:
             pre = resolve_backend(matmul_backend()) == "grouped"
         # serve-side params: prefill and decode both read these, so the
-        # unpack is paid once per engine, not once per dispatch
-        self._serve_params = _preunpack_params(params) if pre else params
+        # unpack is paid once per engine, not once per dispatch; placed on
+        # the device once (an artifact's memmapped host leaves would
+        # otherwise be copied to the accelerator at every dispatch)
+        self._serve_params = jax.device_put(
+            _preunpack_params(params) if pre else params)
         self.preunpack_decode = pre
         self._loop_cache: Dict[Tuple[int, bool, int, bool], Any] = {}
         self._prefill_cache: Dict[int, Any] = {}

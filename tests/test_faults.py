@@ -466,3 +466,43 @@ class TestChaosScenario:
         assert sum(h.finish_reason == "rejected" for h in faulty) == 2
         kinds = {k for k, _ in inj.log}
         assert {"nan", "dispatch", "stall"} <= kinds
+
+
+class TestServeExitCode:
+    """launch/serve.py's batch path: a request lost to a contained fault
+    (or a run that serves no token) must end the process with a failure,
+    while fault containment keeps the engine serving the rest."""
+
+    ARGS = ["--no-quantize", "--requests", "2", "--max-new", "4",
+            "--slots", "2", "--capacity", "32"]
+
+    @pytest.fixture
+    def serve(self, monkeypatch):
+        import signal
+
+        from repro.launch import serve
+
+        # the test process keeps its own signal handlers and compile cache
+        saved = {s: signal.getsignal(s) for s in (signal.SIGINT,
+                                                  signal.SIGTERM)}
+        monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+        yield serve
+        for s, h in saved.items():
+            signal.signal(s, h)
+
+    def test_dispatch_error_exits_nonzero(self, serve, monkeypatch, capsys):
+        plan = FaultPlan(seed=0).dispatch_error("decode", 0)
+        monkeypatch.setattr(
+            serve, "ServingEngine",
+            lambda *a, **kw: ServingEngine(
+                *a, injector=FaultInjector(plan), **kw))
+        with pytest.raises(SystemExit) as exc:
+            serve.main(self.ARGS)
+        assert exc.value.code not in (0, None)
+        out = capsys.readouterr().out
+        assert "ERROR request" in out and "decode dispatch failed" in out
+
+    def test_clean_run_exits_zero(self, serve):
+        results = serve.main(self.ARGS)
+        assert [r.finish_reason for r in results] == ["length", "length"]
+        assert all(len(r.tokens) == 4 for r in results)

@@ -102,14 +102,15 @@ class TestSmallMFastPath:
                                    rtol=1e-4, atol=1e-4)
 
     def test_small_m_kernel_direct(self):
-        """The matvec kernel entry point itself, bypassing dispatch."""
-        from repro.kernels.ternary_matmul.kernel import ternary_matvec_pallas
+        """The kernel entry point itself with the whole decode batch in one
+        m block (block_m = m), bypassing dispatch."""
+        from repro.kernels.ternary_matmul.kernel import ternary_matmul_pallas
 
         q, t1p, t2p = _quantized(256, 384, seed=21)
         x = jnp.asarray(np.random.default_rng(22)
                         .standard_normal((4, 384), dtype=np.float32))
-        y = ternary_matvec_pallas(x, t1p, t2p, q.alpha, group_size=128,
-                                  block_n=128, interpret=True)
+        y = ternary_matmul_pallas(x, t1p, t2p, q.alpha, group_size=128,
+                                  block_m=4, block_n=128, interpret=True)
         y_r = tm_ref.ternary_matmul_ref(x, q.t1, q.t2, q.alpha, group_size=128)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_r),
                                    rtol=1e-4, atol=1e-4)
@@ -178,8 +179,10 @@ class TestBackendSelection:
         hits_before = tm_ops._select_tiles.cache_info().hits
         tm_ops._select_tiles(7, 4096)  # identical call must hit the cache
         assert tm_ops._select_tiles.cache_info().hits == hits_before + 1
-        assert tm_ops._select_tiles(7, 4096) == (True, 7, 128)
-        assert tm_ops._select_tiles(256, 384) == (False, 128, 128)
+        assert tm_ops._select_tiles(7, 4096) == (7, 256)
+        assert tm_ops._select_tiles(256, 384) == (128, 128)
+        assert tm_ops._select_tiles(8, 151936) == (8, 128)  # 151936 = 1187·128
+        assert tm_ops._select_tiles(3, 96) == (3, 96)  # n < 128 lanes: whole
 
 
 class TestPTQTPSearchKernel:

@@ -378,7 +378,9 @@ class TestWatchdog:
             hs = [sup.submit(p, sp) for p, sp in jobs]
             inj = sup._injectors[0]
             assert inj.stall_engaged.wait(timeout=60)
-            assert _wait_until(lambda: sup.generation == 1)
+            # the generation id moves before the recovery record lands
+            assert _wait_until(lambda: sup.recoveries)
+            assert sup.generation == 1
             rec = sup.recoveries[0]
             assert rec["exc"].startswith("StepTimeout")
             inj.release_stalls()  # the wedged gen-0 thread wakes, exits

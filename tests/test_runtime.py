@@ -146,3 +146,29 @@ class TestStragglers:
         assert rep["dead"] == [] and sorted(rep["healthy"]) == [0, 1]
         # host 2's 50s step time is excluded from the median
         assert rep["median_step_s"] == pytest.approx(1.1)
+
+
+class TestCompileCache:
+    def test_env_var_wins_else_fixed_checkout_path(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX untouched;
+        unset, the cache goes to the fixed <checkout>/.jax_cache."""
+        from repro.runtime import compile_cache
+
+        saved = {k: getattr(jax.config, k) for k in
+                 ("jax_compilation_cache_dir", "jax_enable_compilation_cache")}
+        try:
+            monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere/cache")
+            assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+            assert jax.config.jax_compilation_cache_dir == \
+                saved["jax_compilation_cache_dir"]
+
+            monkeypatch.delenv(compile_cache.ENV_VAR)
+            path = compile_cache.enable_compile_cache()
+            assert path == str(Path(__file__).resolve().parents[1]
+                               / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert jax.config.jax_enable_compilation_cache
+            assert compile_cache.enable_compile_cache() == path  # fixed
+        finally:
+            for k, v in saved.items():
+                jax.config.update(k, v)

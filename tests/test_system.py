@@ -26,12 +26,9 @@ class TestShardingRules:
     def _mesh(self, multi=False):
         from jax.sharding import AbstractMesh
 
-        # the installed jax's AbstractMesh wants ((name, size), ...) pairs;
-        # other jax releases take (sizes_tuple, names_tuple) — re-check the
-        # signature when bumping jax
         if multi:
-            return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-        return AbstractMesh((("data", 16), ("model", 16)))
+            return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+        return AbstractMesh((16, 16), ("data", "model"))
 
     @pytest.mark.parametrize("arch", list(configs.ARCH_IDS))
     @pytest.mark.parametrize("multi", [False, True])
