@@ -14,9 +14,10 @@ weights made from ``--seed``:
 3. two of the same requests go over loopback HTTP (unary and SSE) through
    ``EngineDriver`` + ``ThreadedHttpServer``; their tokens must equal the
    in-process tokens at temperature 0;
-4. the Pallas kernels are compared with the XLA paths on the chip:
-   ``ternary_matmul`` pallas vs grouped on the artifact's packed planes,
-   ``chunk_attention`` (ring and paged) pallas vs stream.
+4. the Pallas kernels are compared with the XLA paths, run at HIGHEST
+   matmul precision, on the chip: ``ternary_matmul`` pallas vs grouped on
+   the artifact's packed planes, ``chunk_attention`` (ring and paged)
+   pallas vs stream, each under its own limit on the relative L2 error.
 
 Each phase prints its wall seconds with compile seconds kept apart, and the
 run prints the resolved kernel backends, finish reasons, token counts,
@@ -43,10 +44,19 @@ ROOT = Path(__file__).resolve().parent
 ARCH = "qwen2-1.5b"
 WORK = ROOT / ".chip_smoke"           # listed in .gitignore
 SERVE = dict(requests=8, max_new=32, slots=8, capacity=2048, prefill_chunk=16)
-# Parity limit, max |pallas - xla| / max |xla|: the Pallas ternary kernel
+# Parity limits on ||pallas - xla||_2 / ||xla||_2, the XLA path run at
+# HIGHEST matmul precision as the reference. The Pallas ternary kernel
 # feeds the MXU α-scaled weights rounded to the activation dtype (bf16,
-# relative step 2^-8), the XLA path exact trits with α applied in f32.
-PARITY_TOL = 1e-2
+# relative step 2^-8), where the XLA path keeps trits exact and applies α
+# in f32; the attention kernel's f32 dots run at the MXU's default
+# precision. On a v5e over seeds 0-2 the ternary kernel reads 1.59e-3 to
+# 1.72e-3 and attention 2.26e-3 to 3.29e-3 (PERF.md); each limit sits
+# more than twice above its highest reading, where a wrong group, trit
+# field, tile or mask reads O(1).
+PARITY_LIMITS = {"ternary_matmul": 5e-3, "chunk_attention": 7.5e-3}
+# chunk lengths of the attention parity: decode, the served prefill chunk,
+# and a chunk whose default tile (32) the compiled kernel rounds up to 128
+PARITY_CHUNKS = (1, SERVE["prefill_chunk"], 256)
 # XLA's compile of each program (a persistent-cache hit records only its
 # read); tracing and lowering nest across jit levels, so they are left in
 # "other" rather than counted twice
@@ -96,24 +106,24 @@ class Phases:
         return out
 
 
-def quantize_phase(art_dir: Path, seed: int, config: str):
+def quantize_phase(art_dir: Path, seed: int):
     from repro.launch import quantize
 
-    quantize.main(["--arch", ARCH, "--config", config, "--seed", str(seed),
+    quantize.main(["--arch", ARCH, "--config", "full", "--seed", str(seed),
                    "--out", str(art_dir), "--overwrite", "--no-resume"])
 
 
-def serve_phase(art_dir: Path, serve: dict):
+def serve_phase(art_dir: Path):
     from repro.launch import serve as serve_mod
 
     try:
         results = serve_mod.main([
             "--artifact", str(art_dir), "--warmup",
-            "--requests", str(serve["requests"]),
-            "--max-new", str(serve["max_new"]),
-            "--slots", str(serve["slots"]),
-            "--capacity", str(serve["capacity"]),
-            "--prefill-chunk", str(serve["prefill_chunk"])])
+            "--requests", str(SERVE["requests"]),
+            "--max-new", str(SERVE["max_new"]),
+            "--slots", str(SERVE["slots"]),
+            "--capacity", str(SERVE["capacity"]),
+            "--prefill-chunk", str(SERVE["prefill_chunk"])])
     except SystemExit as e:
         raise RuntimeError(f"serve.main exited: {e.code}") from e
     for r in results:
@@ -121,7 +131,7 @@ def serve_phase(art_dir: Path, serve: dict):
             f"tokens={len(r.tokens)}")
     bad = [r.uid for r in results
            if r.finish_reason not in ("stop", "length") or not r.tokens]
-    if len(results) != serve["requests"] or bad:
+    if len(results) != SERVE["requests"] or bad:
         raise RuntimeError(f"requests not served cleanly: {bad}")
     return results
 
@@ -146,7 +156,7 @@ def _post(base, prompt, max_new, seed, stream):
         return tokens
 
 
-def http_phase(art_dir: Path, serve: dict, results, full: bool):
+def http_phase(art_dir: Path, results):
     from repro.artifacts import load_artifact, load_model_config
     from repro.core.quantize_model import QuantizedKernel
     from repro.data.tokenizer import ByteTokenizer
@@ -161,13 +171,13 @@ def http_phase(art_dir: Path, serve: dict, results, full: bool):
         f"heads {cfg.n_heads}/{cfg.n_kv_heads}, d_ff {cfg.d_ff}, "
         f"vocab {cfg.vocab_size}, {cfg.param_dtype}; lm_head quantized: "
         f"{lm_head_q}; {manifest['stats']['n_quantized']} quantized kernels")
-    if full and (cfg.n_layers, cfg.d_model, cfg.vocab_size, lm_head_q) \
+    if (cfg.n_layers, cfg.d_model, cfg.vocab_size, lm_head_q) \
             != (28, 1536, 151936, True):
         raise RuntimeError("artifact is not full-width qwen2-1.5b with a "
                            "quantized lm_head")
     engine = ServingEngine(params, cfg, EngineConfig(
-        max_slots=serve["slots"], capacity=serve["capacity"],
-        prefill_chunk=serve["prefill_chunk"]))
+        max_slots=SERVE["slots"], capacity=SERVE["capacity"],
+        prefill_chunk=SERVE["prefill_chunk"]))
     tok = ByteTokenizer()
     driver = EngineDriver(engine).start()
     srv = ThreadedHttpServer(driver).start()
@@ -175,7 +185,7 @@ def http_phase(art_dir: Path, serve: dict, results, full: bool):
         base = f"http://{srv.host}:{srv.port}"
         for i, stream in ((0, False), (1, True)):
             prompt = tok.encode(PROMPTS[i % len(PROMPTS)], eos=False)
-            got = _post(base, prompt, serve["max_new"], i, stream)
+            got = _post(base, prompt, SERVE["max_new"], i, stream)
             want = list(results[i].tokens)
             kind = "sse" if stream else "unary"
             log(f"http {kind} request {i}: {len(got)} tokens, equal to "
@@ -190,25 +200,35 @@ def http_phase(art_dir: Path, serve: dict, results, full: bool):
     return params
 
 
-def _rel_err(a, b) -> float:
+def _rel_err(got, want) -> float:
+    """||got - want||_2 / ||want||_2; inf unless both are finite."""
     import numpy as np
 
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    if not (np.isfinite(a).all() and np.isfinite(b).all()):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    if not (np.isfinite(got).all() and np.isfinite(want).all()):
         return float("inf")
-    return float(np.max(np.abs(a - b)) / max(np.max(np.abs(b)), 1e-30))
+    return float(np.linalg.norm(got - want)
+                 / max(np.linalg.norm(want), 1e-30))
 
 
-def parity_phase(params, seed: int, serve: dict):
+def parity_phase(params, seed: int):
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.kernels.chunk_attention import (chunk_attention,
-                                               chunk_attention_paged,
-                                               paged_tile)
-    from repro.kernels.chunk_attention.ops import _select_tile
+                                               chunk_attention_paged)
     from repro.kernels.ternary_matmul.ops import ternary_matmul
+
+    def versus_xla(op, xla_backend, *args):
+        got = op(*args, backend="pallas")
+        with jax.default_matmul_precision("highest"):
+            want = op(*args, backend=xla_backend)
+        return _rel_err(got, want)
+
+    def matmul(x, qk, backend):
+        return ternary_matmul(x, qk.t1p, qk.t2p, qk.alpha,
+                              group_size=qk.group_size, backend=backend)
 
     rng = np.random.default_rng(seed)
     errs = {}
@@ -216,15 +236,13 @@ def parity_phase(params, seed: int, serve: dict):
     for name, qk in (("lm_head", params["lm_head"]["kernel"]),
                      ("mlp_wo", jax.tree.map(lambda a: a[0],
                                              mlp["wo"]["kernel"]))):
-        for m in (serve["slots"], 256):
+        for m in (SERVE["slots"], 256):
             x = jnp.asarray(rng.standard_normal((m, qk.d_in)), jnp.bfloat16)
-            ys = [ternary_matmul(x, qk.t1p, qk.t2p, qk.alpha,
-                                 group_size=qk.group_size, backend=be)
-                  for be in ("pallas", "grouped")]
-            errs[f"ternary_matmul {name} m={m}"] = _rel_err(*ys)
+            errs[("ternary_matmul", f"{name} m={m}")] = versus_xla(
+                lambda x, backend: matmul(x, qk, backend), "grouped", x)
 
-    b, cap, kv, g, hd, ps = serve["slots"], serve["capacity"], 2, 6, 128, 16
-    for L in (1, serve["prefill_chunk"]):
+    b, cap, kv, g, hd, ps = SERVE["slots"], SERVE["capacity"], 2, 6, 128, 16
+    for L in PARITY_CHUNKS:
         for int8 in (False, True):
             q = jnp.asarray(rng.standard_normal((b, L, kv, g, hd)),
                             jnp.float32)
@@ -251,9 +269,8 @@ def parity_phase(params, seed: int, serve: dict):
             args = (q, kn, vn, kc, ks, vc, vs, jnp.asarray(pb), positions,
                     lengths)
             tag = f"L={L} {'int8' if int8 else 'bf16'}"
-            ys = [chunk_attention(*args, backend=be, tile=_select_tile(cap, L))
-                  for be in ("pallas", "stream")]
-            errs[f"chunk_attention ring {tag}"] = _rel_err(*ys)
+            errs[("chunk_attention", f"ring {tag}")] = versus_xla(
+                chunk_attention, "stream", *args)
             # the same ring as pages in a shuffled pool (page 0 = null page)
             n_pages = cap // ps
             perm = rng.permutation(b * n_pages) + 1
@@ -269,19 +286,17 @@ def parity_phase(params, seed: int, serve: dict):
             pos_pool = pool(jnp.asarray(pb)).at[0].set(-1)
             pargs = (q, kn, vn, pool(kc), pool(ks), pool(vc), pool(vs),
                      pos_pool, table, positions, lengths)
-            ys = [chunk_attention_paged(*pargs, backend=be,
-                                        tile=paged_tile(ps, L))
-                  for be in ("pallas", "stream")]
-            errs[f"chunk_attention paged {tag}"] = _rel_err(*ys)
-    for k, v in errs.items():
-        log(f"parity {k}: max|pallas-xla|/max|xla| = {v:.3e} "
-            f"(limit {PARITY_TOL:g})")
-    bad = [k for k, v in errs.items() if not v <= PARITY_TOL]
+            errs[("chunk_attention", f"paged {tag}")] = versus_xla(
+                chunk_attention_paged, "stream", *pargs)
+    for (op, case), v in errs.items():
+        log(f"parity {op} {case}: ||pallas-xla||/||xla|| = {v!r} "
+            f"(limit {PARITY_LIMITS[op]:g})")
+    bad = [k for k, v in errs.items() if not v <= PARITY_LIMITS[k[0]]]
     if bad:
-        raise RuntimeError(f"parity beyond {PARITY_TOL:g}: {bad}")
+        raise RuntimeError(f"parity beyond its limit: {bad}")
 
 
-def smoke(seed: int, config: str = "full", serve: dict = SERVE) -> None:
+def smoke(seed: int) -> None:
     """The phases, in order; raises on the first failure."""
     import jax
 
@@ -297,11 +312,10 @@ def smoke(seed: int, config: str = "full", serve: dict = SERVE) -> None:
     art_dir = WORK / "artifact"
     t0 = time.perf_counter()
     try:
-        phases.run("quantize", quantize_phase, art_dir, seed, config)
-        results = phases.run("serve", serve_phase, art_dir, serve)
-        params = phases.run("http", http_phase, art_dir, serve, results,
-                            config == "full")
-        phases.run("parity", parity_phase, params, seed, serve)
+        phases.run("quantize", quantize_phase, art_dir, seed)
+        results = phases.run("serve", serve_phase, art_dir)
+        params = phases.run("http", http_phase, art_dir, results)
+        phases.run("parity", parity_phase, params, seed)
     finally:
         shutil.rmtree(WORK, ignore_errors=True)
     total = time.perf_counter() - t0

@@ -15,7 +15,9 @@ takes lane block ``kv`` of width hd; int8 scales are transposed to
 (KV·B, 1, cap) rows and applied to the scores and the softmax weights
 (q·(s k) = s (q·k)), so no per-slot column ever needs a lane→sublane
 relayout; positions come in both orientations (queries as a (G·L, 1)
-column, keys as a (1, tile) row) so the mask is a plain broadcast.
+column, keys as a (1, tile) row) so the mask is a plain broadcast. Those
+(1, tile) rows need a tile that is a multiple of 128 or every slot;
+``ops.lane_tile`` picks one for the compiled kernel.
 Lengths (and the page table) ride in SMEM as scalar prefetch.
 
 Ring wrap, sliding windows, and right-padding are all mask regions of the

@@ -17,7 +17,9 @@ Tile selection: one tile is sized so the live score block stays near
 ``_TILE_ELEMS`` elements per (kv-head, group) — so decode (L = 1) gets a
 single full-ring tile (no loop overhead on the hot path) while a 64-token
 prefill chunk against a 32k ring walks 128 tiles. Tiles must divide cap
-exactly (same rule as the ternary-matmul grid).
+exactly (same rule as the ternary-matmul grid). The compiled Pallas kernel
+further rounds the tile up to a lane-legal one (``lane_tile``); the
+interpreter walks the tile as given.
 
 ``tracked_block_bytes`` / ``peak_tracked_bytes`` expose the analytic score
 -block footprint — the number the long-context benchmark reports and the
@@ -98,6 +100,23 @@ def paged_tile(page_size: int, L: int) -> int:
                     best = d
         i += 1
     return best
+
+
+@functools.lru_cache(maxsize=None)
+def lane_tile(slots: int, tile: int) -> int:
+    """The tile a compiled Pallas kernel walks over ``slots`` ring slots
+    (or page entries): the kernel reads positions and int8 scales as
+    (1, tile) rows, which the TPU accepts only for a multiple of 128 lanes
+    or all of ``slots``. ``tile`` itself when legal, else the smallest
+    legal divisor of ``slots`` above it (the score block grows to at most
+    (G·L, 128) per step).
+    """
+    if tile % 128 == 0 or tile == slots:
+        return tile
+    for t in range(128 * -(-tile // 128), slots, 128):
+        if slots % t == 0:
+            return t
+    return slots
 
 
 def tracked_block_bytes(b: int, kv: int, g: int, L: int, cap: int, *,
@@ -254,6 +273,9 @@ def chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
     t = min(t, cap)
     while cap % t:  # tiles must divide cap exactly — a remainder tile would
         t -= 1      # silently drop ring slots from the visible set
+    interpret = jax.default_backend() != "tpu"
+    if backend == "pallas" and not interpret:
+        t = lane_tile(cap, t)
     _TRACK["peak_bytes"] = max(
         _TRACK["peak_bytes"],
         tracked_block_bytes(b, kv, g, L, cap, backend=backend, tile=t))
@@ -268,8 +290,8 @@ def chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
         out = chunk_attention_pallas(
             q.transpose(0, 2, 3, 1, 4), k_new, v_new, k_cache, k_scale,
             v_cache, v_scale, pos_buf, positions,
-            lengths.astype(jnp.int32), window=window, tile=t,
-            interpret=jax.default_backend() != "tpu")
+            lengths.astype(jnp.int32), window=window,
+            tile=t, interpret=interpret)
         return out.transpose(0, 3, 1, 2, 4)
     raise ValueError(f"unknown chunk-attention backend {backend!r}")
 
@@ -306,6 +328,9 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
     t = min(t, ps)
     while ps % t:  # tiles must divide the page — a spanning tile would need
         t -= 1     # a two-page gather
+    interpret = jax.default_backend() != "tpu"
+    if backend == "pallas" and not interpret:
+        t = lane_tile(ps, t)
     _TRACK["peak_bytes"] = max(
         _TRACK["peak_bytes"],
         tracked_block_bytes(b, kv, g, L, cap, backend=backend, tile=t))
@@ -324,7 +349,7 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
         out = chunk_attention_paged_pallas(
             q.transpose(0, 2, 3, 1, 4), k_new, v_new, k_pool, k_scale,
             v_pool, v_scale, pos_pool, table, positions,
-            lengths.astype(jnp.int32), window=window, tile=t,
-            interpret=jax.default_backend() != "tpu")
+            lengths.astype(jnp.int32), window=window,
+            tile=t, interpret=interpret)
         return out.transpose(0, 3, 1, 2, 4)
     raise ValueError(f"unknown chunk-attention backend {backend!r}")

@@ -5,6 +5,13 @@ packed 2-bit trit-planes stream HBM→VMEM (0.5 B/weight instead of 2 B),
 are unpacked with shifts/masks on the VPU, scaled by their group's α pair
 and fed to the MXU in the activation dtype.
 
+Numerics. Ŵ = α¹∘T¹ + α²∘T² is formed in f32 and rounded to the
+activation dtype before the MXU, so a bf16 model multiplies by bf16
+weights, as a dense bf16 checkpoint would (relative error 2^-9 per
+weight, about 1.5e-3 in the output's norm). The XLA ``grouped`` path
+keeps the trits exact and applies α in f32 after accumulation; the two
+are compared under a stated limit, not bit for bit.
+
 Layout. Byte b of a packed row holds trits 4b..4b+3 (field f = bits
 2f..2f+1, see ``core/packing.py``), so unpacking a (bn, K) byte block
 field by field yields four lane-dense (bn, K) planes: field f carries

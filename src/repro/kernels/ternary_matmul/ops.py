@@ -7,7 +7,8 @@ Backends:
                   Pallas interpreter elsewhere (the platform alone decides;
                   interpret mode is for validation only, never for serving).
                   Decode batches (m < 128) stay resident in one m block
-                  with no padding to MXU tiles; larger m is tiled by 128.
+                  with no padding to MXU tiles; larger m is tiled by 128,
+                  and n is padded up to a multiple of 128 lanes.
   * ``grouped`` — XLA path over *packed* planes: unpack + grouped einsum.
                   This is what the multi-pod dry-run lowers, and is what XLA
                   itself would fuse on TPU absent the hand kernel.
@@ -40,7 +41,7 @@ DEFAULT_BACKEND = "auto"
 # would waste > (1 - m/128) of every pass, so all m rows form one block.
 SMALL_M_THRESHOLD = 128
 # Widest output block: the TPU needs a block's last dim to be a multiple of
-# 128 lanes (or the whole n), and each block holds bn · d/4 packed bytes.
+# 128 lanes, and each block holds bn · d/4 packed bytes.
 _MAX_BLOCK_N = 256
 
 
@@ -82,15 +83,14 @@ def _largest_divisor_at_most(n: int, cap: int) -> int:
 def _select_tiles(m: int, n: int) -> tuple:
     """Per-shape (block_m, block_n) choice, memoized.
 
-    block_n divides n exactly (Pallas grids need exact tiling on the weight
-    axis) and is a multiple of 128 lanes, or the whole n when n has no such
-    divisor; block_m is all of a decode batch, else the 128-row MXU tile
-    with the residual rows padded by the caller.
+    block_n is a multiple of 128 lanes dividing n rounded up to 128 (the
+    caller pads the weight rows to that); block_m is all of a decode
+    batch, else the 128-row MXU tile with the residual rows padded by the
+    caller.
     """
     bm = m if m < SMALL_M_THRESHOLD else 128
-    if n % 128:
-        return bm, n
-    return bm, 128 * _largest_divisor_at_most(n // 128, _MAX_BLOCK_N // 128)
+    return bm, 128 * _largest_divisor_at_most(-(-n // 128),
+                                              _MAX_BLOCK_N // 128)
 
 
 def _grouped(x, t1p, t2p, alpha, group_size):
@@ -115,19 +115,22 @@ def _grouped(x, t1p, t2p, alpha, group_size):
     return y.reshape(*lead, n)
 
 
-def _pallas(x, t1p, t2p, alpha, group_size):
+def _pallas(x, t1p, t2p, alpha, group_size, *, interpret):
     *lead, d = x.shape
     x2 = x.reshape(-1, d)
     m = x2.shape[0]
     n = t1p.shape[0]
     bm, bn = _select_tiles(m, n)
-    pad = (-m) % bm
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+    pad_m, pad_n = (-m) % bm, (-n) % bn
+    if pad_m:
+        x2 = jnp.pad(x2, ((0, pad_m), (0, 0)))
+    if pad_n:  # zero rows: trits 0, α 0 -> output columns sliced off below
+        t1p, t2p = (jnp.pad(t, ((0, pad_n), (0, 0))) for t in (t1p, t2p))
+        alpha = jnp.pad(alpha, ((0, pad_n), (0, 0), (0, 0)))
     y = ternary_matmul_pallas(
         x2, t1p, t2p, alpha, group_size=group_size, block_m=bm, block_n=bn,
-        interpret=jax.default_backend() != "tpu")
-    return y[:m].reshape(*lead, n)
+        interpret=interpret)
+    return y[:m, :n].reshape(*lead, n)
 
 
 def ternary_matmul(
@@ -168,7 +171,8 @@ def ternary_matmul(
     elif backend == "grouped":
         y = _grouped(x, t1p, t2p, alpha, group_size)
     elif backend == "pallas":
-        y = _pallas(x, t1p, t2p, alpha, group_size)
+        y = _pallas(x, t1p, t2p, alpha, group_size,
+                    interpret=jax.default_backend() != "tpu")
     else:
         raise ValueError(f"unknown backend {backend!r}")
     return y.astype(out_dtype) if out_dtype is not None else y

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.chunk_attention.ops import (_select_tile, chunk_attention,
+                                               lane_tile,
                                                tracked_block_bytes)
 from repro.kernels.chunk_attention.ref import (chunk_attention_ref,
                                                chunk_mask, history_mask,
@@ -290,6 +291,18 @@ class TestStreamingFootprint:
         the whole ring as one tile (the decode fast path)."""
         assert _select_tile(4096, 1) == 4096
         assert _select_tile(256, 64) < 256
+
+    @pytest.mark.parametrize("slots", [16, 96, 2048, 4096, 4224])
+    @pytest.mark.parametrize("L", [1, 16, 64, 128, 256, 1024])
+    def test_lane_tile_is_legal(self, slots, L):
+        """The compiled kernel's tile divides the slots and is a multiple of
+        128 lanes or all of them, never below the budget tile."""
+        t0 = _select_tile(slots, L)
+        t = lane_tile(slots, t0)
+        assert slots % t == 0 and (t % 128 == 0 or t == slots)
+        assert t >= t0
+        if t0 % 128 == 0:
+            assert t == t0
 
 
 class TestModelLevelBackends:

@@ -182,7 +182,8 @@ class TestBackendSelection:
         assert tm_ops._select_tiles(7, 4096) == (7, 256)
         assert tm_ops._select_tiles(256, 384) == (128, 128)
         assert tm_ops._select_tiles(8, 151936) == (8, 128)  # 151936 = 1187·128
-        assert tm_ops._select_tiles(3, 96) == (3, 96)  # n < 128 lanes: whole
+        assert tm_ops._select_tiles(3, 96) == (3, 128)  # padded to 128
+        assert tm_ops._select_tiles(8, 32064) == (8, 128)  # 250.5·128
 
 
 class TestPTQTPSearchKernel:

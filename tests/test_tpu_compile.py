@@ -25,16 +25,20 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import ptqtp
 from repro.kernels.chunk_attention.kernel import (chunk_attention_paged_pallas,
                                                   chunk_attention_pallas)
-from repro.kernels.chunk_attention.ops import _select_tile, paged_tile
-from repro.kernels.ternary_matmul.kernel import ternary_matmul_pallas
-from repro.kernels.ternary_matmul.ops import _select_tiles
+from repro.kernels.chunk_attention.ops import (_select_tile, lane_tile,
+                                               paged_tile)
+from repro.kernels.ternary_matmul import ops as tm_ops
 
 G = 128
-# qwen2-1.5b linear shapes (n_out, d_in): q/o, k/v, gate/up, down, lm_head
+# qwen2-1.5b linear shapes (n_out, d_in): q/o, k/v, gate/up, down, lm_head;
+# then two n that are not a multiple of 128 lanes: phi3-vision's lm_head
+# and deepseek-moe's dense gate/up
 LINEAR = [(1536, 1536), (256, 1536), (8960, 1536), (1536, 8960),
-          (151936, 1536)]
-SLOTS, CAP, KV, GROUP, HD, PAGE = 8, 4096, 2, 6, 128, 16
-PREFILL_CHUNK = 64  # EngineConfig default
+          (151936, 1536), (32064, 3072), (10944, 2048)]
+SLOTS, CAP, KV, GROUP, HD = 8, 4096, 2, 6, 128
+# decode, the EngineConfig default prefill chunk, and two longer chunks
+# whose default tiles (64, 32) are off the lane rule
+CHUNKS = [1, 64, 128, 256]
 HBM_BYTES = 16 * 1024 ** 3  # one v5e chip
 
 
@@ -75,15 +79,12 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("n,d", LINEAR)
 @pytest.mark.parametrize("m", [1, 8, 256])  # decode (block_m = m), prefill
 def test_ternary_matmul_compiles(one_chip, m, n, d):
-    bm, bn = _select_tiles(m, n)
-    mp = -(-m // bm) * bm
-    args = [_spec(one_chip, (mp, d), jnp.bfloat16),
+    args = [_spec(one_chip, (m, d), jnp.bfloat16),
             _spec(one_chip, (n, d // 4), jnp.uint8),
             _spec(one_chip, (n, d // 4), jnp.uint8),
             _spec(one_chip, (n, d // G, 2), jnp.float32)]
-    _compile(lambda x, t1, t2, a: ternary_matmul_pallas(
-        x, t1, t2, a, group_size=G, block_m=bm, block_n=bn,
-        interpret=False), *args)
+    _compile(lambda x, t1, t2, a: tm_ops._pallas(
+        x, t1, t2, a, G, interpret=False), *args)
 
 
 def _attn_inputs(sh, L):
@@ -93,10 +94,10 @@ def _attn_inputs(sh, L):
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8])
-@pytest.mark.parametrize("L", [1, PREFILL_CHUNK])
+@pytest.mark.parametrize("L", CHUNKS)
 def test_chunk_attention_ring_compiles(one_chip, L, cache_dtype):
     int8 = cache_dtype == jnp.int8
-    tile = _select_tile(CAP, L)
+    tile = lane_tile(CAP, _select_tile(CAP, L))
     args = _attn_inputs(one_chip, L) + [
         _spec(one_chip, (SLOTS, CAP, KV, HD), cache_dtype),
         _spec(one_chip, (SLOTS, CAP, KV, HD), cache_dtype),
@@ -114,20 +115,21 @@ def test_chunk_attention_ring_compiles(one_chip, L, cache_dtype):
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8])
-@pytest.mark.parametrize("L", [1, PREFILL_CHUNK])
-def test_chunk_attention_paged_compiles(one_chip, L, cache_dtype):
+@pytest.mark.parametrize("L,page", [(1, 16), (64, 16), (256, 16),
+                                    (256, 512)])
+def test_chunk_attention_paged_compiles(one_chip, L, page, cache_dtype):
     int8 = cache_dtype == jnp.int8
-    n_pages = CAP // PAGE
+    n_pages = CAP // page
     pool = SLOTS * n_pages + 1                 # + the null page
-    tile = paged_tile(PAGE, L)
+    tile = lane_tile(page, paged_tile(page, L))
     args = _attn_inputs(one_chip, L) + [
-        _spec(one_chip, (pool, PAGE, KV, HD), cache_dtype),
-        _spec(one_chip, (pool, PAGE, KV, HD), cache_dtype),
-        _spec(one_chip, (pool, PAGE), jnp.int32),
+        _spec(one_chip, (pool, page, KV, HD), cache_dtype),
+        _spec(one_chip, (pool, page, KV, HD), cache_dtype),
+        _spec(one_chip, (pool, page), jnp.int32),
         _spec(one_chip, (SLOTS, n_pages), jnp.int32),
         _spec(one_chip, (SLOTS, L), jnp.int32),
         _spec(one_chip, (SLOTS,), jnp.int32)]
-    scales = [_spec(one_chip, (pool, PAGE, KV), jnp.float32)] * 2
+    scales = [_spec(one_chip, (pool, page, KV), jnp.float32)] * 2
 
     def fn(q, kn, vn, kp, vp, pp, table, pos, lens, *sc):
         ks, vs = sc if int8 else (None, None)
