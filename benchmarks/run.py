@@ -15,10 +15,10 @@ import traceback
 
 from benchmarks import (bench_artifacts, bench_condition, bench_decode,
                         bench_groupwise, bench_http, bench_iterations,
-                        bench_latency, bench_memory, bench_observability,
-                        bench_paged_kv, bench_perplexity, bench_prefill,
-                        bench_recovery, bench_roofline, bench_runtime,
-                        bench_serving_api, bench_tolerance)
+                        bench_latency, bench_memory, bench_paged_kv,
+                        bench_perplexity, bench_prefill, bench_recovery,
+                        bench_roofline, bench_runtime, bench_serving_api,
+                        bench_tolerance)
 from benchmarks.common import RESULTS
 
 SUITES = {
@@ -31,7 +31,6 @@ SUITES = {
     "artifacts": bench_artifacts.run,      # quantize-once/serve-many boot
     "serving_api": bench_serving_api.run,  # v1 streaming TTFT + cancel churn
     "paged_kv": bench_paged_kv.run,        # paged pool + COW prefix reuse
-    "observability": bench_observability.run,  # v1.3 tracing overhead gate
     "http": bench_http.run,                # v1.4 wire identity + DRR fairness
     "recovery": bench_recovery.run,        # v1.5 MTTR/availability/replay
 

@@ -57,52 +57,33 @@ PARITY_LIMITS = {"ternary_matmul": 5e-3, "chunk_attention": 7.5e-3}
 # chunk lengths of the attention parity: decode, the served prefill chunk,
 # and a chunk whose default tile (32) the compiled kernel rounds up to 128
 PARITY_CHUNKS = (1, SERVE["prefill_chunk"], 256)
-# XLA's compile of each program (a persistent-cache hit records only its
-# read); tracing and lowering nest across jit levels, so they are left in
-# "other" rather than counted twice
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def log(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
 
 
-class CompileClock:
-    """Seconds XLA spends compiling, and persistent-cache hits and misses,
-    read per phase."""
-
-    def __init__(self, jax):
-        self.seconds = 0.0
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == _COMPILE_EVENT:
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-
 class Phases:
-    def __init__(self, clock: CompileClock):
-        self.clock = clock
+    """Wall seconds per phase, with the seconds of XLA's backend compiles
+    (the program's ``serving_compile*`` counters, one process-wide
+    monitor; a persistent-cache hit counts with its read) kept apart;
+    tracing and lowering nest across jit levels, so they are left in
+    "other" rather than counted twice."""
+
+    def __init__(self, compiles):
+        self.compiles = compiles
         self.rows = []
 
     def run(self, name, fn, *args):
-        c0, t0 = self.clock.seconds, time.perf_counter()
-        h0, m0 = self.clock.hits, self.clock.misses
+        c = self.compiles
+        n0, c0, h0, t0 = c.count, c.seconds, c.hits, time.perf_counter()
         out = fn(*args)
         wall = time.perf_counter() - t0
-        comp = self.clock.seconds - c0
+        comp = c.seconds - c0
         self.rows.append((name, wall, comp))
         log(f"phase {name}: {wall:.2f}s wall = {comp:.2f}s compile + "
-            f"{wall - comp:.2f}s other (persistent cache: "
-            f"{self.clock.hits - h0} hits, {self.clock.misses - m0} misses)")
+            f"{wall - comp:.2f}s other ({c.count - n0} compiles, "
+            f"{c.hits - h0} of them persistent-cache hits)")
         return out
 
 
@@ -303,12 +284,13 @@ def smoke(seed: int) -> None:
     from repro.kernels.chunk_attention import resolve_chunk_backend
     from repro.kernels.ternary_matmul.ops import resolve_backend
     from repro.runtime.compile_cache import enable_compile_cache
+    from repro.serving.observability import compile_monitor
 
     log(f"compile cache: {enable_compile_cache()}")
     log(f"backends: ternary_matmul auto -> {resolve_backend('auto')}, "
         f"chunk_attention auto -> {resolve_chunk_backend('auto')}, "
         f"pallas interpret mode: {jax.default_backend() != 'tpu'}")
-    phases = Phases(CompileClock(jax))
+    phases = Phases(compile_monitor())
     art_dir = WORK / "artifact"
     t0 = time.perf_counter()
     try:

@@ -179,6 +179,7 @@ def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="chunk_attention",
     )(*prefetch, q.reshape(b, kv, g * L, hd),
       k_new.reshape(b, L, kv * hd), v_new.reshape(b, L, kv * hd),
       kc, ks, vc, vs, pos,

@@ -139,4 +139,5 @@ def ternary_matmul_pallas(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="ternary_matmul",
     )(xp, t1p, t2p, a)

@@ -134,8 +134,8 @@ a default bundle with tracing off is always attached). Its parts:
 * **Zero perturbation** (the testable guarantee, like determinism): a
   request's tokens are bit-identical with tracing on, off, or the
   bundle left unconfigured. Instrumentation is host-side only and never
-  adds a compile-cache axis; ``benchmarks/bench_observability.py``
-  bounds the tok/s overhead of tracing at < 3%.
+  adds a compile-cache axis; what tracing on costs end to end is read
+  on the chip (PERF.md, "Findings").
 
 ``RequestResult`` additionally carries ``t_admit`` and the derived
 ``queue_wait`` (0.0 for never-admitted requests); heartbeat payloads are
@@ -231,6 +231,40 @@ injectable clock) — becomes a recovery, not a fleet-wide ``"error"``:
   retire with the existing ``"error"``), and the supervisor duck-types
   the driver's client surface, so every v1.4 rule above applies
   verbatim under supervision.
+
+Observability on the profiler's clock (v1.6)
+--------------------------------------------
+* **The engine thread is tiled by spans.** Under ``EngineDriver`` the
+  driver loop's phases (``driver_loop`` around one pass, the step
+  included; ``driver_lock``, ``driver_calls``, ``driver_offer``,
+  ``driver_pump``, ``driver_idle`` inside it) and the step's
+  (``decode_prepare`` and ``prefill_prepare`` besides the v1.3 phases)
+  leave no part of the thread without a span; each has its
+  ``serving_phase_<name>_seconds_total`` counter.
+* **Profiling a live server.** While tracing is on, every span is also
+  a ``jax.profiler.TraceAnnotation`` of the same name and each step a
+  ``StepTraceAnnotation("step", step_num=...)``. Serve with tracing on
+  (``Observability(trace=True)``; ``serve.py --trace-out``) and capture
+  a profile — ``jax.profiler.start_trace``/``stop_trace``, or
+  ``jax.profiler.start_server(port)`` and TensorBoard's profiler — and
+  the engine's phases appear on the host thread beside the device's
+  ops. With tracing off no annotation object is made.
+* **The frontend's wait.** ``DriverHandle.t_offer`` stamps the moment
+  the fair queue hands a request to the engine; the histogram
+  ``serving_frontend_queue_wait_seconds`` observes ``t_offer −
+  t_submit``, and while tracing a ``frontend_queued`` span covers it on
+  the request's track (ending at retirement, with ``finish_reason``, for
+  a request shed or deadlined in the frontend). With the engine's
+  ``queued`` (engine submit → admit) and ``prefill`` (admit → first
+  token) spans, a request's time to first token splits into three
+  measured parts.
+* **Compiles.** ``serving_compiles_total``,
+  ``serving_compile_seconds_total`` and
+  ``serving_compile_cache_hits_total`` count the process's XLA backend
+  compiles, their seconds and persistent-cache hits (one process-wide
+  ``jax.monitoring`` listener; a cache hit counts as a compile with its
+  read's duration); a compile that finishes on the driver thread is a
+  ``compile`` span on the engine track while tracing.
 
 Consumption
 -----------

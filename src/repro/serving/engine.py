@@ -896,6 +896,10 @@ class ServingEngine:
         stays O(log K)) — a fleet that only needs 3 more tokens never pays
         for a 16-step dispatch.
         """
+        with self.obs.step_annotation(self.engine_steps + 1):
+            return self._step()
+
+    def _step(self) -> List[RequestHandle]:
         obs = self.obs
         t_step0, tok0, churn0 = self._step_begin()
         self.engine_steps += 1
@@ -928,16 +932,18 @@ class ServingEngine:
                   n_steps) for i in dec])
             if self._tables_dirty:
                 self._page_maintenance()
-        (temps, active, seeds, top_k, top_p, stops), use_mask, stop_w = \
-            self._fleet_arrays()
-        # tokens generated so far per row: the on-device draw for a row's
-        # i-th token always uses fold_in(PRNGKey(seed), i), independent of
-        # where the chunk boundaries fell
-        gen0 = jnp.asarray([len(self.slots[i].output) if self._decoding(i)
-                            else 0 for i in range(len(self.slots))], jnp.int32)
         use_poison = self._injector is not None
-        poison = self._poison_array(gen0, n_steps) if use_poison \
-            else jnp.full((len(self.slots),), -1, jnp.int32)
+        with obs.span("decode_prepare"):
+            (temps, active, seeds, top_k, top_p, stops), use_mask, stop_w = \
+                self._fleet_arrays()
+            # tokens generated so far per row: the on-device draw for a
+            # row's i-th token always uses fold_in(PRNGKey(seed), i),
+            # independent of where the chunk boundaries fell
+            gen0 = jnp.asarray([len(self.slots[i].output)
+                                if self._decoding(i) else 0
+                                for i in range(len(self.slots))], jnp.int32)
+            poison = self._poison_array(gen0, n_steps) if use_poison \
+                else jnp.full((len(self.slots),), -1, jnp.int32)
         try:
             self._guard_dispatch("decode", dec)
             with obs.span("decode_dispatch",
@@ -1355,20 +1361,22 @@ class ServingEngine:
         pf = [i for i in range(len(self.slots)) if self._prefilling(i)]
         if not pf:
             return []
-        nb = len(self.slots)
-        need = max(min(len(self._prompts[i]) - self._cursor[i],
-                       self.ecfg.prefill_chunk) for i in pf)
-        length = _pow2ceil(need)
-        tokens = np.zeros((nb, length), np.int32)
-        lengths = np.zeros((nb,), np.int32)
-        for i in pf:
-            # never consume more than prefill_chunk per step, even when the
-            # pow2 bucket rounds past it (non-pow2 prefill_chunk configs)
-            take = min(len(self._prompts[i]) - self._cursor[i],
-                       self.ecfg.prefill_chunk)
-            tokens[i, :take] = self._prompts[i][
-                self._cursor[i]:self._cursor[i] + take]
-            lengths[i] = take
+        obs = self.obs
+        with obs.span("prefill_prepare"):
+            nb = len(self.slots)
+            need = max(min(len(self._prompts[i]) - self._cursor[i],
+                           self.ecfg.prefill_chunk) for i in pf)
+            length = _pow2ceil(need)
+            tokens = np.zeros((nb, length), np.int32)
+            lengths = np.zeros((nb,), np.int32)
+            for i in pf:
+                # never consume more than prefill_chunk per step, even when
+                # the pow2 bucket rounds past it (non-pow2 prefill_chunk)
+                take = min(len(self._prompts[i]) - self._cursor[i],
+                           self.ecfg.prefill_chunk)
+                tokens[i, :take] = self._prompts[i][
+                    self._cursor[i]:self._cursor[i] + take]
+                lengths[i] = take
         if self.paged:
             # prefill only ever writes this row's private unregistered
             # pages (skip starts past the shared prefix and registration
@@ -1378,7 +1386,6 @@ class ServingEngine:
                                for i in pf])
             if self._tables_dirty:
                 self._page_maintenance()
-        obs = self.obs
         t_pf0 = self._clock()
         try:
             self._guard_dispatch("prefill", pf)
@@ -1600,14 +1607,15 @@ class SerialAdmitEngine(ServingEngine):
             h.t_admit = self._clock()
             h._slot = slot
             self.obs.request_admitted(h, slot)
-            fn = self._prefill_len_fn(len(prompt))
+            with self.obs.span("prefill_prepare"):
+                fn = self._prefill_len_fn(len(prompt))
+                tokens = jnp.asarray([prompt], jnp.int32)
             t_pf0 = self._clock()
             try:
                 self._guard_dispatch("prefill", [slot])
                 with self.obs.span("prefill_dispatch",
                                    args={"bucket": len(prompt), "rows": 1}):
-                    logits, one_state = fn(self._serve_params,
-                                           jnp.asarray([prompt], jnp.int32))
+                    logits, one_state = fn(self._serve_params, tokens)
             except EngineCrash as exc:  # engine death escapes containment
                 self._attribute_crash(exc, [slot])
                 raise

@@ -53,6 +53,15 @@ Every timestamp routes through the engine's injectable clock
 (``engine.clock`` — a ``VirtualClock`` under a fault injector), keeping
 the static wall-clock guard and the trace-reconciliation guarantee
 intact across the frontend.
+
+Observability: the driver thread is the only thread that runs the
+engine, so its loop's phases (``observability.DRIVER_PHASES``) are spans
+on the engine track — ``driver_loop`` encloses one pass, the engine step
+included — and with the step's own spans they tile the thread. The wait
+in the fair queue (``t_submit`` → ``t_offer``) feeds the
+``serving_frontend_queue_wait_seconds`` histogram and, while tracing, a
+``frontend_queued`` span on the request's track; compiles that finish on
+the driver thread become ``compile`` spans.
 """
 
 from __future__ import annotations
@@ -67,6 +76,7 @@ from repro.serving.api import (FINISH_CANCELLED, FINISH_REJECTED,
                                FINISH_TIMEOUT, FINISH_ERROR, RequestResult,
                                SamplingParams)
 from repro.serving.frontend.fairness import FairScheduler
+from repro.serving.observability import compile_monitor
 
 _DONE = "done"
 _TOKEN = "token"
@@ -93,6 +103,7 @@ class DriverHandle:
         self.error: Optional[str] = None
         self.truncated = False
         self.t_submit = 0.0
+        self.t_offer = 0.0              # fair queue -> engine.submit
         self.t_admit = 0.0
         self.t_first = 0.0
         self.t_done = 0.0
@@ -196,6 +207,7 @@ class EngineDriver:
     def __init__(self, engine, *, fairness: Optional[FairScheduler] = None,
                  name: str = "engine-driver"):
         self._eng = engine
+        self._obs = engine.obs
         self._clock = engine.clock
         self._fair = fairness if fairness is not None else FairScheduler()
         cap = engine.ecfg.capacity
@@ -228,6 +240,7 @@ class EngineDriver:
         self._thread = threading.Thread(target=self._loop, name=name,
                                         daemon=True)
         self._started = False
+        self._h_fair_wait = None
 
     # ----------------------------------------------------------- lifecycle
     def start(self) -> "EngineDriver":
@@ -243,6 +256,12 @@ class EngineDriver:
             reg.gauge("serving_frontend_queue_depth",
                       poll=lambda: len(self._fair),
                       help="requests waiting in the frontend fair queue")
+            reg.histogram("serving_frontend_queue_wait_seconds",
+                          unit="seconds",
+                          help="driver submit -> offered to the engine "
+                               "(the wait in the fair queue)")
+        self._h_fair_wait = reg.get_histogram(
+            "serving_frontend_queue_wait_seconds")
         self._thread.start()
         return self
 
@@ -383,6 +402,11 @@ class EngineDriver:
             t_done=self._clock(), error=why))
 
     def _finish_locked(self, h: DriverHandle, res: RequestResult) -> None:
+        if not h.t_offer:
+            # retired without leaving the fair queue (shed, deadline,
+            # cancel): its frontend wait ends here
+            self._obs.frontend_queued(h.uid, h.t_submit, res.t_done,
+                                      res.finish_reason)
         if h._replayed:
             # a replayed request's record keeps its original submit/admit/
             # first-token stamps — the client experienced one request, not
@@ -453,6 +477,9 @@ class EngineDriver:
             h = self._fair.pop()
             if h is None:
                 break
+            h.t_offer = self._clock()
+            self._h_fair_wait.observe(max(h.t_offer - h.t_submit, 0.0))
+            self._obs.frontend_queued(h.uid, h.t_submit, h.t_offer)
             inner = eng.submit(h.prompt, h.params, uid=h.uid)
             h._inner = inner
             h.truncated = inner.truncated
@@ -598,6 +625,7 @@ class EngineDriver:
             h._driver = self
             h._inner = None
             h._replayed = True
+            h.t_offer = 0.0   # waits in this driver's fair queue anew
             self._next_uid = max(self._next_uid, h.uid + 1)
             if self._closed or self._draining:
                 self._shed_locked(h, "driver closed" if self._closed
@@ -612,18 +640,30 @@ class EngineDriver:
         return True
 
     def _loop(self) -> None:
-        eng = self._eng
-        while True:
-            with self._cond:
+        with compile_monitor().claim_thread(self._obs):
+            while self._iteration():
+                pass
+
+    def _iteration(self) -> bool:
+        """One pass of the driver loop; False once the thread must exit.
+        Every part of the pass sits in a ``driver_*`` span (or the engine
+        step's own), so a trace accounts for all of the thread's time."""
+        eng, obs = self._eng, self._obs
+        with obs.span("driver_loop"):
+            with obs.span("driver_lock"):
+                self._cond.acquire()
+            try:
                 if self._abandoned:
-                    return  # reaped by a supervisor — handles migrated
-                self._service_calls_locked()
-                if self._closed:
-                    self._shutdown_locked()
-                self._apply_cancels_locked()
-                self._sweep_frontend_locked()
+                    return False  # reaped by a supervisor — handles migrated
+                with obs.span("driver_calls"):
+                    self._service_calls_locked()
+                    if self._closed:
+                        self._shutdown_locked()
+                    self._apply_cancels_locked()
+                    self._sweep_frontend_locked()
                 if not self._closed:
-                    self._offer_locked()
+                    with obs.span("driver_offer"):
+                        self._offer_locked()
                 busy = bool(eng.queue) \
                     or any(s is not None for s in eng.slots)
                 # pending work behind quarantined slots: step anyway so the
@@ -634,26 +674,33 @@ class EngineDriver:
                         and not len(self._fair):
                     self._drained_evt.set()
                 if self._closed and not busy:
-                    self._pump()
+                    with obs.span("driver_pump"):
+                        self._pump()
                     self._drained_evt.set()
-                    return
+                    return False
                 if not busy and not stalled:
                     # a cancel can retire an inner handle without a step;
                     # mirror it before parking or its client hangs
-                    self._pump()
-                    self._cond.wait(0.5)
-                    continue
+                    with obs.span("driver_pump"):
+                        self._pump()
+                    with obs.span("driver_idle"):
+                        self._cond.wait(0.5)
+                    return True
+            finally:
+                self._cond.release()
             self._step_t0 = self._clock()
             try:
                 eng.step()
             except Exception as e:
                 self._step_t0 = None
                 self._fatal(e)
-                return
+                return False
             self._step_t0 = None
             if self._abandoned:
                 # the watchdog reaped us mid-step (hung-step recovery that
                 # eventually woke up): the handles now live on a newer
                 # generation — mirroring anything would double-deliver
-                return
-            self._pump()
+                return False
+            with obs.span("driver_pump"):
+                self._pump()
+            return True

@@ -1,5 +1,6 @@
 """Zero-perturbation serving observability: metrics registry + lifecycle
-trace recorder (the v1.3 contract section in ``repro.serving``).
+trace recorder (the v1.3 contract section in ``repro.serving``, extended
+in v1.6).
 
 Two instruments, one clock:
 
@@ -14,25 +15,29 @@ Two instruments, one clock:
   JSONL stream) and as Prometheus text exposition.
 * :class:`TraceRecorder` — a bounded ring buffer of span/instant events
   (oldest dropped first, drops counted) covering per-request lifecycle
-  (submitted → queued → admitted → prefill chunks → first token → decode
-  → retired, with finish_reason and slot/page annotations) and per-step
-  engine phases (sweep, admit, prefill dispatch/sync, sample-collect,
-  decode dispatch/sync, collect, page maintenance). Exports Chrome/
-  Perfetto ``trace.json`` (load in ``ui.perfetto.dev`` or
-  ``chrome://tracing``).
+  (frontend queue → submitted → queued → admitted → prefill chunks →
+  first token → decode → retired, with finish_reason and slot/page
+  annotations), per-step engine phases (sweep, admit, prefill prepare/
+  dispatch/sync, sample-collect, decode prepare/dispatch/sync, collect,
+  page maintenance), the driver loop that tiles the engine thread around
+  the steps, and XLA compiles on that thread. Exports Chrome/Perfetto
+  ``trace.json`` (load in ``ui.perfetto.dev`` or ``chrome://tracing``).
 
 :class:`Observability` bundles both behind the engine's single injectable
 clock (``repro.runtime.clock``; ``faults.VirtualClock`` substitutes it
 wholesale, making every timestamp — and therefore every span duration and
-histogram observation — deterministic in tests).
+histogram observation — deterministic in tests). While tracing is on,
+every span is also a ``jax.profiler.TraceAnnotation`` (the step a
+``StepTraceAnnotation``), so a profiler capture of a live server shows
+the engine's phases on the host thread beside the device's ops.
 
 The zero-perturbation contract (carried from every prior PR): nothing in
 this module touches the device or the jit cache — all instrumentation is
 host-side bookkeeping around (never inside) the compiled dispatches, so
 tokens are bit-identical with tracing on, off, or unconfigured, and no
-new compile-cache axis exists. Overhead is measured, not assumed:
-``benchmarks/bench_observability.py`` gates the traced/untraced tok/s
-delta at < 3% and asserts bit-identity.
+new compile-cache axis exists. With tracing off a span costs two clock
+reads and one counter update, and no annotation object is made. What
+tracing on costs end to end is measured on the chip (PERF.md, "Findings").
 """
 
 from __future__ import annotations
@@ -40,29 +45,43 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import json
+import threading
+import weakref
 from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.runtime import clock as rtclock
 
-__all__ = ["MetricSpec", "SERVING_METRICS", "PHASES",
+__all__ = ["MetricSpec", "SERVING_METRICS", "PHASES", "DRIVER_PHASES",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "TraceRecorder", "Observability"]
+           "TraceRecorder", "Observability", "CompileMonitor",
+           "compile_monitor"]
 
 
 # ---------------------------------------------------------------------------
 # the frozen metric schema (v1.3)
 # ---------------------------------------------------------------------------
 
-#: engine-step phase names (trace span names on the engine track, and the
-#: ``serving_phase_<name>_seconds_total`` counter suffixes). ``page_maint``
-#: nests inside whichever phase triggered the page bookkeeping (admit,
-#: prefill, or decode), so its seconds are also counted by its parent.
+#: the driver loop's phases (``EngineDriver``): ``driver_loop`` encloses
+#: one pass of the loop, the engine step included; the others nest in it —
+#: taking the driver's lock, serving calls/cancels/frontend deadlines,
+#: offering the fair queue's head to the engine, mirroring tokens to
+#: handles, and parking while there is no work
+DRIVER_PHASES = ("driver_loop", "driver_lock", "driver_calls",
+                 "driver_offer", "driver_pump", "driver_idle")
+
+#: phase names (trace span names on the engine track, and the
+#: ``serving_phase_<name>_seconds_total`` counter suffixes): the engine
+#: step's phases, then the driver loop's. ``page_maint`` nests inside
+#: whichever phase triggered the page bookkeeping (admit, prefill, or
+#: decode), so its seconds are also counted by its parent; the
+#: ``*_prepare`` phases build a dispatch's host arrays before it.
 PHASES = ("sweep", "admit", "prefill_dispatch", "prefill_sync",
           "sample_collect", "decode_dispatch", "decode_sync", "collect",
-          "page_maint")
+          "page_maint", "decode_prepare", "prefill_prepare") + DRIVER_PHASES
 
 #: default latency histogram bucket upper bounds, seconds
 LATENCY_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
@@ -85,7 +104,7 @@ class MetricSpec:
 def _phase_specs() -> Tuple[MetricSpec, ...]:
     return tuple(
         MetricSpec(f"serving_phase_{p}_seconds_total", "counter", "seconds",
-                   f"cumulative host seconds spent in the '{p}' step phase")
+                   f"cumulative host seconds spent in the '{p}' phase")
         for p in PHASES)
 
 
@@ -121,6 +140,13 @@ SERVING_METRICS: Tuple[MetricSpec, ...] = (
                "prompt tokens consumed by prefill dispatches"),
     MetricSpec("serving_trace_dropped_total", "counter", "1",
                "trace events dropped by the bounded ring buffer"),
+    MetricSpec("serving_compiles_total", "counter", "1",
+               "XLA backend compiles in this process (a persistent-cache "
+               "hit counts too, with its read's duration)"),
+    MetricSpec("serving_compile_seconds_total", "counter", "seconds",
+               "seconds of XLA backend compiles in this process"),
+    MetricSpec("serving_compile_cache_hits_total", "counter", "1",
+               "persistent compilation-cache hits in this process"),
     *_phase_specs(),
     # ---- fleet gauges
     MetricSpec("serving_queue_depth", "gauge", "1",
@@ -533,6 +559,77 @@ class TraceRecorder:
 
 
 # ---------------------------------------------------------------------------
+# XLA compiles (one process-wide jax.monitoring listener)
+# ---------------------------------------------------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class CompileMonitor:
+    """Backend compiles of this process (count and seconds) and
+    persistent-cache hits, from JAX's monitoring events.
+
+    A compile that finishes on a thread an :class:`Observability` has
+    claimed (:meth:`claim_thread`; the engine driver claims its own) is
+    also handed to that bundle, which records a ``compile`` span when
+    tracing. Get the one instance with :func:`compile_monitor`."""
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+        self.hits = 0
+        self._lock = threading.Lock()
+        self._sinks: Dict[int, "weakref.ref[Observability]"] = {}
+
+    def _duration(self, event: str, duration: float, **kw) -> None:
+        if event != _COMPILE_EVENT:
+            return
+        with self._lock:
+            self.count += 1
+            self.seconds += duration
+        ref = self._sinks.get(threading.get_ident())
+        obs = ref() if ref is not None else None
+        if obs is not None:
+            obs.compiled(duration, kw.get("fun_name"))
+
+    def _event(self, event: str, **_) -> None:
+        if event == _CACHE_HIT_EVENT:
+            with self._lock:
+                self.hits += 1
+
+    @contextlib.contextmanager
+    def claim_thread(self, obs: "Observability"):
+        """Hand the compiles that finish on the calling thread to ``obs``
+        for the duration of the block."""
+        ident = threading.get_ident()
+        ref = weakref.ref(obs)
+        self._sinks[ident] = ref
+        try:
+            yield
+        finally:
+            if self._sinks.get(ident) is ref:
+                del self._sinks[ident]
+
+
+_MONITOR: Optional[CompileMonitor] = None
+_MONITOR_LOCK = threading.Lock()
+
+
+def compile_monitor() -> CompileMonitor:
+    """The process-wide :class:`CompileMonitor`, registered with
+    ``jax.monitoring`` on first use."""
+    global _MONITOR
+    with _MONITOR_LOCK:
+        if _MONITOR is None:
+            m = CompileMonitor()
+            jax.monitoring.register_event_duration_secs_listener(m._duration)
+            jax.monitoring.register_event_listener(m._event)
+            _MONITOR = m
+    return _MONITOR
+
+
+# ---------------------------------------------------------------------------
 # the bundle the engine carries
 # ---------------------------------------------------------------------------
 
@@ -576,18 +673,49 @@ class Observability:
     def span(self, name: str, track: Tuple[str, int] = TRACK_ENGINE,
              cat: str = "phase", args: Optional[Dict[str, Any]] = None):
         """Time a host-side section: accumulates into the phase counter
-        (when ``name`` is a known engine phase) and records a trace span
-        (when tracing is on). Timestamps come from the bundle clock, so a
+        (when ``name`` is a known phase) and, when tracing is on, records a
+        trace span and opens a ``jax.profiler.TraceAnnotation`` of the same
+        name around the body. Timestamps come from the bundle clock, so a
         VirtualClock yields exact, deterministic spans."""
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            t1 = self.clock()
-            if name in self.phase_seconds:
-                self.phase_seconds[name] += t1 - t0
-            if self.trace is not None:
+        if self.trace is None:
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                if name in self.phase_seconds:
+                    self.phase_seconds[name] += self.clock() - t0
+            return
+        with jax.profiler.TraceAnnotation(name):
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                t1 = self.clock()
+                if name in self.phase_seconds:
+                    self.phase_seconds[name] += t1 - t0
                 self.trace.complete(name, track, t0, t1, cat=cat, args=args)
+
+    @contextlib.contextmanager
+    def step_annotation(self, step_num: int):
+        """While tracing, a ``jax.profiler.StepTraceAnnotation`` named
+        ``step`` around one engine step (the recorder's own ``step`` span
+        is written by the engine); nothing otherwise."""
+        if self.trace is None:
+            yield
+            return
+        with jax.profiler.StepTraceAnnotation("step", step_num=step_num):
+            yield
+
+    def compiled(self, duration: float, fun_name: Optional[str]) -> None:
+        """A compile of ``duration`` seconds just finished on a thread this
+        bundle claimed (:meth:`CompileMonitor.claim_thread`): a ``compile``
+        span ending now, on the engine track, while tracing."""
+        if self.trace is not None:
+            t = self.clock()
+            self.trace.complete("compile", TRACK_ENGINE, t - duration, t,
+                                cat="compile",
+                                args={"fun_name": fun_name} if fun_name
+                                else None)
 
     def instant(self, name: str, track: Tuple[str, int], t: float,
                 args: Optional[Dict[str, Any]] = None) -> None:
@@ -604,6 +732,7 @@ class Observability:
             "an Observability binds to exactly one engine"
         self._engine = engine
         reg, e = self.registry, engine
+        mon = compile_monitor()
         for name, fn in (
             ("serving_requests_submitted_total", lambda: e.submitted),
             ("serving_requests_completed_total", lambda: e.completed),
@@ -619,6 +748,9 @@ class Observability:
             ("serving_prefill_tokens_total", lambda: e.prefill_tokens),
             ("serving_trace_dropped_total",
              lambda: self.trace.dropped if self.trace is not None else 0),
+            ("serving_compiles_total", lambda: mon.count),
+            ("serving_compile_seconds_total", lambda: mon.seconds),
+            ("serving_compile_cache_hits_total", lambda: mon.hits),
         ):
             reg.counter(name, poll=fn, unit=SPEC_BY_NAME[name].unit,
                         help=SPEC_BY_NAME[name].help)
@@ -679,6 +811,18 @@ class Observability:
             self.instant("submitted", request_track(h.uid), h.t_submit,
                          args={"uid": h.uid, "prompt_tokens": len(h.prompt),
                                "truncated": h.truncated})
+
+    def frontend_queued(self, uid: int, t_submit: float, t_end: float,
+                        finish_reason: Optional[str] = None) -> None:
+        """The wait in the frontend's fair queue: from the driver taking
+        the request to offering it to the engine (``t_end``), or to its
+        retirement there (shed, deadline, cancel: ``finish_reason``)."""
+        if self.trace is not None:
+            self.trace.complete(
+                "frontend_queued", request_track(uid), t_submit, t_end,
+                cat="lifecycle",
+                args=({"finish_reason": finish_reason} if finish_reason
+                      else None))
 
     def request_admitted(self, h, slot: int,
                          pages: Optional[Dict[str, int]] = None) -> None:

@@ -13,6 +13,10 @@ The keystone assertions:
 * **Single clock** — a static guard bans raw wall-clock calls from the
   serving and model layers (everything routes through
   ``repro.runtime.clock``, which a ``VirtualClock`` substitutes).
+* **A tiled driver thread** — under the ``EngineDriver`` the driver loop's
+  spans and the step's leave no part of the thread uncovered, tracing on
+  opens profiler annotations and tracing off none, the frontend's wait is
+  a histogram and a span that agree, and compiles are counted.
 """
 
 import json
@@ -30,11 +34,13 @@ from repro.runtime.monitor import (HEARTBEAT_SCHEMA, HeartbeatMonitor,
 from repro.serving import (EngineConfig, FaultInjector, FaultPlan,
                            SamplingParams, SerialAdmitEngine, ServingEngine,
                            VirtualClock)
-from repro.serving.observability import (LATENCY_BUCKETS, PHASES,
-                                         SERVING_METRICS, SPEC_BY_NAME,
+from repro.serving.frontend import EngineDriver
+from repro.serving.observability import (DRIVER_PHASES, LATENCY_BUCKETS,
+                                         PHASES, SERVING_METRICS,
+                                         SPEC_BY_NAME, TRACK_ENGINE,
                                          Histogram, MetricsRegistry,
                                          Observability, TraceRecorder,
-                                         request_track)
+                                         compile_monitor, request_track)
 
 ENGINES = [ServingEngine, SerialAdmitEngine]
 
@@ -240,20 +246,31 @@ class TestEngineTracing:
     @pytest.mark.parametrize("cls", ENGINES)
     def test_zero_perturbation(self, small_model, cls):
         """Bit-identical tokens with tracing on, off, and unconfigured —
-        and no extra jit compilations from instrumentation."""
+        cooperatively and through the driver's thread — and no extra jit
+        compilations from instrumentation."""
         cfg, params = small_model
         sp = SamplingParams(max_new_tokens=6, temperature=0.8, seed=11)
+        prompts = [([5, 9, 17, 2], sp),
+                   ([1, 2], SamplingParams(max_new_tokens=4))]
         runs = []
-        for obs in (None, Observability(trace=False), Observability(trace=True)):
-            eng = cls(params, cfg, EngineConfig(max_slots=2, capacity=32),
-                      observability=obs)
-            hs = [eng.submit([5, 9, 17, 2], sp),
-                  eng.submit([1, 2], SamplingParams(max_new_tokens=4))]
-            eng.run()
-            runs.append(([h.result().tokens for h in hs],
-                         eng.compile_stats()["n_prefill_compiles"],
-                         eng.compile_stats()["n_decode_compiles"]))
-        assert runs[0] == runs[1] == runs[2]
+        for via_driver in (False, True):
+            for obs in (None, Observability(trace=False),
+                        Observability(trace=True)):
+                eng = cls(params, cfg, EngineConfig(max_slots=2, capacity=32),
+                          observability=obs)
+                if via_driver:
+                    drv = EngineDriver(eng).start()
+                    hs = [drv.submit(p, q) for p, q in prompts]
+                    tokens = [h.result(timeout=120).tokens for h in hs]
+                    drv.close()
+                else:
+                    hs = [eng.submit(p, q) for p, q in prompts]
+                    eng.run()
+                    tokens = [h.result().tokens for h in hs]
+                runs.append((tokens,
+                             eng.compile_stats()["n_prefill_compiles"],
+                             eng.compile_stats()["n_decode_compiles"]))
+        assert all(r == runs[0] for r in runs), runs
 
     def test_step_phase_spans_and_counters(self, small_model):
         eng, _ = traced_engine(small_model)
@@ -293,6 +310,196 @@ class TestEngineTracing:
         d = eng.obs.digest()
         assert d["serving_requests_completed_total"] == snap.completed
         assert "ttft_p50_s" in d
+
+
+# ---------------------------------------------------------------------------
+# the driver thread: tiling spans, profiler annotations, the frontend's wait,
+# compiles
+# ---------------------------------------------------------------------------
+
+def _ticking(fn, clock, dt):
+    """``fn`` with the virtual clock advanced ``dt`` on entry and on exit:
+    time passes only inside the work the driver does."""
+    def wrapped(*a, **k):
+        clock.advance(dt)
+        try:
+            return fn(*a, **k)
+        finally:
+            clock.advance(dt)
+    return wrapped
+
+
+def _uncovered(lo, hi, pieces):
+    """Length of [lo, hi] that no (a, b) of ``pieces`` covers."""
+    t, hole = lo, 0.0
+    for a, b in sorted(pieces):
+        if b <= t:
+            continue
+        hole += max(0.0, min(a, hi) - t)
+        t = max(t, b)
+        if t >= hi:
+            break
+    return hole + max(0.0, hi - t)
+
+
+class TestDriverTracing:
+    def test_driver_spans_tile_the_thread(self, small_model):
+        """With time passing only inside the work of each phase (the
+        driver's lock, calls, offers, fan-out and parking; the step's
+        admission, sampling and collection), a chat-like load through the
+        driver leaves no hole over 1 us of virtual time between
+        ``driver_loop`` spans or inside one outside its children."""
+        eng, clock = traced_engine(small_model, EngineConfig(
+            max_slots=2, capacity=32, prefill_chunk=4))
+        drv = EngineDriver(eng)
+        dt = 1e-3
+        for name in ("_service_calls_locked", "_apply_cancels_locked",
+                     "_sweep_frontend_locked", "_offer_locked", "_pump"):
+            setattr(drv, name, _ticking(getattr(drv, name), clock, dt))
+        drv._cond.acquire = _ticking(drv._cond.acquire, clock, dt)
+        drv._cond.wait = _ticking(drv._cond.wait, clock, dt)
+        for name in ("_admit", "_sample_first", "_collect", "_fleet_arrays"):
+            setattr(eng, name, _ticking(getattr(eng, name), clock, dt))
+        drv.start()
+        hs = [drv.submit(list(range(1, 2 + 3 * i)),
+                         SamplingParams(max_new_tokens=3 + i, seed=i))
+              for i in range(5)]
+        for h in hs:
+            assert h.result(timeout=120).finish_reason == "length"
+        drv.close()
+        evs = [e for e in eng.obs.trace.events()
+               if e.track == TRACK_ENGINE and e.ph == "X"]
+        loops = sorted((e.ts, e.ts + e.dur) for e in evs
+                       if e.name == "driver_loop")
+        children = [(e.ts, e.ts + e.dur) for e in evs
+                    if e.name in DRIVER_PHASES[1:] + ("step",)]
+        assert len(loops) > len(hs)
+        assert {e.name for e in evs} >= set(DRIVER_PHASES) | {
+            "step", "decode_prepare", "prefill_prepare"}
+        for (a0, a1), (b0, _) in zip(loops, loops[1:]):
+            assert b0 - a1 <= 1e-6
+        for lo, hi in loops:
+            inside = [(a, b) for a, b in children if lo <= a and b <= hi]
+            assert _uncovered(lo, hi, inside) <= 1e-6, (lo, hi)
+        # every phase fed its frozen seconds counter
+        reg = eng.obs.registry
+        for p in DRIVER_PHASES:
+            assert reg.value(f"serving_phase_{p}_seconds_total") > 0.0
+
+    @pytest.mark.parametrize("trace", [False, True])
+    def test_annotations_only_while_tracing(self, small_model, monkeypatch,
+                                            trace):
+        """Tracing off: no recorder, and not one profiler annotation is
+        made; tracing on: every span and step opens one."""
+        made = {"span": 0, "step": 0}
+
+        def counting(kind, cls):
+            class Counted(cls):
+                def __init__(self, *a, **k):
+                    made[kind] += 1
+                    super().__init__(*a, **k)
+            return Counted
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            counting("span", jax.profiler.TraceAnnotation))
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                            counting("step",
+                                     jax.profiler.StepTraceAnnotation))
+        cfg, params = small_model
+        eng = ServingEngine(params, cfg, EngineConfig(max_slots=2,
+                                                      capacity=32),
+                            observability=Observability(trace=trace))
+        drv = EngineDriver(eng).start()
+        h = drv.submit([1, 2, 3], SamplingParams(max_new_tokens=4))
+        assert h.result(timeout=120).finish_reason == "length"
+        drv.close()
+        if not trace:
+            assert eng.obs.trace is None
+            assert made == {"span": 0, "step": 0}
+        else:
+            evs = [e for e in eng.obs.trace.events()
+                   if e.track == TRACK_ENGINE
+                   and e.name not in ("step", "compile")]
+            assert made["step"] == eng.engine_steps > 0
+            assert made["span"] == len(evs) > 0
+
+    def test_frontend_wait_histogram_matches_spans(self, small_model):
+        """One slot and a queue of requests: the fair-queue wait histogram
+        holds exactly t_offer - t_submit of each, and so do the
+        ``frontend_queued`` spans."""
+        cfg, params = small_model
+        eng = ServingEngine(params, cfg, EngineConfig(max_slots=1,
+                                                      capacity=32),
+                            observability=Observability(trace=True))
+        drv = EngineDriver(eng).start()
+        hs = [drv.submit([1 + i, 2, 3], SamplingParams(max_new_tokens=3))
+              for i in range(5)]
+        for h in hs:
+            h.result(timeout=120)
+        drv.close()
+        waits = np.asarray([h.t_offer - h.t_submit for h in hs])
+        assert (waits >= 0).all() and waits.max() > 0
+        spans = {e.track[1]: e for e in eng.obs.trace.events()
+                 if e.name == "frontend_queued"}
+        durs = np.asarray([spans[h.uid].dur for h in hs])
+        hist = eng.obs.registry.get_histogram(
+            "serving_frontend_queue_wait_seconds")
+        assert hist.count == len(hs)
+        for q in (50, 90, 99):
+            assert hist.percentile(q) == float(np.percentile(waits, q)) \
+                == float(np.percentile(durs, q))
+        for h in hs:
+            assert spans[h.uid].ts == h.t_submit
+            assert h.t_submit <= h.t_offer <= h.t_admit
+
+    def test_frontend_shed_ends_its_span_with_the_reason(self, small_model):
+        cfg, params = small_model
+        eng = ServingEngine(params, cfg, EngineConfig(max_slots=1,
+                                                      capacity=32),
+                            observability=Observability(trace=True))
+        drv = EngineDriver(eng)
+        drv.drain(timeout=0)            # not started: intake is closed
+        h = drv.submit([1, 2], SamplingParams(max_new_tokens=2))
+        assert h.finish_reason == "rejected"
+        [span] = [e for e in eng.obs.trace.events()
+                  if e.name == "frontend_queued"]
+        assert span.track == request_track(h.uid)
+        assert span.ts == h.t_submit and span.ts + span.dur == h.t_done
+        assert span.args["finish_reason"] == "rejected"
+
+    def test_compile_counter_counts_backend_compiles(self, small_model):
+        cfg, params = small_model
+        eng = ServingEngine(params, cfg, EngineConfig(max_slots=1,
+                                                      capacity=32))
+        reg = eng.obs.registry
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        x = np.ones((7, 13), np.float32)   # a shape no other test jits
+        n0 = reg.value("serving_compiles_total")
+        s0 = reg.value("serving_compile_seconds_total")
+        f(x)
+        assert reg.value("serving_compiles_total") == n0 + 1
+        assert reg.value("serving_compile_seconds_total") > s0
+        f(x)
+        assert reg.value("serving_compiles_total") == n0 + 1
+        assert reg.value("serving_compiles_total") == compile_monitor().count
+
+    def test_compile_on_the_driver_thread_is_a_span(self, small_model):
+        """The driver's first step compiles its programs: each compile is
+        a ``compile`` span on the engine track, inside a step."""
+        cfg, params = small_model
+        eng = ServingEngine(params, cfg, EngineConfig(max_slots=1,
+                                                      capacity=24),
+                            observability=Observability(trace=True))
+        drv = EngineDriver(eng).start()
+        drv.submit([3, 1, 4], SamplingParams(max_new_tokens=2)).result(
+            timeout=120)
+        drv.close()
+        evs = eng.obs.trace.events()
+        compiles = [e for e in evs if e.name == "compile"]
+        steps = [e for e in evs if e.name == "step"]
+        assert compiles and all(e.track == TRACK_ENGINE for e in compiles)
+        assert all(any(s.ts <= c.ts + c.dur <= s.ts + s.dur for s in steps)
+                   for c in compiles)
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,10 @@ off around the compiles (an entry written for a described chip cannot be
 read back without one).
 """
 
+import importlib.util
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -138,6 +141,77 @@ def test_chunk_attention_paged_compiles(one_chip, L, page, cache_dtype):
             interpret=False)
 
     _compile(fn, *args, *(scales if int8 else []))
+
+
+def _bench_trace():
+    """The benchmark's trace reduction (``bench/harness/trace.py``), which
+    finds a kernel in a device trace by its op's name."""
+    path = Path(__file__).resolve().parents[1] / "bench" / "harness" / \
+        "trace.py"
+    spec = importlib.util.spec_from_file_location("bench_harness_trace", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod       # its dataclass looks itself up there
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kernel_ops(compiled):
+    return [ln.strip() for ln in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def _instruction(op: str) -> str:
+    """``%name.3`` of ``[ROOT ]%name.3 = type custom-call(...)``."""
+    return op.split(" = ", 1)[0].removeprefix("ROOT ").lstrip("%")
+
+
+def test_kernels_carry_their_names(one_chip):
+    """The compiled custom calls are named ``ternary_matmul`` and
+    ``chunk_attention`` (ring and paged), so a device trace names them: the
+    benchmark finds chunk attention by that name, with its signature
+    fallback ruled out (the op's target hidden from it)."""
+    trace = _bench_trace()
+    d, n = 1536, 8960
+    ternary = _compile(lambda x, t1, t2, a: tm_ops._pallas(
+        x, t1, t2, a, G, interpret=False),
+        _spec(one_chip, (8, d), jnp.bfloat16),
+        _spec(one_chip, (n, d // 4), jnp.uint8),
+        _spec(one_chip, (n, d // 4), jnp.uint8),
+        _spec(one_chip, (n, d // G, 2), jnp.float32))
+    [op] = _kernel_ops(ternary)
+    assert _instruction(op).startswith("ternary_matmul")
+    assert trace.kernel_of(op) == "ternary_matmul"
+
+    L = 32
+    ring = [_spec(one_chip, (SLOTS, CAP, KV, HD), jnp.bfloat16)] * 2 + [
+        _spec(one_chip, (SLOTS, CAP), jnp.int32),
+        _spec(one_chip, (SLOTS, L), jnp.int32),
+        _spec(one_chip, (SLOTS,), jnp.int32)]
+    tile = lane_tile(CAP, _select_tile(CAP, L))
+    page, n_pages = 16, CAP // 16
+    pool = SLOTS * n_pages + 1
+    paged = [_spec(one_chip, (pool, page, KV, HD), jnp.bfloat16)] * 2 + [
+        _spec(one_chip, (pool, page), jnp.int32),
+        _spec(one_chip, (SLOTS, n_pages), jnp.int32),
+        _spec(one_chip, (SLOTS, L), jnp.int32),
+        _spec(one_chip, (SLOTS,), jnp.int32)]
+    compiled = [
+        _compile(lambda q, kn, vn, kc, vc, pb, pos, lens:
+                 chunk_attention_pallas(q, kn, vn, kc, None, vc, None, pb,
+                                        pos, lens, tile=tile,
+                                        interpret=False),
+                 *_attn_inputs(one_chip, L), *ring),
+        _compile(lambda q, kn, vn, kp, vp, pp, table, pos, lens:
+                 chunk_attention_paged_pallas(
+                     q, kn, vn, kp, None, vp, None, pp, table, pos, lens,
+                     tile=lane_tile(page, paged_tile(page, L)),
+                     interpret=False),
+                 *_attn_inputs(one_chip, L), *paged)]
+    for c in compiled:
+        [op] = _kernel_ops(c)
+        assert _instruction(op).startswith("chunk_attention")
+        hidden = op.replace("tpu_custom_call", "hidden_target")
+        assert trace.kernel_of(hidden) == "chunk_attention"
 
 
 def test_quantizer_fits_hbm_at_lm_head(one_chip):
