@@ -33,6 +33,7 @@ The compiled programs go to JAX's persistent cache
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import shutil
 import sys
@@ -199,6 +200,7 @@ def parity_phase(params, seed: int):
 
     from repro.kernels.chunk_attention import (chunk_attention,
                                                chunk_attention_paged)
+    from repro.kernels.chunk_attention.ref import to_storage
     from repro.kernels.ternary_matmul.ops import ternary_matmul
 
     def versus_xla(op, xla_backend, *args):
@@ -211,6 +213,17 @@ def parity_phase(params, seed: int):
         return ternary_matmul(x, qk.t1p, qk.t2p, qk.alpha,
                               group_size=qk.group_size, backend=backend)
 
+    def stacked(k, ks, v, vs, pos):
+        """Two-layer ring storage holding the case at layer 1; layer 0 has
+        other values at the same positions, so reading it shows."""
+        def two(a, other):
+            return None if a is None else jnp.stack([other, a])
+        return to_storage(two(k, -k), two(ks, None if ks is None else 2 * ks),
+                          two(v, -v), two(vs, None if vs is None else 2 * vs),
+                          two(pos, pos))
+
+    ring_op = functools.partial(chunk_attention, layer=1)
+    paged_op = functools.partial(chunk_attention_paged, layer=1)
     rng = np.random.default_rng(seed)
     errs = {}
     mlp = params["blocks"]["b0"]["mlp"]
@@ -247,28 +260,30 @@ def parity_phase(params, seed: int):
                 pb[r, written % cap] = written
             positions = jnp.asarray(pos0[:, None] + np.arange(L), jnp.int32)
             lengths = jnp.full((b,), L, jnp.int32)
-            args = (q, kn, vn, kc, ks, vc, vs, jnp.asarray(pb), positions,
-                    lengths)
+            args = (q, kn, vn, *stacked(kc, ks, vc, vs, jnp.asarray(pb)),
+                    positions, lengths)
             tag = f"L={L} {'int8' if int8 else 'bf16'}"
             errs[("chunk_attention", f"ring {tag}")] = versus_xla(
-                chunk_attention, "stream", *args)
-            # the same ring as pages in a shuffled pool (page 0 = null page)
+                ring_op, "stream", *args)
+            # the same ring as pages in a shuffled pool (page 0 = null page,
+            # pages rounded up to a multiple of 8 as the engine allocates)
             n_pages = cap // ps
             perm = rng.permutation(b * n_pages) + 1
             table = jnp.asarray(perm.reshape(b, n_pages), jnp.int32)
+            n_phys = -(-(b * n_pages + 1) // 8) * 8
 
             def pool(a):
                 if a is None:
                     return None
                 pages = a.reshape((b * n_pages, ps) + a.shape[2:])
-                out = jnp.zeros((b * n_pages + 1,) + pages.shape[1:],
-                                a.dtype)
+                out = jnp.zeros((n_phys,) + pages.shape[1:], a.dtype)
                 return out.at[perm].set(pages)
             pos_pool = pool(jnp.asarray(pb)).at[0].set(-1)
-            pargs = (q, kn, vn, pool(kc), pool(ks), pool(vc), pool(vs),
-                     pos_pool, table, positions, lengths)
+            pargs = (q, kn, vn, *stacked(pool(kc), pool(ks), pool(vc),
+                                         pool(vs), pos_pool),
+                     table, positions, lengths)
             errs[("chunk_attention", f"paged {tag}")] = versus_xla(
-                chunk_attention_paged, "stream", *pargs)
+                paged_op, "stream", *pargs)
     for (op, case), v in errs.items():
         log(f"parity {op} {case}: ||pallas-xla||/||xla|| = {v!r} "
             f"(limit {PARITY_LIMITS[op]:g})")

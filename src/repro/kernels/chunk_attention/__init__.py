@@ -12,7 +12,7 @@ Op contract (stable; ``ops.chunk_attention``)
 ::
 
   chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
-                  pos_buf, positions, lengths, *, window=None,
+                  pos_buf, positions, lengths, *, layer=0, window=None,
                   backend="auto", tile=None)
       -> (B, L, KV, G, hd) float32
 
@@ -21,11 +21,15 @@ Inputs:
               (head h = kv * G + g, matching ``models.attention``).
   k_new/v_new:(B, L, KV, hd) the chunk's fresh keys/values (float — scored
               at full activation precision, *before* any cache write).
-  k_cache/v_cache: (B, cap, KV, hd) the ring **before** this chunk is
-              written — int8 (with per-(slot, kv-head) absmax ``k_scale``/
-              ``v_scale`` (B, cap, KV) f32) or float (scales = None).
-  pos_buf:    (B, cap) int32 absolute position held by each ring slot
+  k_cache/v_cache: (NL, B, cap, KV·hd) the stacked ring of NL layers
+              **before** this chunk is written (storage below) — int8
+              (with per-(slot, kv-head) absmax ``k_scale``/``v_scale``
+              (NL, KV, B, cap) f32, kv-head-major) or float (scales =
+              None).
+  pos_buf:    (NL, B, cap) int32 absolute position held by each ring slot
               (-1 = empty).
+  layer:      which of the NL layers is read (an int, or a traced int32
+              scalar — the layer scan's index).
   positions:  (B, L) int32 absolute position of each chunk query.
   lengths:    (B,) int32 valid token count per row. Rows with length 0 are
               no-ops (their output is unconsumed garbage, finite by
@@ -42,6 +46,16 @@ bitwise on the visible set; floats may reorder):
   decode semantics at L = 1 (the entry at distance exactly ``cap`` is the
   one the token's own write evicts, so it is masked rather than read) and
   covers ring wrap and per-row chunk offsets with no special cases.
+
+Ring storage (who allocates and writes it): ``models.attention.cache_init``
+allocates each attention layer's ring in the layout above, and
+``models.transformer`` stacks the layers of a scan on a leading axis and
+carries the stack through the layer scan — the kernel reads it at the
+scan's layer index, and the model writes each chunk's (or decode token's)
+k/v/pos with one scatter at ``[layer, row, slot]`` after the read. Nothing
+else copies, slices or relays out the ring. ``ref.ring_layer`` takes one
+layer out in the oracle's logical layout ((B, cap, KV, hd), (B, cap, KV),
+(B, cap)); ``ref.to_storage`` is its inverse.
 
 Backends:
   * ``pallas``       — grid (batch, kv-head, tile); int8 tiles stream
@@ -69,15 +83,16 @@ Paged variant (stable; ``ops.chunk_attention_paged``)
 ::
 
   chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
-                        pos_pool, table, positions, lengths, *,
+                        pos_pool, table, positions, lengths, *, layer=0,
                         window=None, backend="auto", tile=None)
       -> (B, L, KV, G, hd) float32
 
 The KV ring virtualized into fixed-size pages: ``k_pool``/``v_pool`` are
-(P, page_size, KV, hd) *physical* pages shared by the whole batch (int8
-with (P, page_size, KV) scales, or float with scales None), ``pos_pool``
-(P, page_size) their per-entry absolute positions, and ``table``
-(B, n_pages) int32 maps each row's logical page to a physical one. The op
+(NL, P, page_size, KV·hd) *physical* pages shared by the whole batch,
+stacked over layers like the ring (int8 with (NL, KV, P, page_size)
+scales, or float with scales None), ``pos_pool`` (NL, P, page_size) their
+per-entry absolute positions, and ``table`` (B, n_pages) int32 maps
+each row's logical page of layer ``layer`` to a physical one. The op
 computes exactly ``chunk_attention`` over the virtual ring
 ``ring[b, p·ps + o] = pool[table[b, p], o]`` (``ref.gather_pages``) with
 capacity ``n_pages · page_size`` — the same mask rule in logical

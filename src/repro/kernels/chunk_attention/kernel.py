@@ -8,17 +8,28 @@ online-softmax state (running max, sum, accumulator) in VMEM scratch; the
 last tile folds the chunk's own keys in and writes the output. The
 (G·L, cap) score block never exists: scores live as (G·L, tile).
 
-Layout. The wrapper hands the kernel lane-dense views, so every block
-obeys the TPU's (8, 128) rule at serving widths (hd a multiple of 128):
-the cache (B, cap, KV, hd) is viewed as (B, cap, KV·hd) and the BlockSpec
-takes lane block ``kv`` of width hd; int8 scales are transposed to
-(KV·B, 1, cap) rows and applied to the scores and the softmax weights
-(q·(s k) = s (q·k)), so no per-slot column ever needs a lane→sublane
-relayout; positions come in both orientations (queries as a (G·L, 1)
-column, keys as a (1, tile) row) so the mask is a plain broadcast. Those
+Layout. The kernel reads the ring where the model keeps it: one stacked
+buffer per leaf for all layers of a scan, stored as the kernel reads it,
+so no view or relayout is made per call (package docstring, "Ring
+storage"). Keys and values are lane-dense (layers, B, cap, KV·hd) and the
+BlockSpec takes lane block ``kv`` of width hd, which obeys the TPU's
+(8, 128) rule at serving widths (hd a multiple of 128). Slot positions
+(layers, rows, cap) and int8 scales (layers, KV, rows, cap), kv-head-major,
+are read as row blocks of 8 rows (every row where the row count is not a
+multiple of 8), of which the kernel takes its own (1, tile) row. So no
+stored leaf has a unit or short axis that XLA could lay out another way
+than the kernel, which would cost a relayout of the whole stack per call.
+Scales apply to the scores and the softmax weights (q·(s k) = s (q·k)),
+so no per-slot column ever needs a lane→sublane relayout. Query positions
+come as a (G·L, 1) column, so the mask is a plain broadcast. Those
 (1, tile) rows need a tile that is a multiple of 128 or every slot;
-``ops.lane_tile`` picks one for the compiled kernel.
-Lengths (and the page table) ride in SMEM as scalar prefetch.
+``ops.lane_tile`` picks one for the compiled kernel. The layer index
+rides in SMEM as scalar prefetch, in one vector after the row lengths (the
+page table, when paged, is a second), and every ring index map returns
+block (layer, row, ...): the layer scan hands the kernel the whole stack
+and no per-layer slice is ever copied out. (One vector, not two, also
+keeps the call's operand count, 11 ring and 12 paged, which the
+benchmark's trace reader matches as a fallback to the kernel's name.)
 
 Ring wrap, sliding windows, and right-padding are all mask regions of the
 same rule (see the package docstring): visible iff 0 <= qpos - kpos <
@@ -65,14 +76,14 @@ def _online_update(q2, k, v, valid, ks, vs, m, l, acc):
     return m_new, l, acc
 
 
-def _kernel(len_ref, *refs, paged: bool, scale: float, reach: int,
-            scaled: bool):
+def _kernel(meta_ref, *refs, paged: bool, scale: float, reach: int,
+            scaled: bool, row_block: int, tpp: int):
     if paged:
-        refs = refs[1:]  # the page table is only read by the index maps
+        tbl_ref, refs = refs[0], refs[1:]
     (q_ref, kn_ref, vn_ref, k_ref, ks_ref, v_ref, vs_ref, pb_ref, qp_ref,
      kp_ref, o_ref, m_sc, l_sc, acc_sc) = refs
     t = pl.program_id(2)
-    length = len_ref[pl.program_id(0)]
+    length = meta_ref[pl.program_id(0)]
     last = pl.num_programs(2) - 1
 
     @pl.when(t == 0)
@@ -83,11 +94,15 @@ def _kernel(len_ref, *refs, paged: bool, scale: float, reach: int,
 
     q2 = q_ref[0, 0].astype(jnp.float32) * scale             # (G·L, hd)
     qpos = qp_ref[0]                                         # (G·L, 1)
-    pb = pb_ref[0]                                           # (1, tile)
+    row = (tbl_ref[pl.program_id(0), t // tpp] if paged
+           else pl.program_id(0))
+    r = pl.ds(row % row_block, 1)                            # own row
+    pb = pb_ref[0, r, :]                                     # (1, tile)
     d = qpos - pb
     valid = (pb >= 0) & (d >= 0) & (d < reach)
-    ks, vs = (ks_ref[0], vs_ref[0]) if scaled else (None, None)
-    m, l, acc = _online_update(q2, k_ref[0], v_ref[0], valid, ks, vs,
+    ks, vs = ((ks_ref[0, 0, r, :], vs_ref[0, 0, r, :]) if scaled
+              else (None, None))
+    m, l, acc = _online_update(q2, k_ref[0, 0], v_ref[0, 0], valid, ks, vs,
                                m_sc[...], l_sc[...], acc_sc[...])
     m_sc[...], l_sc[...], acc_sc[...] = m, l, acc
 
@@ -105,55 +120,59 @@ def _kernel(len_ref, *refs, paged: bool, scale: float, reach: int,
         o_ref[0, 0] = accn / jnp.maximum(ln, 1e-30)          # 0s if unseen
 
 
-def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, *,
+def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, layer, *,
           window, tile, cap, page_table, interpret):
-    """Shared pallas_call. ``kc``/``vc`` are lane-dense (rows, slots,
-    KV·hd) views, ``ks``/``vs`` (rows·KV, 1, slots) scale rows (None for
-    float caches) and ``pos`` (rows, 1, slots); rows are batch rows for
-    the ring and physical pages for the paged pool, where ``page_table``
-    (B, n_pages) maps tile t of row b to its page."""
+    """Shared pallas_call over stacked storage: ``kc``/``vc`` (layers,
+    rows, slots, KV·hd), ``ks``/``vs`` (layers, KV, rows, slots) (None
+    for float caches), ``pos`` (layers, rows, slots); rows are batch rows
+    for the ring and physical pages for the paged pool, where ``page_table``
+    (B, n_pages) maps tile t of row b to its page. Every ring block is
+    read at ``layer``, which rides in SMEM after the row lengths."""
     b, kv, g, L, hd = q.shape
     n_tiles = cap // tile
     reach = min(window, cap) if window else cap
     scaled = ks is not None
     paged = page_table is not None
+    rows, slots = kc.shape[1:3]
+    row_block = 8 if rows % 8 == 0 else rows                 # (8, 128) rule
+    tpp = slots // tile if paged else 1                      # tiles per page
 
-    rows = kc.shape[0]
     if paged:
-        tpp = kc.shape[1] // tile                            # tiles per page
-
         def slot_block(i, t, tbl):
             return tbl[i, t // tpp], t % tpp
     else:
         def slot_block(i, t, tbl):
             return i, t
 
-    def scale_index(i, j, t, _lens, tbl=None):
+    def kv_index(i, j, t, meta, tbl=None):
         row, off = slot_block(i, t, tbl)
-        return j * rows + row, 0, off
+        return meta[b], row, off, j
 
-    def kv_index(i, j, t, _lens, tbl=None):
+    def pos_index(i, j, t, meta, tbl=None):
         row, off = slot_block(i, t, tbl)
-        return row, off, j
+        return meta[b], row // row_block, off
 
-    def pos_index(i, j, t, _lens, tbl=None):
+    def scale_index(i, j, t, meta, tbl=None):
         row, off = slot_block(i, t, tbl)
-        return row, 0, off
+        return meta[b], j, row // row_block, off
 
     def per_row(i, j, t, *_):
         return i, 0, 0
 
     if scaled:
-        scale_spec = pl.BlockSpec((1, 1, tile), scale_index)
+        scale_spec = pl.BlockSpec((1, 1, row_block, tile), scale_index)
     else:  # float cache: 1-entry placeholders, never read
-        ks = vs = jnp.ones((1, 1, 1), jnp.float32)
-        scale_spec = pl.BlockSpec((1, 1, 1), lambda *_: (0, 0, 0))
+        ks = vs = jnp.ones((1, 1, 1, 1), jnp.float32)
+        scale_spec = pl.BlockSpec((1, 1, 1, 1), lambda *_: (0,) * 4)
     head_spec = pl.BlockSpec((1, 1, g * L, hd),
                              lambda i, j, t, *_: (i, j, 0, 0))
     new_spec = pl.BlockSpec((1, L, hd), lambda i, j, t, *_: (i, 0, j))
-    kv_spec = pl.BlockSpec((1, tile, hd), kv_index)
+    kv_spec = pl.BlockSpec((1, 1, tile, hd), kv_index)
 
-    prefetch = (lengths,) + ((page_table,) if paged else ())
+    # one SMEM vector: the B row lengths, then the layer index
+    meta = jnp.concatenate([lengths.astype(jnp.int32),
+                            jnp.reshape(layer, (1,)).astype(jnp.int32)])
+    prefetch = (meta,) + ((page_table,) if paged else ())
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, kv, n_tiles),
@@ -161,7 +180,7 @@ def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, *,
             head_spec,                                       # q
             new_spec, new_spec,                              # k_new, v_new
             kv_spec, scale_spec, kv_spec, scale_spec,        # k, ks, v, vs
-            pl.BlockSpec((1, 1, tile), pos_index),           # slot positions
+            pl.BlockSpec((1, row_block, tile), pos_index),   # slot positions
             pl.BlockSpec((1, g * L, 1), per_row),            # query positions
             pl.BlockSpec((1, 1, L), per_row),                # chunk keys' pos
         ],
@@ -171,7 +190,8 @@ def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, *,
                         pltpu.VMEM((g * L, hd), jnp.float32)],
     )
     kern = functools.partial(_kernel, paged=paged, scale=hd ** -0.5,
-                             reach=reach, scaled=scaled)
+                             reach=reach, scaled=scaled,
+                             row_block=row_block, tpp=tpp)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
@@ -188,47 +208,37 @@ def _call(q, k_new, v_new, kc, ks, vc, vs, pos, positions, lengths, *,
     return out.reshape(b, kv, g, L, hd)
 
 
-def _scale_rows(scale):
-    """(rows, slots, KV) per-slot scales → (KV·rows, 1, slots) lane rows,
-    kv-major (row j·rows + r holds kv head j of ring row / page r)."""
-    if scale is None:
-        return None
-    r, s, kv = scale.shape
-    return scale.transpose(2, 0, 1).reshape(kv * r, 1, s).astype(jnp.float32)
-
-
 def chunk_attention_paged_pallas(q, k_new, v_new, k_pool, k_scale, v_pool,
                                  v_scale, pos_pool, table, positions,
-                                 lengths, *, window=None, tile: int,
+                                 lengths, layer, *, window=None, tile: int,
                                  interpret: bool):
     """Paged Pallas chunk attention. q is (B, KV, G, L, hd) (grid layout);
-    the public op transposes. Pools are (P, page_size, KV, hd) with
-    (P, page_size, KV) scales (int8) or scales None (float); table is
-    (B, n_pages) physical page ids. ``tile`` divides page_size. Returns
-    (B, KV, G, L, hd) f32.
+    the public op transposes. Pools are stacked storage (layers, P,
+    page_size, KV·hd), scales (layers, KV, P, page_size) (int8) or None
+    (float), positions (layers, P, page_size); table is (B, n_pages)
+    physical page ids of layer ``layer``. ``tile`` divides page_size.
+    Returns (B, KV, G, L, hd) f32.
     """
-    P, ps, kv, hd = k_pool.shape
+    ps = k_pool.shape[2]
     n_pages = table.shape[1]
     assert ps % tile == 0, (ps, tile)
-    return _call(q, k_new, v_new, k_pool.reshape(P, ps, kv * hd),
-                 _scale_rows(k_scale), v_pool.reshape(P, ps, kv * hd),
-                 _scale_rows(v_scale), pos_pool.reshape(P, 1, ps),
-                 positions, lengths, window=window, tile=tile,
+    return _call(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale, pos_pool,
+                 positions, lengths, layer, window=window, tile=tile,
                  cap=n_pages * ps, page_table=table.astype(jnp.int32),
                  interpret=interpret)
 
 
 def chunk_attention_pallas(q, k_new, v_new, k_cache, k_scale, v_cache,
-                           v_scale, pos_buf, positions, lengths, *,
+                           v_scale, pos_buf, positions, lengths, layer, *,
                            window=None, tile: int, interpret: bool):
     """Pallas chunk attention. q here is (B, KV, G, L, hd) (grid layout);
-    the public op transposes. ``tile`` divides cap. Returns
+    the public op transposes. The ring is stacked storage (layers, B, cap,
+    KV·hd), scales (layers, KV, B, cap) or None, positions (layers, B,
+    cap), read at ``layer``. ``tile`` divides cap. Returns
     (B, KV, G, L, hd) f32.
     """
-    b, cap, kv, hd = k_cache.shape
+    cap = k_cache.shape[2]
     assert cap % tile == 0, (cap, tile)
-    return _call(q, k_new, v_new, k_cache.reshape(b, cap, kv * hd),
-                 _scale_rows(k_scale), v_cache.reshape(b, cap, kv * hd),
-                 _scale_rows(v_scale), pos_buf.reshape(b, 1, cap),
-                 positions, lengths, window=window, tile=tile, cap=cap,
+    return _call(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale, pos_buf,
+                 positions, lengths, layer, window=window, tile=tile, cap=cap,
                  page_table=None, interpret=interpret)

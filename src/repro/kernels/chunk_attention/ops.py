@@ -178,31 +178,53 @@ def _stream_finish(qf, carry, k_new, v_new, positions, lengths, reach):
     return out.transpose(0, 3, 1, 2, 4)                      # (B,L,KV,G,hd)
 
 
+def _slot_slice(a, layer, off, tile: int, axis: int):
+    """Slots [off, off + tile) on ``axis`` of layer ``layer`` of a stacked
+    storage leaf: one in-place ``dynamic_slice``, no copy of the layer.
+    None passes through (the scales of a float ring)."""
+    if a is None:
+        return None
+    axis %= a.ndim
+    start = [0] * a.ndim
+    start[0], start[axis] = layer, off
+    size = list(a.shape)
+    size[0], size[axis] = 1, tile
+    return jax.lax.dynamic_slice(a, start, size)[0]
+
+
+def _tile_update(qf, carry, k, ks, v, vs, pt, positions, reach, kv):
+    """Dequantize one storage-layout tile and fold it into the carry.
+    k/v (B, tile, KV·hd), ks/vs (KV, B, tile) or None, pt (B, tile) — the
+    same step for the ring and paged walks."""
+    sc = lambda a: None if a is None else _ref.scale_logical(a)
+    k = _ref._deq(_ref.kv_logical(k, kv), sc(ks))         # (B, tile, KV, hd)
+    v = _ref._deq(_ref.kv_logical(v, kv), sc(vs))
+    d = positions[:, :, None] - pt[:, None, :]               # (B, L, tile)
+    valid = (pt[:, None, :] >= 0) & (d >= 0) & (d < reach)
+    return _stream_update(qf, carry, k, v, valid)
+
+
 def _stream(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale, pos_buf,
-            positions, lengths, *, window, tile):
+            positions, lengths, layer, *, window, tile):
     """Online-softmax loop over ring tiles; chunk keys fold in last.
 
-    Tiles are ``dynamic_slice``d out of the (B, cap, ...) ring in place —
-    no upfront reshape/transpose copy of the cache, which would be a
-    second full pass over exactly the HBM bytes this path exists to not
-    touch twice.
+    Tiles are ``dynamic_slice``d out of the stacked ring at ``layer`` in
+    place — no upfront slice, reshape or transpose copy of the cache,
+    which would be a second full pass over exactly the HBM bytes this path
+    exists to not touch twice.
     """
     b, L, kv, g, hd = q.shape
-    cap = k_cache.shape[1]
+    cap = k_cache.shape[2]
     reach = reach_of(cap, window)
     scale = hd ** -0.5
     qf = q.astype(jnp.float32).transpose(0, 2, 3, 1, 4) * scale  # (B,KV,G,L,hd)
 
     def ring_tile(i, carry):
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * tile, tile, axis=1)
-        k = _ref._deq(sl(k_cache), sl(k_scale) if k_scale is not None
-                      else None)                             # (B, tile, KV, hd)
-        v = _ref._deq(sl(v_cache), sl(v_scale) if v_scale is not None
-                      else None)
-        pt = sl(pos_buf)
-        d = positions[:, :, None] - pt[:, None, :]           # (B, L, tile)
-        valid = (pt[:, None, :] >= 0) & (d >= 0) & (d < reach)
-        return _stream_update(qf, carry, k, v, valid)
+        kvt = lambda a: _slot_slice(a, layer, i * tile, tile, 2)
+        row = lambda a: _slot_slice(a, layer, i * tile, tile, -1)
+        return _tile_update(qf, carry, kvt(k_cache), row(k_scale),
+                            kvt(v_cache), row(v_scale), row(pos_buf),
+                            positions, reach, kv)
 
     n_tiles = cap // tile
     carry0 = _stream_carry0(b, kv, g, L, hd)
@@ -214,7 +236,8 @@ def _stream(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale, pos_buf,
 
 
 def _stream_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
-                  pos_pool, table, positions, lengths, *, window, tile):
+                  pos_pool, table, positions, lengths, layer, *, window,
+                  tile):
     """Paged stream path: the same online-softmax walk over *logical* tiles,
     each gathered through the page table (tile divides page_size, so one
     tile never spans two physical pages). Tile i covers logical slots
@@ -223,7 +246,7 @@ def _stream_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
     walk exactly, so the two are bit-identical per backend.
     """
     b, L, kv, g, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     n_pages = table.shape[1]
     cap = n_pages * ps
     reach = reach_of(cap, window)
@@ -236,16 +259,17 @@ def _stream_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
         off = (i % tpp) * tile
         phys = jax.lax.dynamic_index_in_dim(table, pidx, axis=1,
                                             keepdims=False)  # (B,)
-        sl = lambda pool: jax.lax.dynamic_slice_in_dim(
-            jnp.take(pool, phys, axis=0), off, tile, axis=1)
-        k = _ref._deq(sl(k_pool), sl(k_scale) if k_scale is not None
-                      else None)                             # (B, tile, KV, hd)
-        v = _ref._deq(sl(v_pool), sl(v_scale) if v_scale is not None
-                      else None)
-        pt = sl(pos_pool)
-        d = positions[:, :, None] - pt[:, None, :]           # (B, L, tile)
-        valid = (pt[:, None, :] >= 0) & (d >= 0) & (d < reach)
-        return _stream_update(qf, carry, k, v, valid)
+
+        def sl(pool, axis, rows=0):
+            if pool is None:
+                return None
+            pages = jnp.take(jax.lax.dynamic_index_in_dim(
+                pool, layer, 0, keepdims=False), phys, axis=rows)
+            return jax.lax.dynamic_slice_in_dim(pages, off, tile, axis=axis)
+
+        return _tile_update(qf, carry, sl(k_pool, 1), sl(k_scale, -1, 1),
+                            sl(v_pool, 1), sl(v_scale, -1, 1),
+                            sl(pos_pool, -1), positions, reach, kv)
 
     n_tiles = n_pages * tpp
     carry0 = _stream_carry0(b, kv, g, L, hd)
@@ -257,17 +281,19 @@ def _stream_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
 
 
 def chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
-                    pos_buf, positions, lengths, *,
+                    pos_buf, positions, lengths, *, layer=0,
                     window: Optional[int] = None,
                     backend: str = DEFAULT_BACKEND,
                     tile: Optional[int] = None):
     """Chunk-prefill attention vs (pre-write ring ∪ in-chunk keys).
 
-    Shapes/masks: package docstring. Returns (B, L, KV, G, hd) float32.
+    Shapes/masks: package docstring. The ring is the stacked storage of
+    every layer of a scan; ``layer`` (an int or a traced int32 scalar)
+    picks the one read. Returns (B, L, KV, G, hd) float32.
     ``k_scale``/``v_scale`` are None for float (bf16/f32) ring caches.
     """
     b, L, kv, g, hd = q.shape
-    cap = k_cache.shape[1]
+    cap = k_cache.shape[2]
     backend = resolve_chunk_backend(backend)
     t = tile if tile is not None else _select_tile(cap, L)
     t = min(t, cap)
@@ -281,23 +307,26 @@ def chunk_attention(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
         tracked_block_bytes(b, kv, g, L, cap, backend=backend, tile=t))
     if backend == "materialized":
         return _ref.chunk_attention_ref(
-            q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
-            pos_buf, positions, lengths, window=window)
+            q, k_new, v_new,
+            *_ref.ring_layer(k_cache, k_scale, v_cache, v_scale, pos_buf,
+                             layer, kv),
+            positions, lengths, window=window)
     if backend == "stream":
         return _stream(q, k_new, v_new, k_cache, k_scale, v_cache, v_scale,
-                       pos_buf, positions, lengths, window=window, tile=t)
+                       pos_buf, positions, lengths, layer, window=window,
+                       tile=t)
     if backend == "pallas":
         out = chunk_attention_pallas(
             q.transpose(0, 2, 3, 1, 4), k_new, v_new, k_cache, k_scale,
             v_cache, v_scale, pos_buf, positions,
-            lengths.astype(jnp.int32), window=window,
+            lengths.astype(jnp.int32), layer, window=window,
             tile=t, interpret=interpret)
         return out.transpose(0, 3, 1, 2, 4)
     raise ValueError(f"unknown chunk-attention backend {backend!r}")
 
 
 def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
-                          pos_pool, table, positions, lengths, *,
+                          pos_pool, table, positions, lengths, *, layer=0,
                           window: Optional[int] = None,
                           backend: str = DEFAULT_BACKEND,
                           tile: Optional[int] = None):
@@ -306,12 +335,13 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
     table)`` defines (mask rule unchanged, expressed in logical positions
     carried by ``pos_pool`` — prefill and decode L=1 stay unified).
 
-    Extra operands vs the contiguous op: ``k_pool``/``v_pool`` are
-    (P, page_size, KV, hd) physical pages (int8 with (P, page_size, KV)
-    scales, or float with scales None), ``pos_pool`` (P, page_size) the
-    per-entry absolute positions, ``table`` (B, n_pages) int32 physical
-    page ids per logical page. Physical page 0 is the reserved null page
-    (pos ≡ -1, never written): unmapped entries point at it and mask out.
+    Extra operands vs the contiguous op: ``k_pool``/``v_pool`` are stacked
+    physical pages (layers, P, page_size, KV·hd) (int8 with (layers, KV,
+    P, page_size) scales, or float with scales None), ``pos_pool``
+    (layers, P, page_size) the per-entry absolute positions, ``table``
+    (B, n_pages) int32 physical page ids per logical page of layer
+    ``layer``. Physical page 0 is the reserved null page (pos ≡ -1, never
+    written): unmapped entries point at it and mask out.
 
     Backends mirror the contiguous op: ``materialized`` gathers the pages
     into a contiguous ring and runs ``chunk_attention_ref`` (the oracle by
@@ -320,7 +350,7 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
     is bit-identical to its contiguous-ring counterpart.
     """
     b, L, kv, g, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     n_pages = table.shape[1]
     cap = n_pages * ps
     backend = resolve_chunk_backend(backend)
@@ -335,21 +365,21 @@ def chunk_attention_paged(q, k_new, v_new, k_pool, k_scale, v_pool, v_scale,
         _TRACK["peak_bytes"],
         tracked_block_bytes(b, kv, g, L, cap, backend=backend, tile=t))
     if backend == "materialized":
+        kp, ksp, vp, vsp, pp = _ref.ring_layer(k_pool, k_scale, v_pool,
+                                               v_scale, pos_pool, layer, kv)
+        gather = lambda a: None if a is None else gather_pages(a, table)
         return _ref.chunk_attention_ref(
-            q, k_new, v_new, gather_pages(k_pool, table),
-            None if k_scale is None else gather_pages(k_scale, table),
-            gather_pages(v_pool, table),
-            None if v_scale is None else gather_pages(v_scale, table),
-            gather_pages(pos_pool, table), positions, lengths, window=window)
+            q, k_new, v_new, gather(kp), gather(ksp), gather(vp),
+            gather(vsp), gather(pp), positions, lengths, window=window)
     if backend == "stream":
         return _stream_paged(q, k_new, v_new, k_pool, k_scale, v_pool,
                              v_scale, pos_pool, table, positions, lengths,
-                             window=window, tile=t)
+                             layer, window=window, tile=t)
     if backend == "pallas":
         out = chunk_attention_paged_pallas(
             q.transpose(0, 2, 3, 1, 4), k_new, v_new, k_pool, k_scale,
             v_pool, v_scale, pos_pool, table, positions,
-            lengths.astype(jnp.int32), window=window,
+            lengths.astype(jnp.int32), layer, window=window,
             tile=t, interpret=interpret)
         return out.transpose(0, 3, 1, 2, 4)
     raise ValueError(f"unknown chunk-attention backend {backend!r}")

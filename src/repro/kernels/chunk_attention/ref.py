@@ -6,6 +6,12 @@ serving path, restated against the package's mask contract. It doubles as
 the ``backend="materialized"`` baseline (it is jit-friendly) and as the
 parity oracle for the Pallas kernel and the streaming fallback.
 
+The oracle reads one layer of the ring in its *logical* layout — keys
+(B, cap, KV, hd), scales (B, cap, KV), positions (B, cap) — which
+``ring_layer`` takes out of the stacked storage the serving path keeps
+(package docstring, "Ring storage"); ``to_storage`` is its inverse, for
+tests that build a ring by hand.
+
 The mask helpers here are the single source of truth for the visible set;
 ``ops`` and ``kernel`` reimplement them tile-wise and the tests assert the
 reimplementations agree bitwise.
@@ -44,6 +50,37 @@ def chunk_mask(positions, lengths, reach: int):
     valid = jnp.arange(L)[None, None, :] < lengths[:, None, None]
     d = positions[:, :, None] - positions[:, None, :]
     return valid & (d >= 0) & (d < reach)
+
+
+def kv_logical(x, kv: int):
+    """(rows, slots, KV·hd) lane-dense keys or values → (rows, slots, KV,
+    hd)."""
+    r, n, f = x.shape
+    return x.reshape(r, n, kv, f // kv)
+
+
+def scale_logical(s):
+    """(KV, rows, slots) int8 scales → (rows, slots, KV)."""
+    return s.transpose(1, 2, 0)
+
+
+def ring_layer(k_cache, k_scale, v_cache, v_scale, pos_buf, layer, kv: int):
+    """Layer ``layer`` of a stacked ring (or paged pool) in the oracle's
+    logical layout: (k, k_scale, v, v_scale, pos), scales None for a float
+    ring."""
+    sc = lambda a: None if a is None else scale_logical(a[layer])
+    return (kv_logical(k_cache[layer], kv), sc(k_scale),
+            kv_logical(v_cache[layer], kv), sc(v_scale), pos_buf[layer])
+
+
+def to_storage(k, k_scale, v, v_scale, pos):
+    """Inverse of ``ring_layer`` over every layer: logical leaves with a
+    leading layer axis — k/v (NL, rows, slots, KV, hd), scales (NL, rows,
+    slots, KV) or None, pos (NL, rows, slots) — in the stacked storage
+    layout the op reads."""
+    kvs = lambda a: a.reshape(a.shape[:3] + (-1,))
+    sc = lambda a: None if a is None else a.transpose(0, 3, 1, 2)
+    return kvs(k), sc(k_scale), kvs(v), sc(v_scale), pos
 
 
 def _deq(c, scale):

@@ -12,6 +12,16 @@ capacity = W (so a gemma3 local layer at 500k context holds 1024 slots, not
 500k — the cache-memory optimization that makes `long_500k` feasible).
 One implementation serves both (window = capacity ⇒ full attention).
 
+The ring is stored as the attention kernel reads it — k/v (B, cap, KV·hd)
+lane-dense, int8 scales (KV, B, cap), positions (B, cap) — and the
+layers of a scan are one stack with a leading layer axis that the layer
+scan carries in place (``models.transformer``). Every function here takes
+that stack plus the layer index (or one unscanned layer's cache with
+``layer=None``): the kernel reads the layer in place, and the only write
+is one scatter of the chunk's (or decode token's) k/v/pos at
+``[layer, row, slot]``, after the read. The paged pool (``pages_*``) is
+stored and written the same way, through the page table.
+
 Every serving-time attention read — chunk prefill and single-token decode
 (its L = 1 case) — goes through one backend-dispatched op,
 ``repro.kernels.chunk_attention``: online softmax against (pre-write ring ∪
@@ -154,13 +164,25 @@ def attention_forward(
 # decode: ring-buffer KV cache
 # ---------------------------------------------------------------------------
 
+# leaf names of the ring and of the paged pool: k, v, k scale, v scale, pos
+_RING = ("k", "v", "k_scale", "v_scale", "pos")
+_PAGES = ("pages_k", "pages_v", "pages_ks", "pages_vs", "pages_pos")
+
+
 def cache_init(cfg, batch: int, capacity: int, window: Optional[int],
                dtype, *, kv_spec: Optional[Dict[str, int]] = None
                ) -> Dict[str, Any]:
-    """Ring cache. capacity = min(window, max_context) for local layers.
+    """Ring cache of one layer. capacity = min(window, max_context) for
+    local layers.
+
+    Stored as ``repro.kernels.chunk_attention`` reads it (its "Ring
+    storage"), so no call relays it out: ``k``/``v`` (B, cap, KV·hd)
+    lane-dense, ``pos`` (B, cap) (-1 = empty slot). The layers of a
+    scan are stacked on a leading axis by ``models.transformer``.
 
     kv_cache_dtype="int8" (§Perf it. 5, beyond-paper): k/v stored int8 with
-    per-(slot, kv-head) absmax scales — halves cache HBM capacity AND the
+    per-(slot, kv-head) absmax scales ``k_scale``/``v_scale`` (KV, B, cap),
+    kv-head-major (``row_axis``) — halves cache HBM capacity AND the
     decode-read traffic that dominates the decode_32k memory term.
 
     ``kv_spec = {"page_size": ps, "max_pages": n}`` selects the *paged*
@@ -171,17 +193,33 @@ def cache_init(cfg, batch: int, capacity: int, window: Optional[int],
                                 page_size=kv_spec["page_size"],
                                 max_pages=kv_spec["max_pages"])
     cap = min(window, capacity) if window else capacity
-    hd = cfg.head_dim
-    cache = {"pos": jnp.full((batch, cap), -1, jnp.int32)}
+    return _kv_storage(cfg, batch, cap, dtype, _RING)
+
+
+def _kv_storage(cfg, rows: int, slots: int, dtype, names) -> Dict[str, Any]:
+    """Zeroed k/v (rows, slots, KV·hd), int8 scales (KV, rows, slots) and
+    positions (rows, slots) = -1 under ``names`` (``_RING`` or
+    ``_PAGES``)."""
+    kn, vn, ksn, vsn, pn = names
+    f = cfg.n_kv_heads * cfg.head_dim
+    out = {pn: jnp.full((rows, slots), -1, jnp.int32)}
     if cfg.kv_cache_dtype == "int8":
-        cache["k"] = jnp.zeros((batch, cap, cfg.n_kv_heads, hd), jnp.int8)
-        cache["v"] = jnp.zeros((batch, cap, cfg.n_kv_heads, hd), jnp.int8)
-        cache["k_scale"] = jnp.zeros((batch, cap, cfg.n_kv_heads), jnp.float32)
-        cache["v_scale"] = jnp.zeros((batch, cap, cfg.n_kv_heads), jnp.float32)
-    else:
-        cache["k"] = jnp.zeros((batch, cap, cfg.n_kv_heads, hd), dtype)
-        cache["v"] = jnp.zeros((batch, cap, cfg.n_kv_heads, hd), dtype)
-    return cache
+        dtype = jnp.int8
+        for n in (ksn, vsn):
+            out[n] = jnp.zeros((cfg.n_kv_heads, rows, slots), jnp.float32)
+    out[kn] = jnp.zeros((rows, slots, f), dtype)
+    out[vn] = jnp.zeros((rows, slots, f), dtype)
+    return out
+
+
+def row_axis(name: str) -> int:
+    """The axis of one layer's cache leaf ``name`` that holds its rows
+    (batch rows, or physical pages for ``pages_*``). int8 scales are
+    kv-head-major, (KV, rows, slots): their rows and slots then keep the
+    TPU's default tiling, which the kernel reads and the scatter writes
+    alike; every other leaf leads with its rows. A scan's stacked leaves
+    add one leading layer axis."""
+    return 1 if name in ("k_scale", "v_scale", "pages_ks", "pages_vs") else 0
 
 
 def paged_cache_init(cfg, batch: int, capacity: int, window: Optional[int],
@@ -190,12 +228,16 @@ def paged_cache_init(cfg, batch: int, capacity: int, window: Optional[int],
     """Paged KV cache: one batch-global pool of fixed-size pages plus a
     per-row page table (``repro.kernels.chunk_attention`` paged contract).
 
-    Pool leaves are named ``pages_*`` — they are *physical* storage owned
-    by the allocator, not per-row state, and the engine's row reset skips
-    them. ``table`` (B, n_pages) int32 is per-row; entry 0 points at the
-    reserved null page (``pages_pos[0] ≡ -1``, never written), so an
-    unmapped logical page reads as empty. The pool holds ``max_pages``
-    allocatable pages + the null page.
+    Pool leaves are named ``pages_*`` and stored like the ring with pages
+    for rows: ``pages_k``/``pages_v`` (P, page_size, KV·hd), ``pages_ks``/
+    ``pages_vs`` (KV, P, page_size), ``pages_pos`` (P, page_size).
+    They are *physical* storage owned by the allocator, not per-row state,
+    and the engine's row reset skips them. ``table`` (B, n_pages) int32 is
+    per-row; entry 0 points at the reserved null page (``pages_pos[0] ≡
+    -1``, never written), so an unmapped logical page reads as empty. The
+    pool holds ``max_pages`` allocatable pages + the null page, rounded up
+    to a multiple of 8 pages (the kernel reads page positions 8 pages at a
+    time; the spare pages are never mapped).
 
     Sliding-window layers are rejected: paging virtualizes one uniform
     logical capacity per row, and a window < capacity layer would need its
@@ -209,27 +251,9 @@ def paged_cache_init(cfg, batch: int, capacity: int, window: Optional[int],
     if capacity % page_size:
         raise ValueError(f"page_size {page_size} must divide "
                          f"capacity {capacity}")
-    hd = cfg.head_dim
-    n_pages = capacity // page_size
-    P = max_pages + 1  # + the reserved null page 0
-    cache = {
-        "pages_pos": jnp.full((P, page_size), -1, jnp.int32),
-        "table": jnp.zeros((batch, n_pages), jnp.int32),
-    }
-    if cfg.kv_cache_dtype == "int8":
-        cache["pages_k"] = jnp.zeros((P, page_size, cfg.n_kv_heads, hd),
-                                     jnp.int8)
-        cache["pages_v"] = jnp.zeros((P, page_size, cfg.n_kv_heads, hd),
-                                     jnp.int8)
-        cache["pages_ks"] = jnp.zeros((P, page_size, cfg.n_kv_heads),
-                                      jnp.float32)
-        cache["pages_vs"] = jnp.zeros((P, page_size, cfg.n_kv_heads),
-                                      jnp.float32)
-    else:
-        cache["pages_k"] = jnp.zeros((P, page_size, cfg.n_kv_heads, hd),
-                                     dtype)
-        cache["pages_v"] = jnp.zeros((P, page_size, cfg.n_kv_heads, hd),
-                                     dtype)
+    n_phys = -(-(max_pages + 1) // 8) * 8                    # + null page
+    cache = _kv_storage(cfg, n_phys, page_size, dtype, _PAGES)
+    cache["table"] = jnp.zeros((batch, capacity // page_size), jnp.int32)
     return cache
 
 
@@ -242,72 +266,106 @@ def _q8(x):
     return q, scale
 
 
-def cache_prefill(cfg, cache: Dict[str, Any], k, v, positions) -> Dict[str, Any]:
-    """Write a full prefill sequence into the ring (keeps the last `cap`).
+def _as_stack(cache, layer):
+    """(stacked cache, layer index, inverse). A scan's cache is already
+    the stack and ``layer`` its index; an unscanned layer's cache (layer
+    None) gains a leading axis of one, which costs no copy."""
+    if layer is not None:
+        return cache, layer, lambda c: c
+    return (jax.tree.map(lambda a: a[None], cache), 0,
+            lambda c: jax.tree.map(lambda a: a[0], c))
 
-    k/v: (B, S, KV, hd); positions: (B, S) absolute.
+
+def _capacity(cache) -> int:
+    if "table" in cache:
+        return cache["table"].shape[-1] * cache["pages_k"].shape[2]
+    return cache["k"].shape[2]
+
+
+def _attend(cfg, cache, layer, q, k, v, positions, lengths, window):
+    """Chunk attention of q (B, L, KV, G, hd) against layer ``layer`` of
+    the stacked ring or pool (read in place) ∪ the chunk's own k/v."""
+    lengths = lengths.astype(jnp.int32)
+    if "table" in cache:
+        return chunk_attention_paged(
+            q, k, v, cache["pages_k"], cache.get("pages_ks"),
+            cache["pages_v"], cache.get("pages_vs"), cache["pages_pos"],
+            cache["table"][layer], positions, lengths, layer=layer,
+            window=window, backend=cfg.attn_backend)
+    return chunk_attention(
+        q, k, v, cache["k"], cache.get("k_scale"), cache["v"],
+        cache.get("v_scale"), cache["pos"], positions, lengths, layer=layer,
+        window=window, backend=cfg.attn_backend)
+
+
+def _after(y, cache):
+    """Order the ring's write after the kernel's read of it: the write then
+    updates the stack in place, where XLA would otherwise copy the stack
+    to keep the read's operand intact."""
+    return jax.lax.optimization_barrier((y, cache))
+
+
+def _store(cache, layer, slots, k, v, positions):
+    """Write chunk k/v (B, S, KV, hd) and their positions (B, S) at
+    logical ring ``slots`` (B, S) of layer ``layer`` of the stacked
+    storage: one scatter per leaf at ``[layer, row, slot]``, so only the
+    slots tokens land in are written. Slot == cap means "drop this entry"
+    (padding, and wrap-around entries the row's own chunk tail overwrites).
+
+    Paged storage resolves each logical slot through the layer's page
+    table to (physical page, offset). Writes that resolve to the null page
+    (table entry 0 — an unmapped logical page) are dropped too: the null
+    page's pos ≡ -1 invariant is what makes unmapped gathers safe, so
+    nothing may ever dirty it. Distinct rows never map a writable logical
+    page to the same physical page (the allocator copy-on-write-forks
+    shared pages before any dispatch that writes them), so the scatter has
+    no cross-row collisions.
     """
-    b, s, kv, hd = k.shape
-    cap = cache["k"].shape[1]
-    if s <= cap:
-        ktail, vtail, ptail = k, v, positions
+    b, s = slots.shape
+    if "table" in cache:
+        names = _PAGES
+        n_phys, ps = cache["pages_k"].shape[1:3]
+        n_pages = cache["table"].shape[-1]
+        page = jnp.clip(slots // ps, 0, n_pages - 1)
+        phys = jnp.take_along_axis(cache["table"][layer], page, axis=1)
+        drop = (slots >= n_pages * ps) | (phys == 0)
+        rows = jnp.where(drop, n_phys, phys)                 # out of range
+        slots = slots % ps
     else:
-        ktail, vtail, ptail = k[:, -cap:], v[:, -cap:], positions[:, -cap:]
-    slots = ptail % cap
-    out = {"pos": _scatter_slots(cache["pos"], slots,
-                                 ptail.astype(jnp.int32))}
-    if "k_scale" in cache:  # int8 cache
-        kq, ks = _q8(ktail)
-        vq, vs = _q8(vtail)
-        out["k"] = _scatter_slots(cache["k"], slots, kq)
-        out["v"] = _scatter_slots(cache["v"], slots, vq)
-        out["k_scale"] = _scatter_slots(cache["k_scale"], slots, ks)
-        out["v_scale"] = _scatter_slots(cache["v_scale"], slots, vs)
-    else:
-        out["k"] = _scatter_slots(cache["k"], slots, ktail)
-        out["v"] = _scatter_slots(cache["v"], slots, vtail)
+        names = _RING
+        rows = jnp.broadcast_to(jnp.arange(b)[:, None], (b, s))
+    kn, vn, ksn, vsn, pn = names
+    out = dict(cache)
+    out[pn] = cache[pn].at[layer, rows, slots].set(
+        positions.astype(jnp.int32), mode="drop")
+    if ksn in cache:  # int8 storage: quantize the written entries
+        (k, ks), (v, vs) = _q8(k), _q8(v)
+        # every (head, row, slot) indexed: an update window over the head
+        # axis makes XLA lay the stack out head-minor for the scatter, and
+        # relay it out for the kernel on every call
+        heads = jnp.arange(ks.shape[-1])
+        for n, sc in ((ksn, ks), (vsn, vs)):
+            out[n] = cache[n].at[layer, heads, rows[..., None],
+                                 slots[..., None]].set(sc, mode="drop")
+    for n, val in ((kn, k), (vn, v)):
+        out[n] = cache[n].at[layer, rows, slots].set(
+            val.reshape(b, s, -1).astype(cache[n].dtype), mode="drop")
     return out
 
 
-def _scatter_slots(buf, slots, vals):
-    """buf: (B, cap, ...), slots: (B, S), vals: (B, S, ...).
+def cache_prefill(cfg, cache: Dict[str, Any], k, v, positions, *,
+                  layer=None) -> Dict[str, Any]:
+    """Write a full prefill sequence into the ring (keeps the last `cap`).
 
-    Slot index == cap (one past the ring) means "drop this entry" — used by
-    the chunked-prefill path to skip right-padding and stale wrap-around
-    writes without a select over the whole cache.
+    k/v: (B, S, KV, hd); positions: (B, S) absolute. ``layer``: this
+    layer's index in a scan's stacked cache, None for an unscanned layer.
     """
-    def per_batch(bf, sl, vl):
-        return bf.at[sl].set(vl, mode="drop")
-
-    return jax.vmap(per_batch)(buf, slots, vals)
-
-
-def _scatter_pages(pool, table, slots, vals):
-    """Paged analogue of ``_scatter_slots``: write logical ring slots
-    through the page table into the physical pool.
-
-    pool: (P, ps, ...); table: (B, n_pages) int32; slots: (B, S) *logical*
-    slot ids where slot == n_pages·ps means "drop" (same sentinel rule as
-    the contiguous path); vals: (B, S, ...).
-
-    Writes resolving to the null page (table entry 0 — an unmapped logical
-    page) are dropped too: the null page's pos ≡ -1 invariant is what makes
-    unmapped gathers safe, so nothing may ever dirty it. Distinct rows
-    never map a writable logical page to the same physical page (the
-    allocator copy-on-write-forks shared pages before any dispatch that
-    writes them), so the flattened scatter has no cross-row collisions.
-    """
-    P, ps = pool.shape[0], pool.shape[1]
-    n_pages = table.shape[1]
-    page = jnp.clip(slots // ps, 0, n_pages - 1)
-    phys = jnp.take_along_axis(table, page, axis=1)          # (B, S)
-    flat = phys * ps + slots % ps
-    drop = (slots >= n_pages * ps) | (phys == 0)
-    flat = jnp.where(drop, P * ps, flat)                     # out of range
-    fp = pool.reshape((P * ps,) + pool.shape[2:])
-    fp = fp.at[flat.reshape(-1)].set(
-        vals.reshape((-1,) + vals.shape[2:]).astype(pool.dtype), mode="drop")
-    return fp.reshape(pool.shape)
+    cache, layer, unstack = _as_stack(cache, layer)
+    cap = _capacity(cache)
+    if k.shape[1] > cap:
+        k, v, positions = k[:, -cap:], v[:, -cap:], positions[:, -cap:]
+    slots = (positions % cap).astype(jnp.int32)
+    return unstack(_store(cache, layer, slots, k, v, positions))
 
 
 def attention_prefill_chunk(
@@ -319,6 +377,7 @@ def attention_prefill_chunk(
     lengths: jax.Array,
     *,
     window: Optional[int] = None,
+    layer=None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Padded-batch chunk prefill: attend to (ring cache ∪ chunk), then write.
 
@@ -327,7 +386,8 @@ def attention_prefill_chunk(
       (row r valid through positions[r, lengths[r]-1]); lengths: (B,) valid
       token counts — 0 makes the row a complete no-op (its cache survives
       untouched, so decoding/free rows can ride along in a fixed-shape
-      dispatch).
+      dispatch); layer: this layer's index in a scan's stacked cache, None
+      for an unscanned layer.
 
     The chunk queries score against the *pre-write* ring (history from
     earlier chunks — for sliding-window layers the ring holds exactly the
@@ -339,72 +399,24 @@ def attention_prefill_chunk(
     chunk k/v are scattered into the ring; padding and entries a row's own
     chunk tail would immediately overwrite (length > cap) are dropped.
     """
+    cache, layer, unstack = _as_stack(cache, layer)
     b, L, _ = x.shape
     hd = cfg.head_dim
     kv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-    paged = "table" in cache
-    cap = (cache["table"].shape[1] * cache["pages_k"].shape[1] if paged
-           else cache["k"].shape[1])
+    cap = _capacity(cache)
 
     q, k, v = _qkv(params, cfg, x, positions)
-    qh = q.reshape(b, L, kv, g, hd)
-
-    valid = jnp.arange(L)[None, :] < lengths[:, None]        # (B, L)
-    if paged:
-        y = chunk_attention_paged(
-            qh, k, v, cache["pages_k"], cache.get("pages_ks"),
-            cache["pages_v"], cache.get("pages_vs"), cache["pages_pos"],
-            cache["table"], positions, lengths.astype(jnp.int32),
-            window=window, backend=cfg.attn_backend)
-    else:
-        y = chunk_attention(
-            qh, k, v, cache["k"], cache.get("k_scale"), cache["v"],
-            cache.get("v_scale"), cache["pos"], positions,
-            lengths.astype(jnp.int32), window=window,
-            backend=cfg.attn_backend)
+    y, cache = _after(_attend(cfg, cache, layer, q.reshape(b, L, kv, g, hd),
+                              k, v, positions, lengths, window), cache)
     y = y.reshape(b, L, cfg.n_heads * hd).astype(x.dtype)
     y = dense(params["wo"], y)
 
     # write the chunk into the ring (drop padding + beyond-ring tail)
+    valid = jnp.arange(L)[None, :] < lengths[:, None]        # (B, L)
     row_end = positions[:, :1] + lengths[:, None]            # (B, 1)
     keep = valid & (positions >= row_end - cap)
     slots = jnp.where(keep, positions % cap, cap).astype(jnp.int32)
-    if paged:
-        return y, _write_pages(cache, slots, k, v, positions)
-    out = {"pos": _scatter_slots(cache["pos"], slots,
-                                 positions.astype(jnp.int32))}
-    if "k_scale" in cache:
-        kq, ks = _q8(k)
-        vq, vs = _q8(v)
-        out["k"] = _scatter_slots(cache["k"], slots, kq)
-        out["v"] = _scatter_slots(cache["v"], slots, vq)
-        out["k_scale"] = _scatter_slots(cache["k_scale"], slots, ks)
-        out["v_scale"] = _scatter_slots(cache["v_scale"], slots, vs)
-    else:
-        out["k"] = _scatter_slots(cache["k"], slots, k.astype(cache["k"].dtype))
-        out["v"] = _scatter_slots(cache["v"], slots, v.astype(cache["v"].dtype))
-    return y, out
-
-
-def _write_pages(cache, slots, k, v, positions):
-    """Scatter chunk k/v (B, S, KV, hd) at logical ``slots`` (sentinel
-    n_pages·ps = drop) through the page table; shared by the chunked
-    prefill and decode (S = 1) write paths."""
-    table = cache["table"]
-    out = {"table": table,
-           "pages_pos": _scatter_pages(cache["pages_pos"], table, slots,
-                                       positions.astype(jnp.int32))}
-    if "pages_ks" in cache:  # int8 pages: quantize the written entries
-        kq, ks = _q8(k)
-        vq, vs = _q8(v)
-        out["pages_k"] = _scatter_pages(cache["pages_k"], table, slots, kq)
-        out["pages_v"] = _scatter_pages(cache["pages_v"], table, slots, vq)
-        out["pages_ks"] = _scatter_pages(cache["pages_ks"], table, slots, ks)
-        out["pages_vs"] = _scatter_pages(cache["pages_vs"], table, slots, vs)
-    else:
-        out["pages_k"] = _scatter_pages(cache["pages_k"], table, slots, k)
-        out["pages_v"] = _scatter_pages(cache["pages_v"], table, slots, v)
-    return out
+    return y, unstack(_store(cache, layer, slots, k, v, positions))
 
 
 def attention_decode(
@@ -416,8 +428,11 @@ def attention_decode(
     *,
     window: Optional[int] = None,
     active: Optional[jax.Array] = None,
+    layer=None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One-token decode. x_t: (B, D); pos: (B,) absolute position of x_t.
+    """One-token decode. x_t: (B, D); pos: (B,) absolute position of x_t;
+    layer: this layer's index in a scan's stacked cache, None for an
+    unscanned layer.
 
     active (B,) bool: rows with active=False leave the ring untouched (their
     write is dropped) — required when decode shares the batch state with
@@ -431,58 +446,28 @@ def attention_decode(
     implementation. active=False rows pass length 0 (no self key, no
     write), mirroring their dropped write.
     """
+    cache, layer, unstack = _as_stack(cache, layer)
     b, _ = x_t.shape
     hd = cfg.head_dim
     kv, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-    paged = "table" in cache
-    cap = (cache["table"].shape[1] * cache["pages_k"].shape[1] if paged
-           else cache["k"].shape[1])
+    cap = _capacity(cache)
 
     q = dense(params["wq"], x_t).reshape(b, cfg.n_heads, hd)
     k_t = dense(params["wk"], x_t).reshape(b, kv, hd)
-    v_t = dense(params["wv"], x_t).reshape(b, kv, hd)
+    v_t = dense(params["wv"], x_t).reshape(b, 1, kv, hd)
     q = apply_rope(q, pos, cfg.rope_theta)
-    k_t = apply_rope(k_t, pos, cfg.rope_theta)
+    k_t = apply_rope(k_t, pos, cfg.rope_theta)[:, None]
 
-    qh = q.reshape(b, 1, kv, g, hd)
     lengths = (active.astype(jnp.int32) if active is not None
                else jnp.ones((b,), jnp.int32))
-    if paged:
-        y = chunk_attention_paged(
-            qh, k_t[:, None], v_t[:, None], cache["pages_k"],
-            cache.get("pages_ks"), cache["pages_v"], cache.get("pages_vs"),
-            cache["pages_pos"], cache["table"],
-            pos[:, None].astype(jnp.int32), lengths, window=window,
-            backend=cfg.attn_backend)
-    else:
-        y = chunk_attention(
-            qh, k_t[:, None], v_t[:, None], cache["k"], cache.get("k_scale"),
-            cache["v"], cache.get("v_scale"), cache["pos"],
-            pos[:, None].astype(jnp.int32), lengths, window=window,
-            backend=cfg.attn_backend)
+    positions = pos[:, None].astype(jnp.int32)
+    y, cache = _after(_attend(cfg, cache, layer, q.reshape(b, 1, kv, g, hd),
+                              k_t, v_t, positions, lengths, window), cache)
     y = y.reshape(b, cfg.n_heads * hd).astype(x_t.dtype)
     y = dense(params["wo"], y)
 
     slot = (pos % cap).astype(jnp.int32)  # (B,)
     if active is not None:
         slot = jnp.where(active, slot, cap)  # cap = out of ring → dropped
-    if paged:
-        return y, _write_pages(cache, slot[:, None], k_t[:, None],
-                               v_t[:, None], pos[:, None])
-    upd = lambda bf, s_, v_: bf.at[s_].set(v_, mode="drop")
-    pc = jax.vmap(upd)(cache["pos"], slot, pos.astype(jnp.int32))
-    new_cache = {"pos": pc}
-    if "k_scale" in cache:  # int8 cache: quantize the new token's write
-        kq, ks = _q8(k_t)
-        vq, vs = _q8(v_t)
-        new_cache.update(
-            k=jax.vmap(upd)(cache["k"], slot, kq),
-            v=jax.vmap(upd)(cache["v"], slot, vq),
-            k_scale=jax.vmap(upd)(cache["k_scale"], slot, ks),
-            v_scale=jax.vmap(upd)(cache["v_scale"], slot, vs))
-    else:
-        new_cache.update(k=jax.vmap(upd)(cache["k"], slot,
-                                         k_t.astype(cache["k"].dtype)),
-                         v=jax.vmap(upd)(cache["v"], slot,
-                                         v_t.astype(cache["v"].dtype)))
-    return y, new_cache
+    return y, unstack(_store(cache, layer, slot[:, None], k_t, v_t,
+                             positions))

@@ -3,7 +3,11 @@
 Layer stacking: prefix blocks (unscanned) + `lax.scan` over the repeating
 block-pattern period (compile time O(1) in depth; params stacked with a
 leading n_periods axis) + automatic remainder blocks. Remat wraps the scan
-body. Decode threads per-layer caches through the same scan as (xs → ys).
+body. Decode threads the caches through the same scan: recurrent state
+(rwkv, rg-lru), which a step rewrites whole, as (xs → ys); the attention
+KV ring (or paged pool), of which a step writes a few slots, as one stacked
+buffer in the scan's carry that every layer reads and writes in place at
+its layer index (``_run_blocks``).
 
 Model API (all pure functions):
   init_params(cfg, key)                        → params
@@ -125,7 +129,7 @@ def _block_cache_init(kind: str, cfg, batch: int, capacity: int, dtype,
                            dtype, kv_spec=kv_spec)
 
 
-def _block_prefill(kind: str, p, cfg, x, positions, cache):
+def _block_prefill(kind: str, p, cfg, x, positions, cache, layer=None):
     """Full-seq forward that also fills the decode cache."""
     if kind == "rwkv":
         h = rms_norm(p["time_norm"], x, cfg.norm_eps)
@@ -152,7 +156,7 @@ def _block_prefill(kind: str, p, cfg, x, positions, cache):
         return_kv=True)
     b, s, _ = x.shape
     pos_bs = jnp.broadcast_to(positions[None, :], (b, s))
-    cache = attn.cache_prefill(cfg, cache, k, v, pos_bs)
+    cache = attn.cache_prefill(cfg, cache, k, v, pos_bs, layer=layer)
     x = x + y
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
     if "moe" in p:
@@ -171,7 +175,8 @@ def _freeze_rows(active, new, old):
     return jax.tree.map(sel, new, old)
 
 
-def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None):
+def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None,
+                  layer=None):
     if kind == "rwkv":
         h = rms_norm(p["time_norm"], x_t, cfg.norm_eps)
         y, (x_last, wkv) = rwkv_mod.rwkv_time_decode(
@@ -195,7 +200,7 @@ def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None):
     h = rms_norm(p["attn_norm"], x_t, cfg.norm_eps)
     y, cache = attn.attention_decode(p["attn"], cfg, cache, h, pos,
                                      window=_mixer_window(kind, cfg),
-                                     active=active)
+                                     active=active, layer=layer)
     x_t = x_t + y
     h = rms_norm(p["mlp_norm"], x_t, cfg.norm_eps)
     if "moe" in p:
@@ -203,6 +208,61 @@ def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None):
     else:
         y = mlp_mod.mlp_forward(p["mlp"], h, cfg.mlp_type)
     return x_t + y, cache
+
+
+def _recurrent(kind: str) -> bool:
+    """Recurrent blocks rewrite their whole (small) state every step; the
+    attention blocks keep a KV ring (or paged pool) of which a step writes
+    only the slots its tokens land in."""
+    return kind == "rwkv" or kind.startswith("rglru")
+
+
+def _run_blocks(cfg, blocks, caches, x, block_fn, *, remat=False):
+    """Run the stacked period blocks over ``x``.
+
+    ``block_fn(kind, p, cache, x, layer) -> (x, cache)`` runs one block.
+    Recurrent caches are sliced per layer and restacked (``xs → ys``):
+    their step rewrites them whole. Ring caches ride in the scan's carry
+    as the whole stack and are passed on with the layer index: the kernel
+    reads the layer in place and the block writes only the slots its
+    tokens land in, so no ring-sized slice, fill or copy exists (a ring
+    in ``xs → ys`` would be copied into a fresh stack on every call).
+    Returns (x, caches).
+    """
+    names = [f"b{i}" for i in range(len(cfg.block_pattern))]
+    kinds = dict(zip(names, cfg.block_pattern))
+    ring = {n: caches[n] for n in names if not _recurrent(kinds[n])}
+    rec = {n: caches[n] for n in names if _recurrent(kinds[n])}
+
+    def period_fn(carry, xs):
+        x, ring = carry
+        period_params, rec_p, layer = xs
+        ring, new_rec = dict(ring), {}
+        for n in names:
+            if n in ring:
+                x, ring[n] = block_fn(kinds[n], period_params[n], ring[n], x,
+                                      layer)
+            else:
+                x, new_rec[n] = block_fn(kinds[n], period_params[n],
+                                         rec_p[n], x, None)
+        return (constrain(x, "hidden"), ring), new_rec
+
+    if remat:
+        period_fn = jax.checkpoint(period_fn)
+    if cfg.scan_layers:
+        (x, ring), rec = jax.lax.scan(
+            period_fn, (x, ring),
+            (blocks, rec, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+    else:  # unrolled (exact per-layer HLO costs; dry-run cost variants)
+        outs = []
+        for i in range(cfg.n_periods):
+            sl = lambda a: a[i]
+            (x, ring), nc = period_fn(
+                (x, ring), (jax.tree.map(sl, blocks), jax.tree.map(sl, rec),
+                            i))
+            outs.append(nc)
+        rec = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+    return x, {**ring, **rec}
 
 
 # ---------------------------------------------------------------------------
@@ -311,11 +371,12 @@ def init_decode_state(cfg, batch: int, capacity: int, *,
     adt = dtype_of(cfg.activation_dtype)
 
     def stack_cache(kind):
-        one = _block_cache_init(kind, cfg, batch, capacity, adt, kv_spec)
-        # broadcast (not zeros!) so sentinel values (e.g. pos = -1) survive
-        return jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_periods,) + a.shape)
-            .copy(), one)
+        # broadcast (not zeros!) so sentinel values (e.g. pos = -1) survive;
+        # one jitted fill writes each stack once, where eager code would
+        # hold the layer, its broadcast and a copy of the stack at once
+        return jax.jit(lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (cfg.n_periods,) + a.shape),
+            _block_cache_init(kind, cfg, batch, capacity, adt, kv_spec)))()
 
     return {
         "pos": jnp.zeros((batch,), jnp.int32),
@@ -344,29 +405,11 @@ def prefill(params, cfg, batch, capacity: int) -> Tuple[jax.Array, Dict]:
             state["prefix"][f"p{i}"])
 
     if cfg.n_periods:
-        def period_fn(x, xs):
-            period_params, cache_p = xs
-            new_caches = {}
-            for pidx, kind in enumerate(cfg.block_pattern):
-                x, new_caches[f"b{pidx}"] = _block_prefill(
-                    kind, period_params[f"b{pidx}"], cfg, x, positions,
-                    cache_p[f"b{pidx}"])
-            return constrain(x, "hidden"), new_caches
-
-        if cfg.remat == "full":
-            period_fn = jax.checkpoint(period_fn)
-        if cfg.scan_layers:
-            x, new_blocks = jax.lax.scan(period_fn, x,
-                                         (params["blocks"], state["blocks"]))
-        else:
-            outs = []
-            for i in range(cfg.n_periods):
-                sl = lambda a: a[i]
-                x, nc = period_fn(x, (jax.tree.map(sl, params["blocks"]),
-                                      jax.tree.map(sl, state["blocks"])))
-                outs.append(nc)
-            new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        state["blocks"] = new_blocks
+        x, state["blocks"] = _run_blocks(
+            cfg, params["blocks"], state["blocks"], x,
+            lambda kind, p, cache, x, layer: _block_prefill(
+                kind, p, cfg, x, positions, cache, layer),
+            remat=cfg.remat == "full")
 
     for i, kind in enumerate(cfg.remainder_pattern):
         x, state["suffix"][f"s{i}"] = _block_prefill(
@@ -380,7 +423,7 @@ def prefill(params, cfg, batch, capacity: int) -> Tuple[jax.Array, Dict]:
 
 
 def _block_prefill_chunk(kind: str, p, cfg, x, positions, lengths, valid,
-                         cache):
+                         cache, layer=None):
     """Chunk forward that continues from and updates an existing cache.
 
     x: (B, L, D) right-padded; positions: (B, L) absolute per row;
@@ -410,7 +453,7 @@ def _block_prefill_chunk(kind: str, p, cfg, x, positions, lengths, valid,
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
     y, cache = attn.attention_prefill_chunk(
         p["attn"], cfg, cache, h, positions, lengths,
-        window=_mixer_window(kind, cfg))
+        window=_mixer_window(kind, cfg), layer=layer)
     x = x + y
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
     if "moe" in p:
@@ -452,29 +495,11 @@ def prefill_chunk(params, cfg, state, batch, lengths) -> Tuple[jax.Array, Dict]:
             valid, state["prefix"][f"p{i}"])
 
     if cfg.n_periods:
-        def period_fn(x, xs):
-            period_params, cache_p = xs
-            new_caches = {}
-            for pidx, kind in enumerate(cfg.block_pattern):
-                x, new_caches[f"b{pidx}"] = _block_prefill_chunk(
-                    kind, period_params[f"b{pidx}"], cfg, x, positions,
-                    lengths, valid, cache_p[f"b{pidx}"])
-            return constrain(x, "hidden"), new_caches
-
-        if cfg.remat == "full":
-            period_fn = jax.checkpoint(period_fn)
-        if cfg.scan_layers:
-            x, new_blocks = jax.lax.scan(period_fn, x,
-                                         (params["blocks"], state["blocks"]))
-        else:
-            outs = []
-            for i in range(cfg.n_periods):
-                sl = lambda a: a[i]
-                x, nc = period_fn(x, (jax.tree.map(sl, params["blocks"]),
-                                      jax.tree.map(sl, state["blocks"])))
-                outs.append(nc)
-            new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_state["blocks"] = new_blocks
+        x, new_state["blocks"] = _run_blocks(
+            cfg, params["blocks"], state["blocks"], x,
+            lambda kind, p, cache, x, layer: _block_prefill_chunk(
+                kind, p, cfg, x, positions, lengths, valid, cache, layer),
+            remat=cfg.remat == "full")
 
     for i, kind in enumerate(cfg.remainder_pattern):
         x, new_state["suffix"][f"s{i}"] = _block_prefill_chunk(
@@ -511,27 +536,10 @@ def decode_step(params, cfg, state, tokens, active=None) -> Tuple[jax.Array, Dic
             x, pos, active)
 
     if cfg.n_periods:
-        def period_fn(x, xs):
-            period_params, cache_p = xs
-            new_caches = {}
-            for pidx, kind in enumerate(cfg.block_pattern):
-                x, new_caches[f"b{pidx}"] = _block_decode(
-                    kind, period_params[f"b{pidx}"], cfg, cache_p[f"b{pidx}"],
-                    x, pos, active)
-            return x, new_caches
-
-        if cfg.scan_layers:
-            x, new_blocks = jax.lax.scan(period_fn, x,
-                                         (params["blocks"], state["blocks"]))
-        else:
-            outs = []
-            for i in range(cfg.n_periods):
-                sl = lambda a: a[i]
-                x, nc = period_fn(x, (jax.tree.map(sl, params["blocks"]),
-                                      jax.tree.map(sl, state["blocks"])))
-                outs.append(nc)
-            new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_state["blocks"] = new_blocks
+        x, new_state["blocks"] = _run_blocks(
+            cfg, params["blocks"], state["blocks"], x,
+            lambda kind, p, cache, x, layer: _block_decode(
+                kind, p, cfg, cache, x, pos, active, layer))
 
     for i, kind in enumerate(cfg.remainder_pattern):
         x, new_state["suffix"][f"s{i}"] = _block_decode(

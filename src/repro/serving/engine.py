@@ -65,6 +65,7 @@ from repro.core.quantize_model import QuantizedKernel
 from repro.kernels.ternary_matmul.ops import resolve_backend
 from repro.models import (decode_step, init_decode_state, prefill,
                           prefill_chunk)
+from repro.models.attention import row_axis
 from repro.models.common import matmul_backend
 from repro.runtime import clock as rtclock
 from repro.runtime.monitor import HealthSnapshot
@@ -216,6 +217,13 @@ def _preunpack_params(params):
                         is_leaf=lambda x: isinstance(x, QuantizedKernel))
 
 
+def _row_axis(path: str) -> int:
+    """The batch-row (or physical-page) axis of the decode-state leaf at
+    ``path``: the leaf's own (``models.attention.row_axis``), one further
+    in for a scan's stacked caches (``/blocks/``: (L, ...))."""
+    return row_axis(path.rsplit("/", 1)[-1]) + ("/blocks/" in path)
+
+
 def _merge_slot_impl(batch_state, one_state, slot):
     """Write a batch=1 decode state into slot `slot` of the batch state.
 
@@ -229,7 +237,7 @@ def _merge_slot_impl(batch_state, one_state, slot):
     def walk(dst, src, path):
         if isinstance(dst, dict):
             return {k: walk(dst[k], src[k], f"{path}/{k}") for k in dst}
-        axis = 1 if "/blocks/" in path else 0  # stacked caches: (L, B, ...)
+        axis = _row_axis(path)
         idx = [slice(None)] * dst.ndim
         idx[axis] = slot
         return dst.at[tuple(idx)].set(
@@ -271,7 +279,7 @@ def _reset_rows_impl(state, mask, pos0):
             return {k: walk(v, f"{path}/{k}") for k, v in node.items()}
         if path.rsplit("/", 1)[-1].startswith("pages_"):
             return node
-        axis = 1 if "/blocks/" in path else 0  # stacked caches: (L, B, ...)
+        axis = _row_axis(path)
         shape = [1] * node.ndim
         shape[axis] = node.shape[axis]
         if path == "/pos":
@@ -303,7 +311,7 @@ def _page_maint_impl(state, src, dst, clear, tables):
                 else t
         if not name.startswith("pages_"):
             return node
-        axis = 1 if "/blocks/" in path else 0  # stacked pools: (L, P, ...)
+        axis = _row_axis(path)                 # the pool's physical pages
         idx = [slice(None)] * node.ndim
         idx[axis] = dst
         node = node.at[tuple(idx)].set(jnp.take(node, src, axis=axis))
@@ -868,7 +876,7 @@ class ServingEngine:
             name = path.rsplit("/", 1)[-1]
             if name.startswith("pages_"):
                 pool_bytes += int(node.nbytes)
-                n_phys = node.shape[1 if "/blocks/" in path else 0]
+                n_phys = node.shape[_row_axis(path)]
             elif name == "table":
                 table_bytes += int(node.nbytes)
             elif name in ("k", "v", "k_scale", "v_scale") \

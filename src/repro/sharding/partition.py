@@ -231,21 +231,21 @@ def state_pspecs(state: Any, mesh: Mesh, *, sequence_sharded: bool) -> Any:
         core = shape[1:] if stacked else shape
         name = path.rsplit("/", 1)[-1]
 
-        if name in ("k_scale", "v_scale"):  # (B, cap, KV) int8-cache scales
+        if name in ("k_scale", "v_scale"):  # (KV, B, cap) int8 scales
+            if sequence_sharded:
+                spec = (None, None, _maybe(mesh, "data", core[2]))
+            else:
+                spec = (None, _maybe(mesh, dp, core[1]),
+                        _maybe(mesh, "model", core[2]))
+        elif name in ("k", "v"):          # (B, cap, KV·hd)
             if sequence_sharded:
                 spec = (None, _maybe(mesh, "data", core[1]), None)
-            else:
-                spec = (_maybe(mesh, dp, core[0]),
-                        _maybe(mesh, "model", core[1]), None)
-        elif name in ("k", "v"):          # (B, cap, KV, hd)
-            if sequence_sharded:
-                spec = (None, _maybe(mesh, "data", core[1]), None, None)
             else:
                 # batch over dp AND cache sequence over "model" (KV heads are
                 # too few to TP; slot-sharding divides cache HBM by tp)
                 spec = (_maybe(mesh, dp, core[0]),
-                        _maybe(mesh, "model", core[1]), None, None)
-        elif name == "pos" and len(core) == 2:   # ring position buffer
+                        _maybe(mesh, "model", core[1]), None)
+        elif name == "pos" and len(core) == 2:   # ring positions (B, cap)
             if sequence_sharded:
                 spec = (None, _maybe(mesh, "data", core[1]))
             else:

@@ -27,7 +27,28 @@ from repro.kernels.chunk_attention.ops import (_select_tile, chunk_attention,
                                                tracked_block_bytes)
 from repro.kernels.chunk_attention.ref import (chunk_attention_ref,
                                                chunk_mask, history_mask,
-                                               reach_of)
+                                               reach_of, to_storage)
+
+# the op reads a stacked ring at a layer index: cases put their ring at
+# LAYER of a two-layer stack whose other layer holds other values at the
+# same positions, so a read of the wrong layer changes the output
+LAYER = 1
+
+
+def stack(k, ks, v, vs, pos):
+    """One layer's logical ring leaves (rows = batch rows or physical
+    pages) → two-layer stacked storage with this ring at ``LAYER``."""
+    def two(a, other):
+        return None if a is None else jnp.stack([other, a])
+    return to_storage(two(k, -k), two(ks, None if ks is None else 2 * ks),
+                      two(v, -v), two(vs, None if vs is None else 2 * vs),
+                      two(pos, pos))
+
+
+def stored(args):
+    """A ``make_case`` input with its ring in the op's stacked storage."""
+    q, kn, vn, kc, ks, vc, vs, pb, positions, lengths = args
+    return (q, kn, vn, *stack(kc, ks, vc, vs, pb), positions, lengths)
 
 
 def make_case(rng, b, L, kv, g, hd, cap, *, int8=True, wrap=False,
@@ -85,8 +106,9 @@ class TestBackendParity:
         rng = np.random.default_rng(hash((b, L, kv, cap, int8)) % 2**31)
         args = make_case(rng, b, L, kv, g, hd, cap, int8=int8, wrap=wrap)
         ref = np.asarray(chunk_attention_ref(*args, window=window))
-        got = np.asarray(chunk_attention(*args, window=window,
-                                         backend=backend, tile=8))
+        got = np.asarray(chunk_attention(*stored(args), layer=LAYER,
+                                         window=window, backend=backend,
+                                         tile=8))
         lengths = np.asarray(args[-1])
         for r in range(b):
             if lengths[r] or int(jnp.sum(args[7][r] >= 0)):  # anything visible
@@ -104,7 +126,8 @@ class TestBackendParity:
         # empty ring too: nothing visible at all
         args = args[:7] + (jnp.full_like(args[7], -1),) + args[8:]
         for backend in ("stream", "pallas", "materialized"):
-            out = np.asarray(chunk_attention(*args, backend=backend, tile=4))
+            out = np.asarray(chunk_attention(*stored(args), layer=LAYER,
+                                             backend=backend, tile=4))
             assert np.isfinite(out).all(), backend
 
 
@@ -142,10 +165,10 @@ class TestExactMasks:
                     v_ring[:, s] = 1.0
                 else:
                     v_new[:, s - cap] = 1.0
-                probes.append(np.asarray(chunk_attention(
+                probes.append(np.asarray(chunk_attention(*stored((
                     zeros(q), zeros(kn), jnp.asarray(v_new), zeros(kc), None,
-                    jnp.asarray(v_ring), None, pb, positions, lengths,
-                    window=window, backend=backend, tile=4)))
+                    jnp.asarray(v_ring), None, pb, positions, lengths)),
+                    layer=LAYER, window=window, backend=backend, tile=4)))
             outs[backend] = _visible_sets(np.stack(probes), cap + L)
 
         # first-principles brute force of the contract rule
@@ -246,7 +269,8 @@ class TestStreamingFootprint:
 
     def _args(self, cap):
         rng = np.random.default_rng(1)
-        return make_case(rng, self.B, self.L, self.KV, self.G, self.HD, cap)
+        return stored(make_case(rng, self.B, self.L, self.KV, self.G,
+                                self.HD, cap))
 
     def test_no_full_score_block_in_jaxpr(self):
         """Structural, not benched: the streaming jaxpr contains no

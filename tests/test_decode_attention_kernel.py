@@ -66,9 +66,12 @@ def test_matches_model_int8_decode_path():
     g = cfg.n_heads // kv
     q = dense(p["wq"], x_t).reshape(b, cfg.n_heads, hd)
     q = apply_rope(q, pos, cfg.rope_theta).reshape(b, kv, g, hd)
-    y_kern = decode_attention(
-        q.astype(jnp.float32), cache2["k"], cache2["k_scale"],
-        cache2["v"], cache2["v_scale"], cache2["pos"], pos)
+    # the model's ring storage, in the kernel's (B, S, KV, hd) layout
+    from repro.kernels.chunk_attention.ref import ring_layer
+    k8, ks, v8, vs, pb = ring_layer(
+        *(cache2[n][None] for n in ("k", "k_scale", "v", "v_scale", "pos")),
+        0, kv)
+    y_kern = decode_attention(q.astype(jnp.float32), k8, ks, v8, vs, pb, pos)
     y_kern = dense(p["wo"], y_kern.reshape(b, cfg.n_heads * hd))
     np.testing.assert_allclose(np.asarray(y_kern), np.asarray(y_model),
                                rtol=5e-3, atol=5e-3)

@@ -35,7 +35,7 @@ from repro.models import init_params
 from repro.serving import (EngineConfig, FaultInjector, FaultPlan,
                            PageAllocator, SamplingParams, SerialAdmitEngine,
                            ServingEngine, VirtualClock)
-from tests.test_chunk_attention_kernel import make_case
+from tests.test_chunk_attention_kernel import LAYER, make_case, stack, stored
 
 BACKENDS = ("materialized", "stream", "pallas")
 
@@ -43,7 +43,8 @@ BACKENDS = ("materialized", "stream", "pallas")
 def paginate(rng, ring_args, page_size):
     """Scatter a contiguous-ring case into randomly permuted physical
     pages: per-row page p of the ring lands at a random distinct physical
-    id >= 1; physical page 0 is the reserved null page (pos = -1)."""
+    id >= 1; physical page 0 is the reserved null page (pos = -1). Pools
+    come in the oracle's logical layout (``stored_paged`` stacks them)."""
     (q, kn, vn, kc, ks, vc, vs, pb, positions, lengths) = ring_args
     b, cap = pb.shape
     ps = page_size
@@ -66,6 +67,13 @@ def paginate(rng, ring_args, page_size):
     return (q, kn, vn, pool_of(kc), pool_of(ks), pool_of(vc), pool_of(vs),
             jnp.asarray(pos_pool), jnp.asarray(table, jnp.int32),
             positions, lengths)
+
+
+def stored_paged(paged):
+    """A ``paginate`` input with its pools in the op's stacked storage."""
+    q, kn, vn, kp, ksp, vp, vsp, posp, table, positions, lengths = paged
+    return (q, kn, vn, *stack(kp, ksp, vp, vsp, posp), table, positions,
+            lengths)
 
 
 PAGED_CASES = [
@@ -92,10 +100,12 @@ class TestPageGatherBitIdentity:
         rng = np.random.default_rng(hash((b, L, cap, ps, int8)) % 2**31)
         ring = make_case(rng, b, L, kv, g, hd, cap, int8=int8, wrap=wrap)
         paged = paginate(rng, ring, ps)
-        want = np.asarray(chunk_attention(*ring, window=window,
-                                          backend=backend, tile=ps))
-        got = np.asarray(chunk_attention_paged(*paged, window=window,
-                                               backend=backend, tile=ps))
+        want = np.asarray(chunk_attention(*stored(ring), layer=LAYER,
+                                          window=window, backend=backend,
+                                          tile=ps))
+        got = np.asarray(chunk_attention_paged(
+            *stored_paged(paged), layer=LAYER, window=window,
+            backend=backend, tile=ps))
         np.testing.assert_array_equal(got, want, err_msg=backend)
 
     def test_materialized_is_gather_then_oracle(self):
@@ -109,7 +119,8 @@ class TestPageGatherBitIdentity:
             q, kn, vn, gather_pages(kp, table), gather_pages(ksp, table),
             gather_pages(vp, table), gather_pages(vsp, table),
             gather_pages(posp, table), positions, lengths, window=None)
-        got = chunk_attention_paged(*paged, backend="materialized")
+        got = chunk_attention_paged(*stored_paged(paged), layer=LAYER,
+                                    backend="materialized")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         # and the gathered ring reconstructs the original exactly
         np.testing.assert_array_equal(np.asarray(gather_pages(posp, table)),
@@ -124,7 +135,8 @@ class TestPageGatherBitIdentity:
                          lengths=np.zeros((2,), np.int64))
         paged = paginate(rng, ring, 4)
         paged = paged[:8] + (jnp.zeros_like(paged[8]),) + paged[9:]
-        out = np.asarray(chunk_attention_paged(*paged, backend=backend))
+        out = np.asarray(chunk_attention_paged(*stored_paged(paged),
+                                               layer=LAYER, backend=backend))
         assert np.isfinite(out).all(), backend
 
     def test_paged_tile_divides_page(self):
@@ -238,6 +250,29 @@ class TestEnginePrefixReuse:
         assert cold_eng.alloc.hits == 0
         # cache reuse showed up as skipped prefill work
         assert warm_eng.prefill_steps < cold_eng.prefill_steps
+
+    @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+    def test_multilayer_greedy_matches_across_backends_and_layouts(
+            self, kv_dtype):
+        """A four-layer model, whose ring is one stack read at each layer's
+        index: greedy tokens are identical on the stream and materialized
+        backends and on the ring and paged layouts."""
+        cfg = configs.get_smoke_config("qwen2-1.5b").scaled(
+            n_layers=4, kv_cache_dtype=kv_dtype)
+        params = init_params(cfg, jax.random.PRNGKey(2))
+        rng = np.random.default_rng(4)
+        prompts = [rng.integers(1, 500, size=n).tolist() for n in (3, 40)]
+
+        def run(**kw):
+            eng = ServingEngine(params, cfg, paged_cfg(**kw))
+            hs = [eng.submit(p, SamplingParams(max_new_tokens=6))
+                  for p in prompts]
+            eng.run()
+            return [h.output for h in hs]
+
+        want = run(kv_layout="ring", attn_backend="materialized")
+        assert run(kv_layout="ring", attn_backend="stream") == want
+        assert run(attn_backend="stream") == want
 
     def test_pages_return_to_baseline_after_drain(self, paged_model):
         cfg, params = paged_model

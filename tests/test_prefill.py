@@ -97,6 +97,84 @@ class TestPrefillChunkModel:
             assert jnp.array_equal(a, b)
 
 
+def _ring_rows(state, row):
+    """Every ring leaf of a state at one batch row, all layers at once:
+    {path: array}. Recurrent leaves and the top-level position are left
+    out; ``row_axis`` says where each leaf keeps its rows."""
+    from repro.models.attention import row_axis
+
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        keys = [k.key for k in path]
+        if keys[-1] not in ("k", "v", "k_scale", "v_scale", "pos") \
+                or len(keys) == 1:
+            continue
+        axis = row_axis(keys[-1]) + (keys[0] == "blocks")
+        out["/".join(keys)] = np.asarray(jnp.take(leaf, row, axis=axis))
+    return out
+
+
+class TestStackedRing:
+    """The ring of a scan's layers is one stack carried through the layer
+    scan: each layer is read at its index and written only where its
+    tokens land."""
+
+    @pytest.fixture(scope="class")
+    def deep(self, models):
+        cfg, _ = models["qwen2-1.5b"]
+        cfg = cfg.scaled(n_layers=4)
+        return cfg, init_params(cfg, jax.random.PRNGKey(1))
+
+    @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+    def test_length_zero_row_leaves_every_layer_untouched(self, deep,
+                                                          kv_dtype):
+        """A row that rides through a prefill chunk with length 0, or
+        through a decode step inactive, keeps its ring slice in every
+        layer bit for bit, while the other row's slice changes in every
+        layer."""
+        cfg, params = deep
+        cfg = cfg.scaled(kv_cache_dtype=kv_dtype)
+        st = init_decode_state(cfg, 2, 16)
+        toks = jnp.asarray([[3, 4, 5, 6], [7, 8, 9, 1]], jnp.int32)
+        _, st = prefill_chunk(params, cfg, st, {"tokens": toks},
+                              jnp.asarray([4, 2], jnp.int32))
+        before = [_ring_rows(st, r) for r in (0, 1)]
+        _, st2 = prefill_chunk(params, cfg, st, {"tokens": toks},
+                               jnp.asarray([3, 0], jnp.int32))
+        _, st3 = decode_step(params, cfg, st2, jnp.asarray([5, 5], jnp.int32),
+                             active=jnp.asarray([True, False]))
+        for after in (st2, st3):
+            kept, moved = _ring_rows(after, 1), _ring_rows(after, 0)
+            assert kept.keys() == before[1].keys() and len(kept) >= 3
+            for path, leaf in kept.items():
+                np.testing.assert_array_equal(leaf, before[1][path], path)
+            for path in ("blocks/b0/k", "blocks/b0/pos"):
+                for layer in range(cfg.n_layers):
+                    assert not np.array_equal(moved[path][layer],
+                                              before[0][path][layer])
+
+    def test_layers_hold_their_own_keys(self, deep):
+        """Each layer's slice of the stacked ring holds that layer's keys:
+        the stack after a chunk equals the keys each layer computes from
+        its own input, which a whole-prompt prefill writes layer by
+        layer."""
+        cfg, params = deep
+        prompt = jnp.asarray([[3, 4, 5, 6, 7]], jnp.int32)
+        _, st_full = prefill(params, cfg, {"tokens": prompt}, capacity=8)
+        st = init_decode_state(cfg, 1, 8)
+        _, st = prefill_chunk(params, cfg, st, {"tokens": prompt},
+                              jnp.asarray([5], jnp.int32))
+        ring, ring_full = st["blocks"]["b0"], st_full["blocks"]["b0"]
+        np.testing.assert_array_equal(ring["pos"], ring_full["pos"])
+        np.testing.assert_allclose(ring["k"], ring_full["k"],
+                                   rtol=2e-5, atol=2e-5)
+        # distinct layers, distinct keys: no layer was written into another
+        k = np.asarray(ring["k"])[:, 0, :5]
+        for a in range(cfg.n_layers):
+            for b in range(a + 1, cfg.n_layers):
+                assert not np.allclose(k[a], k[b])
+
+
 class TestBucketedScheduler:
     @pytest.fixture(scope="class")
     def small_model(self, models):
@@ -227,6 +305,29 @@ class TestBucketedScheduler:
         # (40 tokens / chunk 8 = 5 prefill steps, decode ran throughout)
         assert eng.prefill_steps >= 5
         assert eng.steps > decode_steps_before
+
+    @pytest.mark.parametrize("kv_layout", ["ring", "paged"])
+    def test_reused_slot_reads_nothing_stale(self, small_model, kv_layout):
+        """A slot reused after a longer request holds that request's keys
+        in ring slots past the new prompt; the new request must read none
+        of them: its tokens equal those of a fresh engine."""
+        cfg, params = small_model
+        rng = np.random.default_rng(5)
+        long_p = rng.integers(1, 500, size=40).tolist()
+        short_p = rng.integers(1, 500, size=5).tolist()
+        kw = dict(max_slots=1, capacity=64, prefill_chunk=8, decode_chunk=4,
+                  kv_layout=kv_layout, page_size=16)
+
+        def serve(prompts):
+            eng = ServingEngine(params, cfg, EngineConfig(**kw))
+            hs = [eng.submit(p, SamplingParams(max_new_tokens=n))
+                  for p, n in prompts]
+            eng.run()
+            return [h.output for h in hs]
+
+        reused = serve([(long_p, 20), (short_p, 12)])[1]
+        assert reused == serve([(short_p, 12)])[0]
+        assert len(reused) == 12
 
     def test_empty_prompt_rejected(self, small_model):
         cfg, params = small_model

@@ -15,8 +15,10 @@ off around the compiles (an entry written for a described chip cannot be
 read back without one).
 """
 
+import functools
 import importlib.util
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -39,6 +41,7 @@ G = 128
 LINEAR = [(1536, 1536), (256, 1536), (8960, 1536), (1536, 8960),
           (151936, 1536), (32064, 3072), (10944, 2048)]
 SLOTS, CAP, KV, GROUP, HD = 8, 4096, 2, 6, 128
+LAYERS = 2  # the ring is one stack over a scan's layers, read at an index
 # decode, the EngineConfig default prefill chunk, and two longer chunks
 # whose default tiles (64, 32) are off the lane rule
 CHUNKS = [1, 64, 128, 256]
@@ -96,23 +99,36 @@ def _attn_inputs(sh, L):
             _spec(sh, (SLOTS, L, KV, HD), jnp.bfloat16)]
 
 
+def _storage(sh, rows, slots, dtype):
+    """Stacked ring (or pool) storage as ``models.attention`` allocates it:
+    k, v (layers, rows, slots, KV·hd), positions (layers, rows, slots),
+    int8 scales (layers, KV, rows, slots)."""
+    kv = _spec(sh, (LAYERS, rows, slots, KV * HD), dtype)
+    return ([kv, kv, _spec(sh, (LAYERS, rows, slots), jnp.int32)],
+            [_spec(sh, (LAYERS, KV, rows, slots), jnp.float32)] * 2)
+
+
+def _pool_pages(n_pages):
+    """Physical pages of a pool: the slots' pages + the null page, rounded
+    up to a multiple of 8 as ``paged_cache_init`` allocates them."""
+    return -(-(SLOTS * n_pages + 1) // 8) * 8
+
+
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8])
 @pytest.mark.parametrize("L", CHUNKS)
 def test_chunk_attention_ring_compiles(one_chip, L, cache_dtype):
     int8 = cache_dtype == jnp.int8
     tile = lane_tile(CAP, _select_tile(CAP, L))
-    args = _attn_inputs(one_chip, L) + [
-        _spec(one_chip, (SLOTS, CAP, KV, HD), cache_dtype),
-        _spec(one_chip, (SLOTS, CAP, KV, HD), cache_dtype),
-        _spec(one_chip, (SLOTS, CAP), jnp.int32),
+    ring, scales = _storage(one_chip, SLOTS, CAP, cache_dtype)
+    args = _attn_inputs(one_chip, L) + ring + [
         _spec(one_chip, (SLOTS, L), jnp.int32),
-        _spec(one_chip, (SLOTS,), jnp.int32)]
-    scales = [_spec(one_chip, (SLOTS, CAP, KV), jnp.float32)] * 2
+        _spec(one_chip, (SLOTS,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)]
 
-    def fn(q, kn, vn, kc, vc, pb, pos, lens, *sc):
+    def fn(q, kn, vn, kc, vc, pb, pos, lens, layer, *sc):
         ks, vs = sc if int8 else (None, None)
         return chunk_attention_pallas(q, kn, vn, kc, ks, vc, vs, pb, pos,
-                                      lens, tile=tile, interpret=False)
+                                      lens, layer, tile=tile, interpret=False)
 
     _compile(fn, *args, *(scales if int8 else []))
 
@@ -123,22 +139,20 @@ def test_chunk_attention_ring_compiles(one_chip, L, cache_dtype):
 def test_chunk_attention_paged_compiles(one_chip, L, page, cache_dtype):
     int8 = cache_dtype == jnp.int8
     n_pages = CAP // page
-    pool = SLOTS * n_pages + 1                 # + the null page
     tile = lane_tile(page, paged_tile(page, L))
-    args = _attn_inputs(one_chip, L) + [
-        _spec(one_chip, (pool, page, KV, HD), cache_dtype),
-        _spec(one_chip, (pool, page, KV, HD), cache_dtype),
-        _spec(one_chip, (pool, page), jnp.int32),
+    pools, scales = _storage(one_chip, _pool_pages(n_pages), page,
+                             cache_dtype)
+    args = _attn_inputs(one_chip, L) + pools + [
         _spec(one_chip, (SLOTS, n_pages), jnp.int32),
         _spec(one_chip, (SLOTS, L), jnp.int32),
-        _spec(one_chip, (SLOTS,), jnp.int32)]
-    scales = [_spec(one_chip, (pool, page, KV), jnp.float32)] * 2
+        _spec(one_chip, (SLOTS,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)]
 
-    def fn(q, kn, vn, kp, vp, pp, table, pos, lens, *sc):
+    def fn(q, kn, vn, kp, vp, pp, table, pos, lens, layer, *sc):
         ks, vs = sc if int8 else (None, None)
         return chunk_attention_paged_pallas(
-            q, kn, vn, kp, ks, vp, vs, pp, table, pos, lens, tile=tile,
-            interpret=False)
+            q, kn, vn, kp, ks, vp, vs, pp, table, pos, lens, layer,
+            tile=tile, interpret=False)
 
     _compile(fn, *args, *(scales if int8 else []))
 
@@ -183,28 +197,28 @@ def test_kernels_carry_their_names(one_chip):
     assert trace.kernel_of(op) == "ternary_matmul"
 
     L = 32
-    ring = [_spec(one_chip, (SLOTS, CAP, KV, HD), jnp.bfloat16)] * 2 + [
-        _spec(one_chip, (SLOTS, CAP), jnp.int32),
+    ring = _storage(one_chip, SLOTS, CAP, jnp.bfloat16)[0] + [
         _spec(one_chip, (SLOTS, L), jnp.int32),
-        _spec(one_chip, (SLOTS,), jnp.int32)]
+        _spec(one_chip, (SLOTS,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)]
     tile = lane_tile(CAP, _select_tile(CAP, L))
     page, n_pages = 16, CAP // 16
-    pool = SLOTS * n_pages + 1
-    paged = [_spec(one_chip, (pool, page, KV, HD), jnp.bfloat16)] * 2 + [
-        _spec(one_chip, (pool, page), jnp.int32),
+    paged = _storage(one_chip, _pool_pages(n_pages), page,
+                     jnp.bfloat16)[0] + [
         _spec(one_chip, (SLOTS, n_pages), jnp.int32),
         _spec(one_chip, (SLOTS, L), jnp.int32),
-        _spec(one_chip, (SLOTS,), jnp.int32)]
+        _spec(one_chip, (SLOTS,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)]
     compiled = [
-        _compile(lambda q, kn, vn, kc, vc, pb, pos, lens:
+        _compile(lambda q, kn, vn, kc, vc, pb, pos, lens, layer:
                  chunk_attention_pallas(q, kn, vn, kc, None, vc, None, pb,
-                                        pos, lens, tile=tile,
+                                        pos, lens, layer, tile=tile,
                                         interpret=False),
                  *_attn_inputs(one_chip, L), *ring),
-        _compile(lambda q, kn, vn, kp, vp, pp, table, pos, lens:
+        _compile(lambda q, kn, vn, kp, vp, pp, table, pos, lens, layer:
                  chunk_attention_paged_pallas(
                      q, kn, vn, kp, None, vp, None, pp, table, pos, lens,
-                     tile=lane_tile(page, paged_tile(page, L)),
+                     layer, tile=lane_tile(page, paged_tile(page, L)),
                      interpret=False),
                  *_attn_inputs(one_chip, L), *paged)]
     for c in compiled:
@@ -212,6 +226,69 @@ def test_kernels_carry_their_names(one_chip):
         assert _instruction(op).startswith("chunk_attention")
         hidden = op.replace("tpu_custom_call", "hidden_target")
         assert trace.kernel_of(hidden) == "chunk_attention"
+
+
+# ops that copy, fill, slice or relay out a buffer; none may produce a
+# ring-shaped array (a whole stacked leaf, or one layer's (B, cap, ...))
+_RING_OPS = ("broadcast", "copy", "dynamic-slice", "dynamic-update-slice",
+             "transpose")
+
+
+def _ring_shaped_ops(compiled, b: int, cap: int):
+    """(opcode, result type) of every HLO instruction, fused ones included,
+    of a kind in ``_RING_OPS`` whose result is ring-shaped."""
+    ring = re.compile(r"\[(?:%d,)?%d,%d\b" % (LAYERS, b, cap))
+    found = []
+    for ln in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\S+?)(?:\{[^}]*\})? "
+                     r"([a-z-]+)\(", ln)
+        if m and m.group(2) in _RING_OPS and ring.search(m.group(1)):
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+def test_chat_programs_keep_the_ring_in_place(one_chip, monkeypatch):
+    """The chat engine's programs at qwen2-1.5b widths (64 slots × 3072
+    ring slots, bf16 ring, two layers): a padded prefill chunk of 32 and
+    the fused decode loop of 2 steps. The layer scan carries the stacked
+    ring, the kernel reads it at the layer index and each step scatters
+    only its own tokens, so no ring-sized fill, copy, slice or relayout
+    exists and the temporaries stay far below one stacked ring leaf."""
+    from repro import configs
+    from repro.models import init_decode_state, init_params, prefill_chunk
+    from repro.serving.engine import _decode_loop
+
+    b, cap, chunk = 64, 3072, 32
+    cfg = configs.get_config("qwen2-1.5b").scaled(
+        n_layers=LAYERS, param_dtype="bfloat16",
+        activation_dtype="bfloat16", remat="none")
+    spec = lambda tree: jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    params = spec(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    state = spec(jax.eval_shape(lambda: init_decode_state(cfg, b, cap)))
+    rows = lambda dtype, *shape: _spec(one_chip, (b,) + shape, dtype)
+    # the program takes its TPU branch (Pallas kernels) from the platform
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prefill = jax.jit(
+        lambda p, s, t, n: prefill_chunk(p, cfg, s, {"tokens": t}, n),
+        donate_argnums=1).lower(
+            params, state, rows(jnp.int32, chunk), rows(jnp.int32)).compile()
+    decode = jax.jit(functools.partial(
+        _decode_loop, cfg=cfg, n_steps=2, use_mask=False),
+        donate_argnums=1).lower(
+            params, state, rows(jnp.int32), rows(jnp.float32),
+            rows(jnp.bool_), rows(jnp.uint32), rows(jnp.int32),
+            rows(jnp.int32), rows(jnp.float32), rows(jnp.int32, 1),
+            rows(jnp.int32)).compile()
+
+    leaf = state["blocks"]["b0"]["k"]
+    leaf_bytes = leaf.size * leaf.dtype.itemsize
+    for name, compiled in (("prefill", prefill), ("decode", decode)):
+        assert "tpu_custom_call" in compiled.as_text(), name
+        assert _ring_shaped_ops(compiled, b, cap) == [], name
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < leaf_bytes // 4, (name, temp, leaf_bytes)
 
 
 def test_quantizer_fits_hbm_at_lm_head(one_chip):
