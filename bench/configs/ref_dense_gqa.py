@@ -3,8 +3,9 @@
 Straight ``jax.numpy`` in float32 at HIGHEST matmul precision, with no
 kernel, cache or batching trick: the whole sequence at once, causal
 softmax attention, one layer at a time. It imports nothing of the program
-under test; its weights come from the seed through ``harness.weights``,
-dequantized here (Ŵ = α¹T¹ + α²T², exact in f32).
+under test; its weights come from the seed through the harness
+(``weights.Seeded``: the seed under the architecture's leaf rules),
+dequantized (Ŵ = α¹T¹ + α²T², exact in f32).
 
 Layer equations (Qwen2, arXiv:2407.10671):
 
@@ -29,8 +30,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-
-from harness import weights
 
 HI = jax.lax.Precision.HIGHEST
 QUERY_BLOCK = 512
@@ -100,15 +99,14 @@ def _dims(c: Dict):
             float(c["rope_theta"]))
 
 
-def _layer_params(c: Dict, seed: int, layer: int):
+def _layer_params(c: Dict, w, layer: int):
     d, ff = c["hidden_size"], c["intermediate_size"]
     heads, kv, hd, _, _ = _dims(c)
     grp = c["quantization"]["group_size"]
     dt = c["torch_dtype"]
-    W = lambda path, i, o: weights.reference_matrix(
-        seed, f"/blocks/b0/{path}/kernel", layer, i, o, grp)
-    V = lambda path, n: weights.reference_leaf(
-        seed, f"/blocks/b0/{path}", layer, (n,), dt)
+    W = lambda path, i, o: w.matrix(f"/blocks/b0/{path}/kernel", layer, i,
+                                    o, grp)
+    V = lambda path, n: w.leaf(f"/blocks/b0/{path}", layer, (n,), dt)
     return {
         "attn_norm": V("attn_norm/scale", d), "mlp_norm": V("mlp_norm/scale", d),
         "wq": W("attn/wq", d, heads * hd), "bq": V("attn/wq/bias", heads * hd),
@@ -125,22 +123,20 @@ def _embed(table, tokens):
     return jnp.take(table, tokens, axis=0)
 
 
-def final_hidden(c: Dict, seed: int, tokens, fp8: bool = False):
-    """(B, T) token ids -> (B, T, d) final-normed hidden states, f32."""
+def final_hidden(c: Dict, w, tokens, fp8: bool = False):
+    """(B, T) token ids -> (B, T, d) final-normed hidden states, f32, with
+    the weights that ``w`` (``weights.Seeded``) draws."""
     d, v = c["hidden_size"], c["vocab_size"]
-    table = weights.reference_leaf(seed, "/embed/embedding", -1, (v, d),
-                                   c["torch_dtype"])
+    table = w.leaf("/embed/embedding", -1, (v, d), c["torch_dtype"])
     x = _embed(table, jnp.asarray(tokens))
     del table
     for layer in range(c["num_hidden_layers"]):
-        x = _layer(x, _layer_params(c, seed, layer), dims=_dims(c), fp8=fp8)
-    scale = weights.reference_leaf(seed, "/final_norm/scale", -1, (d,),
-                                   c["torch_dtype"])
+        x = _layer(x, _layer_params(c, w, layer), dims=_dims(c), fp8=fp8)
+    scale = w.leaf("/final_norm/scale", -1, (d,), c["torch_dtype"])
     return rms(x, scale, float(c["rms_norm_eps"]))
 
 
-def head(c: Dict, seed: int):
+def head(c: Dict, w):
     """The output head Ŵ (d, V), f32."""
-    return weights.reference_matrix(seed, "/lm_head/kernel", -1,
-                                    c["hidden_size"], c["vocab_size"],
-                                    c["quantization"]["group_size"])
+    return w.matrix("/lm_head/kernel", -1, c["hidden_size"], c["vocab_size"],
+                    c["quantization"]["group_size"])

@@ -3,7 +3,8 @@
 Straight ``jax.numpy`` in float32 at HIGHEST matmul precision: the whole
 sequence per layer, the WKV recurrence as a plain scan over time from a
 zero state. It imports nothing of the program under test; its weights
-come from the seed through ``harness.weights``.
+come from the seed through the harness (``weights.Seeded``: the seed
+under the architecture's leaf rules).
 
 Layer equations (Finch, arXiv:2404.05892), as the program states them:
 
@@ -34,8 +35,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-
-from harness import weights
 
 HI = jax.lax.Precision.HIGHEST
 MIX = ("w", "k", "v", "r", "g")
@@ -112,15 +111,14 @@ def _dims(c: Dict):
     return (c["hidden_size"] // hd, hd, float(c["layer_norm_epsilon"]))
 
 
-def _layer_params(c: Dict, seed: int, layer: int):
+def _layer_params(c: Dict, w, layer: int):
     d, ff = c["hidden_size"], c["intermediate_size"]
     heads, hd, _ = _dims(c)
     grp = c["quantization"]["group_size"]
     dt = c["torch_dtype"]
-    W = lambda path, i, o: weights.reference_matrix(
-        seed, f"/blocks/b0/{path}/kernel", layer, i, o, grp)
-    V = lambda path, shape: weights.reference_leaf(
-        seed, f"/blocks/b0/{path}", layer, shape, dt)
+    W = lambda path, i, o: w.matrix(f"/blocks/b0/{path}/kernel", layer, i,
+                                    o, grp)
+    V = lambda path, shape: w.leaf(f"/blocks/b0/{path}", layer, shape, dt)
     return {
         "time_norm": V("time_norm/scale", (d,)),
         "chan_norm": V("chan_norm/scale", (d,)),
@@ -145,22 +143,20 @@ def _embed(table, tokens):
     return jnp.take(table, tokens, axis=0)
 
 
-def final_hidden(c: Dict, seed: int, tokens, fp8: bool = False):
-    """(B, T) token ids -> (B, T, d) final-normed hidden states, f32."""
+def final_hidden(c: Dict, w, tokens, fp8: bool = False):
+    """(B, T) token ids -> (B, T, d) final-normed hidden states, f32, with
+    the weights that ``w`` (``weights.Seeded``) draws."""
     d, v = c["hidden_size"], c["vocab_size"]
-    table = weights.reference_leaf(seed, "/embed/embedding", -1, (v, d),
-                                   c["torch_dtype"])
+    table = w.leaf("/embed/embedding", -1, (v, d), c["torch_dtype"])
     x = _embed(table, jnp.asarray(tokens))
     del table
     for layer in range(c["num_hidden_layers"]):
-        x = _layer(x, _layer_params(c, seed, layer), dims=_dims(c), fp8=fp8)
-    scale = weights.reference_leaf(seed, "/final_norm/scale", -1, (d,),
-                                   c["torch_dtype"])
+        x = _layer(x, _layer_params(c, w, layer), dims=_dims(c), fp8=fp8)
+    scale = w.leaf("/final_norm/scale", -1, (d,), c["torch_dtype"])
     return rms(x, scale, float(c["layer_norm_epsilon"]))
 
 
-def head(c: Dict, seed: int):
+def head(c: Dict, w):
     """The output head Ŵ (d, V), f32."""
-    return weights.reference_matrix(seed, "/lm_head/kernel", -1,
-                                    c["hidden_size"], c["vocab_size"],
-                                    c["quantization"]["group_size"])
+    return w.matrix("/lm_head/kernel", -1, c["hidden_size"], c["vocab_size"],
+                    c["quantization"]["group_size"])

@@ -5,7 +5,10 @@ per-layer metric lives in a file of its own, found by the name that
 ``BENCHMARK.json`` gives it (see ``spec``):
 
 * ``bench/configs/<config>.json``  — the model as run, with the plain
-  reference it names (``bench/configs/ref_*.py``) beside it;
+  reference it names (``bench/configs/ref_*.py``) and the architecture
+  module of its ``model_type`` (``bench/configs/arch_<model_type>.py``:
+  its mapping onto the program's config, its leaf rules, its work per
+  layer, its kernels and its test size; see ``spec``) beside it;
 * ``bench/traffic/<traffic>.json`` — parameters that ``traffic`` turns into
   a request schedule;
 * ``bench/metrics/<metric>.py``    — a small reader of one per-layer metric.

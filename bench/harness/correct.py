@@ -77,21 +77,23 @@ def _block(h, h8, served, w, *, mm):
     return gap, best - jnp.take_along_axis(logits, pick[:, None], -1)[:, 0]
 
 
-def served_gaps(ref, c: Dict, seed: int, chosen: Sequence[Tuple],
+def served_gaps(ref, c: Dict, seeded, chosen: Sequence[Tuple],
                 control: bool = False) -> Dict[str, np.ndarray]:
     """Gaps of the compared served tokens of ``chosen`` ((record, n)
     pairs; with ``control``, also of the tokens the float8 reference puts
-    first)."""
-    w = ref.head(c, seed)
+    first); the reference draws its weights from ``seeded``
+    (``weights.Seeded``)."""
+    w = ref.head(c, seeded)
     out = {"gaps": [], "control_gaps": []}
     by_len = sorted(chosen, key=lambda rn: len(rn[0].req.prompt) + rn[1])
     for lo in range(0, len(by_len), PASS_ROWS):
-        _compare(ref, c, seed, w, by_len[lo:lo + PASS_ROWS], control, out)
+        _compare(ref, c, seeded, w, by_len[lo:lo + PASS_ROWS], control,
+                 out)
     return {k: (np.concatenate(v) if v else np.zeros((0,)))
             for k, v in out.items()}
 
 
-def _compare(ref, c: Dict, seed: int, w, chosen: Sequence[Tuple],
+def _compare(ref, c: Dict, seeded, w, chosen: Sequence[Tuple],
              control: bool, out: Dict[str, List]) -> None:
     """One reference pass over ``chosen``; appends their gaps to ``out``."""
     seqs = [np.concatenate([r.req.prompt,
@@ -101,9 +103,9 @@ def _compare(ref, c: Dict, seed: int, w, chosen: Sequence[Tuple],
     toks = np.zeros((PASS_ROWS, t_pad), np.int32)   # one shape per length
     for b, s in enumerate(seqs):
         toks[b, :len(s)] = s
-    hidden = {False: ref.final_hidden(c, seed, toks)}
+    hidden = {False: ref.final_hidden(c, seeded, toks)}
     if control:
-        hidden[True] = ref.final_hidden(c, seed, toks, fp8=True)
+        hidden[True] = ref.final_hidden(c, seeded, toks, fp8=True)
 
     for b, (r, n) in enumerate(chosen):
         p = len(r.req.prompt)

@@ -108,8 +108,7 @@ def traced_work(mdl: work.Model, run: Run, pc_open: float, pc_close: float,
                 peaks) -> Dict[str, work.Tally]:
     """Work the served tokens need in the dispatches the traced window
     holds (each dispatch by the host time it was issued)."""
-    out = {"ternary_matmul": work.Tally(), "chunk_attention": work.Tally(),
-           "other": work.Tally()}
+    out = mdl.tallies()
 
     def add(tallies):
         for k, v in tallies.items():
@@ -164,10 +163,11 @@ def serve(cell: Cell, seed: int, traced: bool) -> Serving:
     from repro.serving.observability import Observability
 
     c = cell.config
-    mcfg, ecfg = model.model_config(c), model.engine_config(c)
+    mcfg, ecfg = model.model_config(c, cell.arch), model.engine_config(c)
     compiles = CompileClock.get()
     params = weights.program_params(mcfg, seed,
-                                    c["quantization"]["group_size"])
+                                    c["quantization"]["group_size"],
+                                    weights.leaf_rules(cell.arch))
     engine = ServingEngine(params, mcfg, ecfg,
                            observability=Observability(trace=traced))
     del params
@@ -290,7 +290,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     cc = c["correct"]
     chosen = correct.sample(records, seed, int(cc["sample_requests"]),
                             int(cc["tokens_per_request"]))
-    gaps = (correct.served_gaps(cell.reference(), c, seed, chosen,
+    seeded = weights.Seeded(seed, weights.leaf_rules(cell.arch))
+    gaps = (correct.served_gaps(cell.reference(), c, seeded, chosen,
                                 control=control)
             if chosen else {"gaps": np.zeros(0), "control_gaps": np.zeros(0)})
     # closing the driver sheds what still waits ("driver closed"); any
@@ -312,8 +313,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     if traced:
         run.peaks = device.peaks(dev["kind"])
         run.reduction = trace.reduce(trace.newest_xplane(trace_dir),
-                                     marks["open"], marks["close"])
-        run.work = traced_work(work.Model(c), run, marks["open"],
+                                     marks["open"], marks["close"],
+                                     trace.kernel_set([cell.arch]))
+        run.work = traced_work(work.Model(c, cell.arch), run, marks["open"],
                                marks["close"], run.peaks)
         dev["busy_s"] = run.reduction.busy_s
         dev["window_s"] = run.reduction.window_s

@@ -1,4 +1,24 @@
-"""Resolve a cell of ``BENCHMARK.json`` into the files that define it."""
+"""Resolve a cell of ``BENCHMARK.json`` into the files that define it.
+
+Each is found by name: the configuration file by ``BENCHMARK.json``'s
+entry, its plain reference by the file's ``reference``, its architecture
+module (``configs/arch_<model_type>.py``) by the file's ``model_type``,
+the traffic mix by the cell's ``traffic``, and each per-layer metric's
+reader by the metric's name.
+
+An architecture module gives what the harness counts per architecture:
+
+* ``model_config(c)``: the program's ``ModelConfig`` keywords for
+  configuration file ``c`` (``model.model_config`` adds the shared ones);
+* ``layer(model, i)``: the work of layer ``i`` (``work.Layer``);
+* ``KERNELS`` (optional): kernel -> trace marks of the kernels it adds to
+  the ternary matmul, and ``unnamed_kernel(result, operands)`` (optional)
+  for one that shows in a trace under another call's name (``trace``);
+* ``LEAF_RULES`` (optional): name -> rule of the non-ternary leaves it
+  alone has (``weights.leaf_rules``);
+* ``SHRINK``: the keys that cut a configuration of this type to test
+  size (the tests' tiny trees).
+"""
 
 from __future__ import annotations
 
@@ -22,6 +42,7 @@ class Cell:
     config_name: str
     traffic_name: str
     config: Dict[str, Any]       # bench/configs/<config>.json, as run
+    arch: Any                    # bench/configs/arch_<model_type>.py
     traffic: Dict[str, Any]      # bench/traffic/<traffic>.json
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
@@ -45,6 +66,25 @@ def load_module(path: Path):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+ARCH_NAMES = ("model_config", "layer", "SHRINK")
+
+
+def load_arch(bench_dir: Path, model_type: str):
+    """The architecture module of ``model_type``, beside the
+    configurations."""
+    mod = load_module(Path(bench_dir) / "configs" / f"arch_{model_type}.py")
+    missing = [n for n in ARCH_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SpecError(f"architecture {model_type!r} lacks {missing}")
+    return mod
+
+
+def every_arch(bench_dir: Path = BENCH) -> List[Any]:
+    """Every architecture module under ``bench_dir/configs``."""
+    return [load_arch(bench_dir, p.stem[len("arch_"):]) for p in
+            sorted((Path(bench_dir) / "configs").glob("arch_*.py"))]
 
 
 def _read_json(path: Path) -> Dict[str, Any]:
@@ -76,11 +116,13 @@ def load_cell(root: Path, name: str, bench_dir: Optional[Path] = None) -> Cell:
 
     if not any(k in config.get("correct", {}) for k in STATS):
         raise SpecError(f"config {w['config']!r} compares no gap statistic")
+    arch = load_arch(bench_dir, config["model_type"])
     traffic = _read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     e2e = [m for m in top["end_to_end"] if _applies(m, name)]
     reported = {m["name"] for m in e2e}
     per_layer = [m for m in top["per_layer"]
                  if _applies(m, name) and m["moves"] in reported]
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                traffic_name=w["traffic"], config=config, traffic=traffic,
+                traffic_name=w["traffic"], config=config, arch=arch,
+                traffic=traffic,
                 end_to_end=e2e, per_layer=per_layer, bench_dir=bench_dir)

@@ -16,35 +16,75 @@ name what the host was doing in each idle gap of the device.
 * gaps: the window's complement of busy, on the first chip.
 
 Each op event's name is its HLO text (``%name.12 = type opcode(...)``).
-The ternary matmul's op carries its function's name. The chunk-attention
-``pallas_call`` has no name of its own in the trace (it shows as the call
-around it, ``%closed_call.13``), so it is also found by its signature: a
-TPU custom call with a rank-4 f32 result (rows, kv heads, group × chunk,
-head size) and the kernel's 11 operands (12 when paged).
+A kernel is found by marks, substrings of that text: the ternary matmul,
+which every architecture runs, by its function's name (``CORE``), and
+the kernels an architecture adds by the marks its module lists
+(``KERNELS``). A kernel whose ``pallas_call`` shows in the trace under
+the name of the call around it can be found by its signature instead:
+the architecture module's ``unnamed_kernel(result type, operands)`` names
+a TPU custom call that no mark found. A trace is read for the kernel set
+of its cell's architecture (``kernel_set``); without one, for every
+architecture beside this harness (``every_kernel``).
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import re
+import sys
 from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 MARK_OPEN = "bench.trace_window.open"
 MARK_CLOSE = "bench.trace_window.close"
 OPS_LINE = "XLA Ops"
-# kernel -> substrings of its op names in the device trace
-KERNELS = {
-    "ternary_matmul": ("ternary",),
-    "chunk_attention": ("chunk_attention",),
-}
+# kernel -> substrings of its op names in the device trace: the kernel that
+# every architecture runs
+CORE = {"ternary_matmul": ("ternary",)}
 # "%name.3 = <type>{layout} opcode(": a type is an array type or a tuple of
 # them, whose layouts hold one level of parentheses
 _HEAD = re.compile(r"^%?([^\s=]+) = (\((?:[^()]|\([^()]*\))*\)|\S+?)"
                    r"(?:\{[^}]*\})? ([a-z][\w-]*)\(")
-_ATTENTION_RESULT = re.compile(r"^f32\[\d+,\d+,\d+,\d+\]$")
 _CONTAINERS = ("while", "conditional", "call")
+
+
+@dataclasses.dataclass(frozen=True)
+class Kernels:
+    """The kernels a trace is read for: kernel -> marks, and the
+    architectures' ``unnamed_kernel`` fallbacks."""
+    marks: Dict[str, Tuple[str, ...]]
+    unnamed: Tuple[Callable[[str, int], Optional[str]], ...] = ()
+
+
+def kernel_set(archs) -> Kernels:
+    """``CORE`` and the kernels of the architecture modules ``archs``; an
+    architecture that names a kernel of ``CORE`` is an error."""
+    marks = {k: tuple(v) for k, v in CORE.items()}
+    unnamed = []
+    for arch in archs:
+        for kernel, own in getattr(arch, "KERNELS", {}).items():
+            if kernel in CORE:
+                raise ValueError(f"{arch.__name__} redefines kernel "
+                                 f"{kernel!r}")
+            marks[kernel] = tuple(dict.fromkeys(marks.get(kernel, ())
+                                                + tuple(own)))
+        if hasattr(arch, "unnamed_kernel"):
+            unnamed.append(arch.unnamed_kernel)
+    return Kernels(marks, tuple(unnamed))
+
+
+@functools.lru_cache(maxsize=1)
+def every_kernel() -> Kernels:
+    """The kernels of every architecture module beside this harness
+    (``bench/configs/arch_*.py``), for a trace read for no one cell."""
+    bench = str(Path(__file__).resolve().parents[1])
+    if bench not in sys.path:       # the modules import the harness by name
+        sys.path.insert(0, bench)
+    from harness import spec
+
+    return kernel_set(spec.every_arch(Path(bench)))
 
 
 @dataclasses.dataclass
@@ -98,26 +138,30 @@ def _operands(op_name: str) -> int:
     return args.count("%")
 
 
-def kernel_of(op_name: str) -> Optional[str]:
+def kernel_of(op_name: str, kernels: Optional[Kernels] = None
+              ) -> Optional[str]:
+    kernels = kernels or every_kernel()
     low = op_name.lower()
-    for kernel, marks in KERNELS.items():
+    for kernel, marks in kernels.marks.items():
         if any(m in low for m in marks):
             return kernel
     head = _head(op_name)
     if (head and head[2] == "custom-call"
-            and 'custom_call_target="tpu_custom_call"' in op_name
-            and _ATTENTION_RESULT.match(head[1])
-            and _operands(op_name) in (11, 12)):
-        return "chunk_attention"
+            and 'custom_call_target="tpu_custom_call"' in op_name):
+        for unnamed in kernels.unnamed:
+            kernel = unnamed(head[1], _operands(op_name))
+            if kernel is not None:
+                return kernel
     return None
 
 
-def op_key(op_name: str) -> Optional[str]:
+def op_key(op_name: str, kernels: Optional[Kernels] = None
+           ) -> Optional[str]:
     """A short name that groups one kind of op: the kernel's name, or the
     instruction's name without its numbers, with its result type. None
     for an op that holds others (a loop or a call), whose time its body's
     ops already count."""
-    kernel = kernel_of(op_name)
+    kernel = kernel_of(op_name, kernels)
     if kernel is not None:
         return kernel
     head = _head(op_name)
@@ -138,10 +182,13 @@ def newest_xplane(trace_dir: Path) -> Path:
     return found[-1]
 
 
-def reduce(xplane: Path, pc_open: float, pc_close: float) -> Reduction:
-    """``pc_open``/``pc_close``: perf_counter read inside the two marks."""
+def reduce(xplane: Path, pc_open: float, pc_close: float,
+           kernels: Optional[Kernels] = None) -> Reduction:
+    """``pc_open``/``pc_close``: perf_counter read inside the two marks;
+    ``kernels``: the cell's (``kernel_set``), or every architecture's."""
     from jax.profiler import ProfileData
 
+    kernels = kernels or every_kernel()
     data = ProfileData.from_file(str(xplane))
     marks: Dict[str, float] = {}
     devices = []
@@ -177,10 +224,10 @@ def reduce(xplane: Path, pc_open: float, pc_close: float) -> Reduction:
             inside = max(0.0, min(s + n, hi) - max(s, lo))
             if inside <= 0:
                 continue
-            key = op_key(name)
+            key = op_key(name, kernels)
             if key is not None:
                 ops[key] += inside / 1e9
-            k = kernel_of(name)
+            k = kernel_of(name, kernels)
             if k is not None:
                 kernel_s[k] += inside / 1e9
     n = max(len(devices), 1)

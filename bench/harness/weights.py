@@ -1,6 +1,7 @@
 """Weights made from the seed: random trit-planes in the artifact's layout.
 
-Every leaf is a pure function of (seed, leaf path, layer), so the program
+Every leaf is a pure function of (seed, leaf path, layer, and in a stack
+of matrices such as a layer's experts the matrix's place), so the program
 side and the plain reference draw the same numbers without sharing any
 array. Every value is an integer times a power of two, exact in its dtype,
 so no rounding inside either side's programs can make the two differ.
@@ -14,7 +15,9 @@ so no rounding inside either side's programs can make the two differ.
   initializer. The program gets them packed (``QuantizedKernel``); the
   reference dequantizes them itself, one matrix at a time.
 * Every other leaf is drawn in its own dtype from a rule chosen by its
-  name (``_DENSE_RULES``); an unknown name is an error, not a default.
+  name: the rules every architecture shares (``SHARED_RULES``) and the
+  architecture module's own (``LEAF_RULES``); an unknown name is an
+  error, not a default.
 
 The program side is one jitted call, which maps over the layers of each
 stacked leaf so that no stack of int8 trits or f32 values is ever whole.
@@ -22,9 +25,10 @@ stacked leaf so that no stack of int8 trits or f32 values is ever whole.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import zlib
-from typing import Any, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +46,7 @@ def leaf_key(key: jax.Array, path: str) -> jax.Array:
     return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
-def _ints(key, shape, lo: int, hi: int):
+def ints(key, shape, lo: int, hi: int):
     """Integers uniform over [lo, hi] (hi - lo < 255), as int32."""
     b = jax.random.bits(key, shape, jnp.uint8).astype(jnp.int32)
     return lo + b % (hi - lo + 1)
@@ -80,33 +84,41 @@ def dequantized(t1, t2, alpha, group: int) -> jax.Array:
     return w.reshape(n, d).T
 
 
-def _signed(key, shape, exp: int):
-    return _ints(key, shape, -127, 127).astype(jnp.float32) * 2.0 ** exp
+def signed(key, shape, exp: int):
+    """Integers uniform over [-127, 127] times 2**exp, as f32."""
+    return ints(key, shape, -127, 127).astype(jnp.float32) * 2.0 ** exp
 
 
-# name -> rule(key, shape, path) giving f32 values exact in bf16
-_DENSE_RULES = {
-    "scale": lambda k, s, p: 1.0 + _ints(k, s, -16, 16) * 2.0 ** -7,
-    "embedding": lambda k, s, p: _signed(k, s, -12),
-    "bias": lambda k, s, p: _signed(k, s, -9),
-    "mu_x": lambda k, s, p: _ints(k, s, 0, 255) * 2.0 ** -8,
-    "mu": lambda k, s, p: _ints(k, s, 0, 255) * 2.0 ** -8,
-    "mu_k": lambda k, s, p: _ints(k, s, 0, 255) * 2.0 ** -8,
-    "mu_r": lambda k, s, p: _ints(k, s, 0, 255) * 2.0 ** -8,
-    "decay_base": lambda k, s, p: -6.0 + _ints(k, s, 0, 40) * 2.0 ** -3,
-    "u": lambda k, s, p: _signed(k, s, -10),
-    "mix_lora_a": lambda k, s, p: _signed(k, s, -13),
-    "mix_lora_b": lambda k, s, p: _signed(k, s, -13),
-    "decay_lora_a": lambda k, s, p: _signed(k, s, -13),
-    "decay_lora_b": lambda k, s, p: _signed(k, s, -13),
+# name -> rule(key, shape, path) giving f32 values exact in bf16: the leaves
+# every architecture has; an architecture's own are its ``LEAF_RULES``
+SHARED_RULES = {
+    "scale": lambda k, s, p: 1.0 + ints(k, s, -16, 16) * 2.0 ** -7,
+    "embedding": lambda k, s, p: signed(k, s, -12),
+    "bias": lambda k, s, p: signed(k, s, -9),
 }
 
 
-def dense_value(key, path: str, shape, dtype) -> jax.Array:
+def leaf_rules(arch) -> Dict[str, Callable]:
+    """The shared rules with the architecture module's ``LEAF_RULES``
+    merged over them; a name that both give is an error."""
+    own = getattr(arch, "LEAF_RULES", {})
+    clash = sorted(SHARED_RULES.keys() & own.keys())
+    if clash:
+        raise ValueError(f"{arch.__name__} redefines shared leaf rules "
+                         f"{clash}")
+    return {**SHARED_RULES, **own}
+
+
+def _rule(path: str, rules: Dict[str, Callable]) -> Callable:
     name = path.rsplit("/", 1)[-1]
-    if name not in _DENSE_RULES:
-        raise KeyError(f"no rule for leaf {path!r}: add one to _DENSE_RULES")
-    return _DENSE_RULES[name](key, tuple(shape), path).astype(dtype)
+    if name not in rules:
+        raise KeyError(f"no rule for leaf {path!r}: add one to the "
+                       f"architecture's LEAF_RULES")
+    return rules[name]
+
+
+def dense_value(key, path: str, shape, dtype, rules) -> jax.Array:
+    return _rule(path, rules)(key, tuple(shape), path).astype(dtype)
 
 
 def stacked(path: str) -> bool:
@@ -149,9 +161,10 @@ def leaves(model_cfg, group: int) -> List[Tuple[str, Any, bool]]:
             for path, sds in flatten(shapes)]
 
 
-def program_params(model_cfg, seed: int, group: int):
+def program_params(model_cfg, seed: int, group: int, rules):
     """The program's params for ``model_cfg``, made on the device in one
-    jitted call: ternary leaves as packed ``QuantizedKernel``s."""
+    jitted call: ternary leaves as packed ``QuantizedKernel``s, the others
+    by ``rules`` (``leaf_rules``)."""
     from repro.core.packing import pack_trits
     from repro.core.quantize_model import QuantizedKernel
 
@@ -168,11 +181,13 @@ def program_params(model_cfg, seed: int, group: int):
             shape = tuple(sds.shape)
             per = shape[1:] if stacked(path) else shape
             if ternary:
-                d_in, d_out = per[-2], per[-1]
+                *batch, d_in, d_out = per
                 fn = lambda kk, d_in=d_in, d_out=d_out: packed(kk, d_in, d_out)
+                if batch:
+                    fn = _each(fn, batch)
             else:
                 fn = (lambda kk, path=path, per=per, dt=sds.dtype:
-                      dense_value(kk, path, per, dt))
+                      dense_value(kk, path, per, dt, rules))
             if stacked(path):
                 val = jax.lax.map(
                     lambda l, k=k, fn=fn: fn(jax.random.fold_in(k, l)),
@@ -187,40 +202,66 @@ def program_params(model_cfg, seed: int, group: int):
     return jax.jit(build)(seed_key(seed))
 
 
-def _ref_matrix_impl(raw_key, layer, *, path, d_in, d_out, group, stack):
+def _each(fn, batch):
+    """``fn`` over a stack of ``batch`` matrices (the experts of a layer):
+    the i-th (row-major) from ``fold_in(key, i)``."""
+    n = math.prod(batch)
+
+    def stack(key):
+        out = jax.lax.map(lambda i: fn(jax.random.fold_in(key, i)),
+                          jnp.arange(n))
+        return jax.tree.map(lambda a: a.reshape(*batch, *a.shape[1:]), out)
+
+    return stack
+
+
+def _ref_matrix_impl(raw_key, layer, index, *, path, d_in, d_out, group,
+                     stack, in_stack):
     k = leaf_key(raw_key, path)
     if stack:
         k = jax.random.fold_in(k, layer)
+    if in_stack:
+        k = jax.random.fold_in(k, index)
     return dequantized(*ternary_parts(k, d_in, d_out, group), group)
 
 
 _ref_matrix_jitted = jax.jit(_ref_matrix_impl, static_argnames=(
-    "path", "d_in", "d_out", "group", "stack"))
+    "path", "d_in", "d_out", "group", "stack", "in_stack"))
 
 
-def reference_matrix(seed: int, path: str, layer: int, d_in: int,
-                     d_out: int, group: int) -> jax.Array:
-    """Ŵ (d_in, d_out) f32 of one ternary matrix (layer ``layer`` of a
-    stacked leaf, or -1 for an unstacked one), drawn as the program's."""
-    return _ref_matrix_jitted(seed_key(seed), max(layer, 0), path=path,
-                              d_in=d_in, d_out=d_out, group=group,
-                              stack=layer >= 0)
-
-
-def _ref_dense_impl(raw_key, layer, *, path, shape, dtype, stack):
+def _ref_dense_impl(raw_key, layer, *, path, shape, dtype, stack, rule):
     k = leaf_key(raw_key, path)
     if stack:
         k = jax.random.fold_in(k, layer)
-    return dense_value(k, path, shape, dtype).astype(jnp.float32)
+    return rule(k, shape, path).astype(dtype).astype(jnp.float32)
 
 
 _ref_dense_jitted = jax.jit(_ref_dense_impl, static_argnames=(
-    "path", "shape", "dtype", "stack"))
+    "path", "shape", "dtype", "stack", "rule"))
 
 
-def reference_leaf(seed: int, path: str, layer: int, shape, dtype
-                   ) -> jax.Array:
-    """A non-ternary leaf in f32 (its values are exact in ``dtype``)."""
-    return _ref_dense_jitted(seed_key(seed), max(layer, 0), path=path,
-                             shape=tuple(shape), dtype=jnp.dtype(dtype),
-                             stack=layer >= 0)
+@dataclasses.dataclass(frozen=True)
+class Seeded:
+    """The weights one seed draws under one architecture's leaf rules
+    (``leaf_rules``), as the plain reference reads them: one matrix or
+    leaf, or one layer of a stacked one, at a time, in f32."""
+    seed: int
+    rules: Dict[str, Callable]
+
+    def matrix(self, path: str, layer: int, d_in: int, d_out: int,
+               group: int, index: int = -1) -> jax.Array:
+        """Ŵ (d_in, d_out) f32 of one ternary matrix (layer ``layer`` of a
+        stacked leaf, or -1 for an unstacked one; ``index``: its place,
+        row-major, in a stack of matrices such as a layer's experts, or
+        -1), drawn as the program's."""
+        return _ref_matrix_jitted(seed_key(self.seed), max(layer, 0),
+                                  max(index, 0), path=path, d_in=d_in,
+                                  d_out=d_out, group=group, stack=layer >= 0,
+                                  in_stack=index >= 0)
+
+    def leaf(self, path: str, layer: int, shape, dtype) -> jax.Array:
+        """A non-ternary leaf in f32 (its values are exact in ``dtype``)."""
+        return _ref_dense_jitted(seed_key(self.seed), max(layer, 0),
+                                 path=path, shape=tuple(shape),
+                                 dtype=jnp.dtype(dtype), stack=layer >= 0,
+                                 rule=_rule(path, self.rules))

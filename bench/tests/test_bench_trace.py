@@ -9,7 +9,7 @@ from pathlib import Path
 
 import pytest
 
-import bench_tiny_tree as tiny  # noqa: F401  (sys.path)
+import bench_tiny_tree as tiny
 
 from harness import trace
 
@@ -58,7 +58,13 @@ FILL = ("%broadcast.451.clone.2 = bf16[28,64,3072,2,128]{4,3,2,1,0:T(2,128)"
         "(2,1)} broadcast(bf16[]{:T(256)} %constant.284), dimensions={}")
 
 
+def _qwen2_kernels():
+    return trace.kernel_set([tiny.arch(tiny.config("qwen2-1.5b"))])
+
+
 def test_kernel_names():
+    assert trace.kernel_of(RING, trace.kernel_set([])) is None   # core only
+    assert trace.kernel_of(RING, _qwen2_kernels()) == "chunk_attention"
     assert trace.kernel_of("_ternary_kernel") == "ternary_matmul"
     assert trace.kernel_of(TERNARY) == "ternary_matmul"
     assert trace.kernel_of(RING) == "chunk_attention"
@@ -106,3 +112,42 @@ def test_recorded_tpu_trace():
     assert 0 < red.busy_s < red.window_s
     assert red.kernel_s["ternary_matmul"] > 0
     assert red.kernel_s["chunk_attention"] > 0
+
+
+def test_fixture_ops_keep_their_kernels():
+    """Every op of the recorded trace keeps the op key and kernel that the
+    harness of commit b567248 gave it, before the kernels moved into the
+    architecture modules: 42 op keys (sha256 of their sorted JSON list,
+    computed then), of which only the two kernels' own name a kernel."""
+    import hashlib
+    import json
+
+    from jax.profiler import ProfileData
+
+    kernels = _qwen2_kernels()
+    found = {}
+    for p in ProfileData.from_file(str(FIXTURE)).planes:
+        if p.name.startswith("/device:"):
+            for ln in p.lines:
+                if ln.name == trace.OPS_LINE:
+                    for ev in ln.events:
+                        found.setdefault(str(trace.op_key(ev.name, kernels)),
+                                         set()).add(
+                            trace.kernel_of(ev.name, kernels))
+    assert len(found) == 42
+    assert hashlib.sha256(json.dumps(sorted(found)).encode()).hexdigest() \
+        == "cd3748ae5c7cb5d3afb4574b5261fb64cc6cc8f9ea8ab7f27116436537b46015"
+    assert {k: v for k, v in found.items() if v != {None}} == {
+        "chunk_attention": {"chunk_attention"},
+        "ternary_matmul": {"ternary_matmul"}}
+    assert all(len(v) == 1 for v in found.values())
+
+
+@pytest.mark.parametrize("kernel", ["chunk_attention", "ternary_matmul"])
+def test_cell_kernel_set_reads_the_fixture(kernel):
+    """The chat cell's kernel set finds both kernels' time in the recorded
+    trace, as every architecture's does."""
+    ours = trace.reduce(FIXTURE, 0.0, 0.0, _qwen2_kernels())
+    every = trace.reduce(FIXTURE, 0.0, 0.0)
+    assert ours.kernel_s[kernel] == every.kernel_s[kernel] > 0
+    assert ours.ops == every.ops

@@ -4,7 +4,8 @@
 
 On one TPU chip: the program's ternary matmul (d 1536 -> n 8960 at m 64
 and m 2048, a qwen2-1.5b MLP projection) and its ring chunk attention
-(64 rows x 4096 slots, 2 KV heads of 128, chunks of 1 and 32), each
+(64 rows x 4096 slots, 2 KV heads of 128, chunks of 1 and 32), reading
+layer 1 of a two-layer stacked ring as the layer scan does, each
 compiled first and then run twice between the benchmark's two window
 marks. Writes ``<out>/tpu_v5e.xplane.pb`` (about 150 KB) and prints its
 reduction; copy the file to ``bench/tests/fixtures/``.
@@ -36,6 +37,7 @@ def main(argv=None) -> int:
 
     from repro.core.packing import pack_trits
     from repro.kernels.chunk_attention import chunk_attention
+    from repro.kernels.chunk_attention.ref import to_storage
     from repro.kernels.ternary_matmul.ops import ternary_matmul
 
     n, d = 8960, 1536
@@ -49,16 +51,22 @@ def main(argv=None) -> int:
         return ternary_matmul(x, t1p, t2p, alpha, group_size=128,
                               backend="pallas", out_dtype=x.dtype)
 
-    b, cap, kv, g, hd = 64, 4096, 2, 6, 128
-    kc = jnp.zeros((b, cap, kv, hd), jnp.bfloat16)
-    pos = jnp.tile(jnp.arange(cap, dtype=jnp.int32)[None], (b, 1))
+    n_layers, b, cap, kv, g, hd = 2, 64, 4096, 2, 6, 128
+    ring = jnp.zeros((n_layers, b, cap, kv, hd), jnp.bfloat16)
+    kc, _, vc, _, pos = to_storage(
+        ring, None, ring, None,
+        jnp.tile(jnp.arange(cap, dtype=jnp.int32)[None, None],
+                 (n_layers, b, 1)))
 
     @jax.jit
-    def att(q, kn):
+    def att(q, kn, layer):
         L = q.shape[1]
         positions = jnp.full((b, L), cap, jnp.int32) + jnp.arange(L)[None]
-        return chunk_attention(q, kn, kn, kc, None, kc, None, pos, positions,
-                               jnp.full((b,), L, jnp.int32), backend="pallas")
+        return chunk_attention(q, kn, kn, kc, None, vc, None, pos, positions,
+                               jnp.full((b,), L, jnp.int32), layer=layer,
+                               backend="pallas")
+
+    layer = jnp.int32(1)
 
     xs = [jnp.ones((64, d), jnp.bfloat16), jnp.ones((2048, d), jnp.bfloat16)]
     qs = [(jnp.ones((b, L, kv, g, hd), jnp.float32),
@@ -66,7 +74,7 @@ def main(argv=None) -> int:
     for x in xs:
         mm(x).block_until_ready()
     for q, kn in qs:
-        att(q, kn).block_until_ready()
+        att(q, kn, layer).block_until_ready()
 
     trace_dir = args.out / "fixture_trace"
     shutil.rmtree(trace_dir, ignore_errors=True)
@@ -79,7 +87,7 @@ def main(argv=None) -> int:
             mm(x).block_until_ready()
         time.sleep(0.003)
         for q, kn in qs:
-            att(q, kn).block_until_ready()
+            att(q, kn, layer).block_until_ready()
     with jax.profiler.TraceAnnotation(trace.MARK_CLOSE):
         pc_close = time.perf_counter()
     jax.profiler.stop_trace()
