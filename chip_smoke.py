@@ -17,7 +17,11 @@ weights made from ``--seed``:
 4. the Pallas kernels are compared with the XLA paths, run at HIGHEST
    matmul precision, on the chip: ``ternary_matmul`` pallas vs grouped on
    the artifact's packed planes, ``chunk_attention`` (ring and paged)
-   pallas vs stream, each under its own limit on the relative L2 error.
+   pallas vs stream, each under its own limit on the relative L2 error;
+   and at deepseek-v2-lite's published widths (64 experts of 1408 at d
+   2048, 16 heads over a 512 + 64-lane latent), ``expert_matmul`` pallas
+   vs its XLA path on a decode step's routed rows and ``latent_attention``
+   pallas vs its materialized XLA form.
 
 Each phase prints its wall seconds with compile seconds kept apart, and the
 run prints the resolved kernel backends, finish reasons, token counts,
@@ -54,7 +58,14 @@ SERVE = dict(requests=8, max_new=32, slots=8, capacity=2048, prefill_chunk=16)
 # 1.72e-3 and attention 2.26e-3 to 3.29e-3 (PERF.md); each limit sits
 # more than twice above its highest reading, where a wrong group, trit
 # field, tile or mask reads O(1).
-PARITY_LIMITS = {"ternary_matmul": 5e-3, "chunk_attention": 7.5e-3}
+PARITY_LIMITS = {"ternary_matmul": 5e-3, "chunk_attention": 7.5e-3,
+                 # their siblings' numerics (bf16 Ŵ to the MXU; f32 dots at
+                 # the MXU's default precision), so their siblings' limits
+                 "expert_matmul": 5e-3, "latent_attention": 7.5e-3}
+# deepseek-v2-lite: experts, top-k, (n, d) of gate/up and down; latent heads,
+# latent and rope lanes (stored padded to 640)
+EXPERTS, TOP_K, EXPERT_SHAPES = 64, 6, ((1408, 2048), (2048, 1408))
+MLA_HEADS, LATENT, ROPE, LATENT_LANES = 16, 512, 64, 640
 # chunk lengths of the attention parity: decode, the served prefill chunk,
 # and a chunk whose default tile (32) the compiled kernel rounds up to 128
 PARITY_CHUNKS = (1, SERVE["prefill_chunk"], 256)
@@ -284,12 +295,74 @@ def parity_phase(params, seed: int):
                      table, positions, lengths)
             errs[("chunk_attention", f"paged {tag}")] = versus_xla(
                 paged_op, "stream", *pargs)
+    errs.update(_deepseek_parity(rng, versus_xla))
     for (op, case), v in errs.items():
         log(f"parity {op} {case}: ||pallas-xla||/||xla|| = {v!r} "
             f"(limit {PARITY_LIMITS[op]:g})")
     bad = [k for k, v in errs.items() if not v <= PARITY_LIMITS[k[0]]]
     if bad:
         raise RuntimeError(f"parity beyond its limit: {bad}")
+
+
+def _deepseek_parity(rng, versus_xla):
+    """The grouped expert kernel and latent attention against their XLA
+    paths at deepseek-v2-lite's widths, on random inputs."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.packing import pack_trits
+    from repro.kernels.chunk_attention import latent_attention
+    from repro.kernels.expert_matmul import TILE_ROWS, expert_matmul
+
+    errs = {}
+    # a decode step of the served slots: 6 experts per token, sorted by
+    # expert into tiles of TILE_ROWS, two dead tiles after the live ones
+    experts = rng.integers(0, EXPERTS, SERVE["slots"] * TOP_K)
+    counts = np.bincount(experts, minlength=EXPERTS)
+    tiles = -(-counts // TILE_ROWS)
+    live = int(tiles.sum())
+    te = np.repeat(np.arange(EXPERTS), tiles)
+    te = jnp.asarray(np.concatenate([te, [te[-1]] * 2]), jnp.int32)
+    first = (np.cumsum(tiles) - tiles) * TILE_ROWS
+    rows = np.concatenate([first[e] + np.arange(c)
+                           for e, c in enumerate(counts) if c])
+    for n, d in EXPERT_SHAPES:
+        planes = [jnp.stack([pack_trits(jnp.asarray(
+            rng.integers(-1, 2, (n, d)), jnp.int8)) for _ in range(EXPERTS)])
+            for _ in range(2)]
+        alpha = jnp.asarray(rng.uniform(0.01, 0.03, (EXPERTS, n, d // 128, 2)),
+                            jnp.float32)
+        x = np.zeros(((live + 2) * TILE_ROWS, d), np.float32)
+        x[rows] = rng.standard_normal((len(rows), d))
+        x = jnp.asarray(x, jnp.bfloat16)
+
+        def op(x, backend):
+            y = expert_matmul(x, *planes, alpha, te, jnp.asarray([live]),
+                              backend=backend)
+            return y[:live * TILE_ROWS]                 # live tiles only
+        errs[("expert_matmul", f"{n}x{d} rows={len(rows)}")] = versus_xla(
+            op, "xla", x)
+
+    b, cap = SERVE["slots"], SERVE["capacity"]
+    for L in PARITY_CHUNKS:
+        pad = LATENT_LANES - LATENT - ROPE
+        lanes = lambda *s: jnp.pad(jnp.asarray(rng.standard_normal(
+            s + (LATENT + ROPE,)), jnp.float32), [(0, 0)] * len(s)
+            + [(0, pad)])
+        ring = jnp.stack([-lanes(b, cap), lanes(b, cap)]).astype(jnp.bfloat16)
+        pos0 = rng.integers(cap // 2, 2 * cap, (b,))
+        pb = np.full((b, cap), -1, np.int32)
+        for r, p in enumerate(pos0):
+            written = np.arange(max(0, p - cap), p)
+            pb[r, written % cap] = written
+        args = (lanes(b, L, MLA_HEADS), lanes(b, L).astype(jnp.bfloat16),
+                ring, jnp.asarray(np.stack([pb, pb])),
+                jnp.asarray(pos0[:, None] + np.arange(L), jnp.int32),
+                jnp.full((b,), L, jnp.int32))
+        errs[("latent_attention", f"L={L}")] = versus_xla(
+            functools.partial(latent_attention, scale=0.11472, v_dim=LATENT,
+                              layer=1), "xla", *args)
+    return errs
 
 
 def smoke(seed: int) -> None:

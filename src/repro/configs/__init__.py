@@ -2,6 +2,7 @@
 
 from repro.configs import (
     deepseek_moe_16b,
+    deepseek_v2_lite,
     gemma3_27b,
     grok1_314b,
     llama3_405b,
@@ -25,6 +26,7 @@ _MODULES = {
     "grok-1-314b": grok1_314b,
     "deepseek-moe-16b": deepseek_moe_16b,
     "recurrentgemma-2b": recurrentgemma_2b,
+    "deepseek-v2-lite": deepseek_v2_lite,
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -7,6 +7,8 @@ Block kinds compose a token mixer and a channel mixer:
   "attn+mlp"   full-causal GQA + FFN            (llama/qwen/musicgen/phi)
   "local+mlp"  sliding-window GQA + FFN         (gemma3 local, recurrentgemma)
   "attn+moe"   full-causal GQA + MoE FFN        (grok, deepseek)
+  "mla+mlp"    latent attention (MLA) + FFN      (deepseek-v2 dense layers)
+  "mla+moe"    latent attention (MLA) + MoE FFN  (deepseek-v2)
   "rwkv"       RWKV6 time-mix + channel-mix
   "rglru+mlp"  RG-LRU recurrent block + FFN
 """
@@ -16,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+from repro.models.common import YarnRope
 from repro.models.moe import MoEConfig
 
 SHAPES = {
@@ -46,6 +49,14 @@ class ModelConfig:
     norm_eps: float = 1e-5
     embed_inputs: bool = True          # False: stub frontend feeds embeddings
     moe: Optional[MoEConfig] = None
+    # latent attention ("mla+*" blocks, DeepSeek-V2): a kv_lora_rank-wide
+    # latent plus one shared rope key per token is all the cache holds
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_yarn: Optional[YarnRope] = None
+    rope_interleave: bool = False      # rotation pairs (2i, 2i+1)
     # rwkv / rglru
     rwkv_head_dim: int = 64
     rglru_width: Optional[int] = None
@@ -104,6 +115,11 @@ class ModelConfig:
         d, hd = self.d_model, self.head_dim
         attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd \
             + self.n_heads * hd * d
+        if self.kv_lora_rank:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            nope, v, h = self.qk_nope_head_dim, self.v_head_dim, self.n_heads
+            mla = (d * h * (nope + rope) + d * (r + rope)
+                   + r * h * (nope + v) + h * v * d)
         mlps = {"swiglu": 3, "geglu": 3, "gelu": 2}[self.mlp_type]
         mlp = mlps * d * self.d_ff
         rwkv = 5 * d * d + d * self.d_ff * 2 + d * d  # time (5 proj) + channel
@@ -120,8 +136,9 @@ class ModelConfig:
                 total += rglru + mlp
                 active += rglru + mlp
             else:
-                total += attn
-                active += attn
+                a = mla if kind.startswith("mla") else attn
+                total += a
+                active += a
                 if kind.endswith("moe"):
                     m = self.moe
                     e_p = 3 * d * m.d_expert

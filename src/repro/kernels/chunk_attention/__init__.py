@@ -106,6 +106,13 @@ page index per tile — pages are just non-contiguous tiles), and with
 matching ``tile`` each backend is bit-identical to its contiguous-ring
 counterpart (``materialized`` is gather-then-oracle, bit-identical by
 construction). ``ops.paged_tile`` is the paged tile selector.
+
+Latent variant (``latent.latent_attention``)
+--------------------------------------------
+Multi-head latent attention (DeepSeek-V2): H absorbed query heads against
+one cached latent per token, whose leading lanes are also the value. Same
+masking rule and stacked-ring layer index, its own kernel
+(``latent.py``).
 """
 
 from repro.kernels.chunk_attention.ops import (
@@ -118,9 +125,11 @@ from repro.kernels.chunk_attention.ops import (
     tracked_block_bytes,
 )
 from repro.kernels.chunk_attention.ref import gather_pages
+from repro.kernels.chunk_attention.latent import latent_attention
 
 __all__ = [
     "chunk_attention", "chunk_attention_paged", "gather_pages", "paged_tile",
+    "latent_attention",
     "resolve_chunk_backend", "tracked_block_bytes",
     "peak_tracked_bytes", "reset_tracking",
 ]

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import mla as mla_mod
 from repro.models import mlp as mlp_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -62,7 +63,8 @@ def _block_init(kind: str, cfg, key, dtype) -> Dict[str, Any]:
     mixer, ffn = kind.split("+")
     p = {
         "attn_norm": norm_init(d, dtype),
-        "attn": attn.attn_init(ks[0], cfg, dtype),
+        "attn": (mla_mod.mla_init(ks[0], cfg, dtype) if mixer == "mla"
+                 else attn.attn_init(ks[0], cfg, dtype)),
         "mlp_norm": norm_init(d, dtype),
     }
     if ffn == "moe":
@@ -95,9 +97,14 @@ def _block_forward(kind: str, p, cfg, x, positions):
                                 cfg.mlp_type)
         return x + y
     # attention blocks
-    y = attn.attention_forward(
-        p["attn"], cfg, rms_norm(p["attn_norm"], x, cfg.norm_eps), positions,
-        window=_mixer_window(kind, cfg), q_chunk=cfg.q_chunk)
+    h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
+    if kind.startswith("mla"):
+        y = mla_mod.mla_forward(p["attn"], cfg, h, positions,
+                                q_chunk=cfg.q_chunk)
+    else:
+        y = attn.attention_forward(p["attn"], cfg, h, positions,
+                                   window=_mixer_window(kind, cfg),
+                                   q_chunk=cfg.q_chunk)
     x = x + y
     h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
     if "moe" in p:
@@ -123,14 +130,32 @@ def _block_cache_init(kind: str, cfg, batch: int, capacity: int, dtype,
             "h": jnp.zeros((batch, r), jnp.float32),
             "conv": jnp.zeros((batch, cfg.conv_width - 1, r), dtype),
         }
+    if kind.startswith("mla"):
+        if kv_spec is not None:
+            raise ValueError("the latent (MLA) cache has no paged layout")
+        return mla_mod.latent_cache_init(cfg, batch, capacity, dtype)
     # recurrent states above are per-row and tiny — kv_spec (the paged KV
     # layout) applies to the attention ring caches only
     return attn.cache_init(cfg, batch, capacity, _mixer_window(kind, cfg),
                            dtype, kv_spec=kv_spec)
 
 
+def _ffn_serve(p, cfg, h, valid=None):
+    """A block's FFN at serving time: (y, MoE stats or None). Experts
+    route without capacity (``moe.moe_serve``); ``valid`` (h's leading
+    shape) marks the tokens routed at all."""
+    if "moe" in p:
+        d = h.shape[-1]
+        y, stats = moe_mod.moe_serve(
+            p["moe"], h.reshape(-1, d), cfg.moe, cfg.mlp_type,
+            None if valid is None else valid.reshape(-1))
+        return y.reshape(h.shape), stats
+    return mlp_mod.mlp_forward(p["mlp"], h, cfg.mlp_type), None
+
+
 def _block_prefill(kind: str, p, cfg, x, positions, cache, layer=None):
-    """Full-seq forward that also fills the decode cache."""
+    """Full-seq forward that also fills the decode cache: (x, cache,
+    MoE stats or None)."""
     if kind == "rwkv":
         h = rms_norm(p["time_norm"], x, cfg.norm_eps)
         y, (x_last, wkv) = rwkv_mod.rwkv_time_forward(p["time"], h,
@@ -138,7 +163,7 @@ def _block_prefill(kind: str, p, cfg, x, positions, cache, layer=None):
         x = x + y
         h = rms_norm(p["chan_norm"], x, cfg.norm_eps)
         y, xc_last = rwkv_mod.rwkv_channel_forward(p["chan"], h)
-        return x + y, {"x_time": x_last, "wkv": wkv, "x_chan": xc_last}
+        return x + y, {"x_time": x_last, "wkv": wkv, "x_chan": xc_last}, None
     if kind.startswith("rglru"):
         h = rms_norm(p["rec_norm"], x, cfg.norm_eps)
         y, (h_last, conv_state) = rglru_mod.rglru_forward(
@@ -147,23 +172,24 @@ def _block_prefill(kind: str, p, cfg, x, positions, cache, layer=None):
         y = mlp_mod.mlp_forward(p["mlp"],
                                 rms_norm(p["mlp_norm"], x, cfg.norm_eps),
                                 cfg.mlp_type)
-        return x + y, {"h": h_last, "conv": conv_state}
+        return x + y, {"h": h_last, "conv": conv_state}, None
     # attention blocks
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
-    window = _mixer_window(kind, cfg)
-    y, (k, v) = attn.attention_forward(
-        p["attn"], cfg, h, positions, window=window, q_chunk=cfg.q_chunk,
-        return_kv=True)
     b, s, _ = x.shape
     pos_bs = jnp.broadcast_to(positions[None, :], (b, s))
-    cache = attn.cache_prefill(cfg, cache, k, v, pos_bs, layer=layer)
-    x = x + y
-    h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
-    if "moe" in p:
-        y = moe_mod.moe_forward(p["moe"], h, cfg.moe, cfg.mlp_type)
+    if kind.startswith("mla"):
+        y, cache = mla_mod.mla_chunk(p["attn"], cfg, cache, h, pos_bs,
+                                     jnp.full((b,), s, jnp.int32),
+                                     layer=layer)
     else:
-        y = mlp_mod.mlp_forward(p["mlp"], h, cfg.mlp_type)
-    return x + y, cache
+        window = _mixer_window(kind, cfg)
+        y, (k, v) = attn.attention_forward(
+            p["attn"], cfg, h, positions, window=window, q_chunk=cfg.q_chunk,
+            return_kv=True)
+        cache = attn.cache_prefill(cfg, cache, k, v, pos_bs, layer=layer)
+    x = x + y
+    y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x, cfg.norm_eps))
+    return x + y, cache, stats
 
 
 def _freeze_rows(active, new, old):
@@ -177,6 +203,7 @@ def _freeze_rows(active, new, old):
 
 def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None,
                   layer=None):
+    """One decode token per row: (x_t, cache, MoE stats or None)."""
     if kind == "rwkv":
         h = rms_norm(p["time_norm"], x_t, cfg.norm_eps)
         y, (x_last, wkv) = rwkv_mod.rwkv_time_decode(
@@ -185,7 +212,7 @@ def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None,
         h = rms_norm(p["chan_norm"], x_t, cfg.norm_eps)
         y, xc_last = rwkv_mod.rwkv_channel_decode(p["chan"], h, cache["x_chan"])
         new = {"x_time": x_last, "wkv": wkv, "x_chan": xc_last}
-        return x_t + y, _freeze_rows(active, new, cache)
+        return x_t + y, _freeze_rows(active, new, cache), None
     if kind.startswith("rglru"):
         h = rms_norm(p["rec_norm"], x_t, cfg.norm_eps)
         y, (h_last, conv_state) = rglru_mod.rglru_decode(
@@ -196,18 +223,19 @@ def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None,
                                 rms_norm(p["mlp_norm"], x_t, cfg.norm_eps),
                                 cfg.mlp_type)
         new = {"h": h_last, "conv": conv_state}
-        return x_t + y, _freeze_rows(active, new, cache)
+        return x_t + y, _freeze_rows(active, new, cache), None
     h = rms_norm(p["attn_norm"], x_t, cfg.norm_eps)
-    y, cache = attn.attention_decode(p["attn"], cfg, cache, h, pos,
-                                     window=_mixer_window(kind, cfg),
-                                     active=active, layer=layer)
-    x_t = x_t + y
-    h = rms_norm(p["mlp_norm"], x_t, cfg.norm_eps)
-    if "moe" in p:
-        y = moe_mod.moe_forward(p["moe"], h[:, None], cfg.moe, cfg.mlp_type)[:, 0]
+    if kind.startswith("mla"):
+        y, cache = mla_mod.mla_decode(p["attn"], cfg, cache, h, pos,
+                                      active=active, layer=layer)
     else:
-        y = mlp_mod.mlp_forward(p["mlp"], h, cfg.mlp_type)
-    return x_t + y, cache
+        y, cache = attn.attention_decode(p["attn"], cfg, cache, h, pos,
+                                         window=_mixer_window(kind, cfg),
+                                         active=active, layer=layer)
+    x_t = x_t + y
+    y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x_t, cfg.norm_eps),
+                          active)
+    return x_t + y, cache, stats
 
 
 def _recurrent(kind: str) -> bool:
@@ -217,52 +245,65 @@ def _recurrent(kind: str) -> bool:
     return kind == "rwkv" or kind.startswith("rglru")
 
 
+def has_moe(cfg) -> bool:
+    """Whether any layer routes to experts (serving then counts them)."""
+    return any(k.endswith("moe") for k in cfg.layer_kinds)
+
+
+def _add_stats(acc, stats):
+    return acc if stats is None else acc + stats
+
+
 def _run_blocks(cfg, blocks, caches, x, block_fn, *, remat=False):
     """Run the stacked period blocks over ``x``.
 
-    ``block_fn(kind, p, cache, x, layer) -> (x, cache)`` runs one block.
-    Recurrent caches are sliced per layer and restacked (``xs → ys``):
-    their step rewrites them whole. Ring caches ride in the scan's carry
-    as the whole stack and are passed on with the layer index: the kernel
-    reads the layer in place and the block writes only the slots its
-    tokens land in, so no ring-sized slice, fill or copy exists (a ring
-    in ``xs → ys`` would be copied into a fresh stack on every call).
-    Returns (x, caches).
+    ``block_fn(kind, p, cache, x, layer) -> (x, cache, stats)`` runs one
+    block (stats: its MoE counts, or None). Recurrent caches are sliced
+    per layer and restacked (``xs → ys``): their step rewrites them whole.
+    Ring caches ride in the scan's carry as the whole stack and are passed
+    on with the layer index: the kernel reads the layer in place and the
+    block writes only the slots its tokens land in, so no ring-sized
+    slice, fill or copy exists (a ring in ``xs → ys`` would be copied into
+    a fresh stack on every call). Returns (x, caches, summed MoE stats or
+    None).
     """
     names = [f"b{i}" for i in range(len(cfg.block_pattern))]
     kinds = dict(zip(names, cfg.block_pattern))
     ring = {n: caches[n] for n in names if not _recurrent(kinds[n])}
     rec = {n: caches[n] for n in names if _recurrent(kinds[n])}
+    acc = (jnp.zeros((2,), jnp.int32)
+           if any(k.endswith("moe") for k in cfg.block_pattern) else None)
 
     def period_fn(carry, xs):
-        x, ring = carry
+        x, ring, acc = carry
         period_params, rec_p, layer = xs
         ring, new_rec = dict(ring), {}
         for n in names:
             if n in ring:
-                x, ring[n] = block_fn(kinds[n], period_params[n], ring[n], x,
-                                      layer)
+                x, ring[n], st = block_fn(kinds[n], period_params[n],
+                                          ring[n], x, layer)
             else:
-                x, new_rec[n] = block_fn(kinds[n], period_params[n],
-                                         rec_p[n], x, None)
-        return (constrain(x, "hidden"), ring), new_rec
+                x, new_rec[n], st = block_fn(kinds[n], period_params[n],
+                                             rec_p[n], x, None)
+            acc = _add_stats(acc, st)
+        return (constrain(x, "hidden"), ring, acc), new_rec
 
     if remat:
         period_fn = jax.checkpoint(period_fn)
     if cfg.scan_layers:
-        (x, ring), rec = jax.lax.scan(
-            period_fn, (x, ring),
+        (x, ring, acc), rec = jax.lax.scan(
+            period_fn, (x, ring, acc),
             (blocks, rec, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
     else:  # unrolled (exact per-layer HLO costs; dry-run cost variants)
         outs = []
         for i in range(cfg.n_periods):
             sl = lambda a: a[i]
-            (x, ring), nc = period_fn(
-                (x, ring), (jax.tree.map(sl, blocks), jax.tree.map(sl, rec),
-                            i))
+            (x, ring, acc), nc = period_fn(
+                (x, ring, acc), (jax.tree.map(sl, blocks),
+                                 jax.tree.map(sl, rec), i))
             outs.append(nc)
         rec = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    return x, {**ring, **rec}
+    return x, {**ring, **rec}, acc
 
 
 # ---------------------------------------------------------------------------
@@ -391,30 +432,41 @@ def init_decode_state(cfg, batch: int, capacity: int, *,
     }
 
 
+def _serve_layers(params, cfg, caches, x, block_fn, *, remat=False):
+    """Prefix blocks, the scanned period and the remainder over ``x``
+    with their caches (``caches["prefix" | "blocks" | "suffix"]``).
+    Returns (x, {"prefix", "blocks", "suffix"} caches, summed MoE stats
+    (2,) int32 — assignments routed, expert tile rows — or None for a
+    model without experts)."""
+    out = {"prefix": {}, "blocks": None, "suffix": {}}
+    stats = jnp.zeros((2,), jnp.int32) if has_moe(cfg) else None
+    for part, short, kinds in (("prefix", "p", cfg.prefix_pattern),
+                               ("suffix", "s", cfg.remainder_pattern)):
+        if part == "suffix" and cfg.n_periods:
+            x, out["blocks"], st = _run_blocks(
+                cfg, params["blocks"], caches["blocks"], x, block_fn,
+                remat=remat)
+            stats = _add_stats(stats, st)
+        for i, kind in enumerate(kinds):
+            x, out[part][f"{short}{i}"], st = block_fn(
+                kind, params[part][f"{short}{i}"],
+                caches[part][f"{short}{i}"], x, None)
+            stats = _add_stats(stats, st)
+    return x, out, stats
+
+
 def prefill(params, cfg, batch, capacity: int) -> Tuple[jax.Array, Dict]:
     """Process a full prompt; return (last-position logits, decode state)."""
     x = _embed(params, cfg, batch)
     b, s, _ = x.shape
     positions = jnp.arange(s)
-    adt = dtype_of(cfg.activation_dtype)
     state = init_decode_state(cfg, b, capacity)
-
-    for i, kind in enumerate(cfg.prefix_pattern):
-        x, state["prefix"][f"p{i}"] = _block_prefill(
-            kind, params["prefix"][f"p{i}"], cfg, x, positions,
-            state["prefix"][f"p{i}"])
-
-    if cfg.n_periods:
-        x, state["blocks"] = _run_blocks(
-            cfg, params["blocks"], state["blocks"], x,
-            lambda kind, p, cache, x, layer: _block_prefill(
-                kind, p, cfg, x, positions, cache, layer),
-            remat=cfg.remat == "full")
-
-    for i, kind in enumerate(cfg.remainder_pattern):
-        x, state["suffix"][f"s{i}"] = _block_prefill(
-            kind, params["suffix"][f"s{i}"], cfg, x, positions,
-            state["suffix"][f"s{i}"])
+    x, caches, _ = _serve_layers(
+        params, cfg, state, x,
+        lambda kind, p, cache, x, layer: _block_prefill(
+            kind, p, cfg, x, positions, cache, layer),
+        remat=cfg.remat == "full")
+    state.update(caches)
 
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = dense(params["lm_head"], x[:, -1])
@@ -428,6 +480,7 @@ def _block_prefill_chunk(kind: str, p, cfg, x, positions, lengths, valid,
 
     x: (B, L, D) right-padded; positions: (B, L) absolute per row;
     lengths: (B,) valid counts (0 = no-op row); valid: (B, L) bool.
+    Returns (x, cache, MoE stats or None).
     """
     if kind == "rwkv":
         h = rms_norm(p["time_norm"], x, cfg.norm_eps)
@@ -438,7 +491,8 @@ def _block_prefill_chunk(kind: str, p, cfg, x, positions, lengths, valid,
         h = rms_norm(p["chan_norm"], x, cfg.norm_eps)
         y, xc_last = rwkv_mod.rwkv_channel_forward(
             p["chan"], h, state=cache["x_chan"], mask=valid)
-        return x + y, {"x_time": x_last, "wkv": wkv, "x_chan": xc_last}
+        return (x + y, {"x_time": x_last, "wkv": wkv, "x_chan": xc_last},
+                None)
     if kind.startswith("rglru"):
         h = rms_norm(p["rec_norm"], x, cfg.norm_eps)
         y, (h_last, conv_state) = rglru_mod.rglru_forward(
@@ -448,20 +502,20 @@ def _block_prefill_chunk(kind: str, p, cfg, x, positions, lengths, valid,
         y = mlp_mod.mlp_forward(p["mlp"],
                                 rms_norm(p["mlp_norm"], x, cfg.norm_eps),
                                 cfg.mlp_type)
-        return x + y, {"h": h_last, "conv": conv_state}
+        return x + y, {"h": h_last, "conv": conv_state}, None
     # attention blocks
     h = rms_norm(p["attn_norm"], x, cfg.norm_eps)
-    y, cache = attn.attention_prefill_chunk(
-        p["attn"], cfg, cache, h, positions, lengths,
-        window=_mixer_window(kind, cfg), layer=layer)
-    x = x + y
-    h = rms_norm(p["mlp_norm"], x, cfg.norm_eps)
-    if "moe" in p:
-        y = moe_mod.moe_forward(p["moe"], h, cfg.moe, cfg.mlp_type,
-                                valid=valid)
+    if kind.startswith("mla"):
+        y, cache = mla_mod.mla_chunk(p["attn"], cfg, cache, h, positions,
+                                     lengths, layer=layer)
     else:
-        y = mlp_mod.mlp_forward(p["mlp"], h, cfg.mlp_type)
-    return x + y, cache
+        y, cache = attn.attention_prefill_chunk(
+            p["attn"], cfg, cache, h, positions, lengths,
+            window=_mixer_window(kind, cfg), layer=layer)
+    x = x + y
+    y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x, cfg.norm_eps),
+                          valid)
+    return x + y, cache, stats
 
 
 def prefill_chunk(params, cfg, state, batch, lengths) -> Tuple[jax.Array, Dict]:
@@ -480,31 +534,23 @@ def prefill_chunk(params, cfg, state, batch, lengths) -> Tuple[jax.Array, Dict]:
 
     Returns (logits at each row's last valid token (B, V), updated state).
     Logits of rows with lengths[r] == 0 are garbage — callers ignore them.
+    A model with experts adds ``moe_stats`` to the state it returns: this
+    call's (assignments routed, expert tile rows), int32 (2,); it is not
+    part of the decode state, and a caller drops it before the next call.
     """
     x = _embed(params, cfg, batch)
     b, L, _ = x.shape
     pos0 = state["pos"]
     positions = pos0[:, None] + jnp.arange(L)[None, :]
     valid = jnp.arange(L)[None, :] < lengths[:, None]
-    new_state = {"pos": pos0 + lengths.astype(jnp.int32),
-                 "prefix": {}, "blocks": None, "suffix": {}}
-
-    for i, kind in enumerate(cfg.prefix_pattern):
-        x, new_state["prefix"][f"p{i}"] = _block_prefill_chunk(
-            kind, params["prefix"][f"p{i}"], cfg, x, positions, lengths,
-            valid, state["prefix"][f"p{i}"])
-
-    if cfg.n_periods:
-        x, new_state["blocks"] = _run_blocks(
-            cfg, params["blocks"], state["blocks"], x,
-            lambda kind, p, cache, x, layer: _block_prefill_chunk(
-                kind, p, cfg, x, positions, lengths, valid, cache, layer),
-            remat=cfg.remat == "full")
-
-    for i, kind in enumerate(cfg.remainder_pattern):
-        x, new_state["suffix"][f"s{i}"] = _block_prefill_chunk(
-            kind, params["suffix"][f"s{i}"], cfg, x, positions, lengths,
-            valid, state["suffix"][f"s{i}"])
+    x, new_state, stats = _serve_layers(
+        params, cfg, state, x,
+        lambda kind, p, cache, x, layer: _block_prefill_chunk(
+            kind, p, cfg, x, positions, lengths, valid, cache, layer),
+        remat=cfg.remat == "full")
+    new_state["pos"] = pos0 + lengths.astype(jnp.int32)
+    if stats is not None:
+        new_state["moe_stats"] = stats
 
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     idx = jnp.maximum(lengths - 1, 0).astype(jnp.int32)
@@ -519,6 +565,8 @@ def decode_step(params, cfg, state, tokens, active=None) -> Tuple[jax.Array, Dic
     active (B,) bool: rows with active=False are frozen — position and every
     cache leaf pass through unchanged, so a decode dispatch can share the
     batch state with rows that are mid-(chunked-)prefill or already free.
+    Rows with active=False are not routed to experts; ``moe_stats`` as in
+    ``prefill_chunk``.
     """
     adt = dtype_of(cfg.activation_dtype)
     if cfg.embed_inputs:
@@ -528,23 +576,13 @@ def decode_step(params, cfg, state, tokens, active=None) -> Tuple[jax.Array, Dic
     x = x.astype(adt)
     pos = state["pos"]
     new_pos = pos + (active.astype(jnp.int32) if active is not None else 1)
-    new_state = {"pos": new_pos, "prefix": {}, "blocks": None, "suffix": {}}
-
-    for i, kind in enumerate(cfg.prefix_pattern):
-        x, new_state["prefix"][f"p{i}"] = _block_decode(
-            kind, params["prefix"][f"p{i}"], cfg, state["prefix"][f"p{i}"],
-            x, pos, active)
-
-    if cfg.n_periods:
-        x, new_state["blocks"] = _run_blocks(
-            cfg, params["blocks"], state["blocks"], x,
-            lambda kind, p, cache, x, layer: _block_decode(
-                kind, p, cfg, cache, x, pos, active, layer))
-
-    for i, kind in enumerate(cfg.remainder_pattern):
-        x, new_state["suffix"][f"s{i}"] = _block_decode(
-            kind, params["suffix"][f"s{i}"], cfg, state["suffix"][f"s{i}"],
-            x, pos, active)
+    x, new_state, stats = _serve_layers(
+        params, cfg, state, x,
+        lambda kind, p, cache, x, layer: _block_decode(
+            kind, p, cfg, cache, x, pos, active, layer))
+    new_state["pos"] = new_pos
+    if stats is not None:
+        new_state["moe_stats"] = stats
 
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = dense(params["lm_head"], x)

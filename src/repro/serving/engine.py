@@ -67,6 +67,7 @@ from repro.models import (decode_step, init_decode_state, prefill,
                           prefill_chunk)
 from repro.models.attention import row_axis
 from repro.models.common import matmul_backend
+from repro.models.transformer import has_moe
 from repro.runtime import clock as rtclock
 from repro.runtime.monitor import HealthSnapshot
 from repro.serving.api import (FINISH_CANCELLED, FINISH_ERROR, FINISH_LENGTH,
@@ -347,17 +348,22 @@ def _decode_loop(params, state, tokens, temps, active, seeds, gen_idx,
         gen counter equals ``poison[b]`` its logits are overwritten with
         NaN *on device*, exercising the real non-finite containment path.
     Returns:
-      (new_state, (toks, bad)): toks (n_steps, B) — the sampled token per
-      step; bad (n_steps, B) bool — True where the row's logits for that
-      step were non-finite (the host retires such rows with reason
+      (new_state, (toks, bad, moe)): toks (n_steps, B) — the sampled token
+      per step; bad (n_steps, B) bool — True where the row's logits for
+      that step were non-finite (the host retires such rows with reason
       ``"error"`` and discards the garbage token). The reduction is a
       per-row ``isfinite`` all — numerics of surviving rows are untouched,
-      so adding the health output preserves bit-identity.
+      so adding the health output preserves bit-identity. moe: the steps'
+      summed MoE counts (``decode_step``'s ``moe_stats``), None for a
+      model without experts.
     """
 
     def body(carry, _):
-        state, tok, active, gen = carry
+        state, tok, active, gen, moe = carry
         logits, state = decode_step(params, cfg, state, tok, active)
+        step_moe = state.pop("moe_stats", None)
+        if step_moe is not None:
+            moe = moe + step_moe
         if use_poison:
             logits = jnp.where((gen == poison)[:, None] & active[:, None],
                                jnp.nan, logits)
@@ -375,14 +381,15 @@ def _decode_loop(params, state, tokens, temps, active, seeds, gen_idx,
         # here on and the host is about to retire it anyway
         active = jnp.logical_and(active,
                                  jnp.logical_not(jnp.logical_or(hit, bad)))
-        return (state, nxt, active, gen), (nxt, bad)
+        return (state, nxt, active, gen, moe), (nxt, bad)
 
+    moe0 = jnp.zeros((2,), jnp.int32) if has_moe(cfg) else None
     # Full unroll: the scan body is op-overhead-bound at decode shapes, and
     # unrolling lets XLA fuse across steps (measured ~40% per-token on CPU).
-    (state, _, _, _), (toks, bad) = jax.lax.scan(
-        body, (state, tokens, active, gen_idx), None, length=n_steps,
+    (state, _, _, _, moe), (toks, bad) = jax.lax.scan(
+        body, (state, tokens, active, gen_idx, moe0), None, length=n_steps,
         unroll=min(n_steps, 16))
-    return state, (toks, bad)
+    return state, (toks, bad, moe)
 
 
 class ServingEngine:
@@ -417,6 +424,13 @@ class ServingEngine:
         self.slots: List[Optional[RequestHandle]] = [None] * engine_cfg.max_slots
         # ---- paged KV layout (see _plan_pages for the admission story)
         self.paged = engine_cfg.kv_layout == "paged"
+        kinds = tuple(model_cfg.layer_kinds)
+        if self.paged and any(k.startswith("mla") for k in kinds):
+            # pages, and the prefix reuse that splices them, exist for k/v
+            # rings only; a latent ring would be served wrong, not slower
+            raise ValueError(
+                "kv_layout='paged' and prefix reuse have no latent (MLA) "
+                "cache layout: serve this model with kv_layout='ring'")
         kv_spec = None
         if self.paged:
             ps = engine_cfg.page_size
@@ -424,9 +438,6 @@ class ServingEngine:
             total = engine_cfg.max_pages
             if total is None:
                 total = engine_cfg.max_slots * self._per_slot
-            kinds = (tuple(model_cfg.prefix_pattern)
-                     + tuple(model_cfg.block_pattern)
-                     + tuple(model_cfg.remainder_pattern))
             # prefix reuse splices cached KV pages under a later request —
             # sound only when attention is the *only* stateful mixer (a
             # recurrent rwkv/rglru state summarizes every prior token and
@@ -494,6 +505,12 @@ class ServingEngine:
         self.submitted = 0           # submit() calls accepted
         self.tokens_generated = 0    # tokens delivered to outputs
         self.prefill_tokens = 0      # prompt tokens consumed by prefill
+        # routed experts: assignments computed, expert tile rows run (read
+        # from each dispatch at the step's next sync)
+        self.moe = has_moe(model_cfg)
+        self.moe_routed_rows = 0
+        self.moe_tile_rows = 0
+        self._moe_pending: List[Tuple[Any, Dict[str, Any]]] = []
         self.obs = observability if observability is not None \
             else Observability()
         # the engine's clock (a VirtualClock under an injector) owns every
@@ -784,7 +801,7 @@ class ServingEngine:
     def _warm_prefill(self):
         nb = len(self.slots)
         for length in self._bucket_lengths(self.ecfg.prefill_chunk):
-            _, self.state = self._prefill_fn(length)(
+            _, self.state, _ = self._prefill_fn(length)(
                 self._serve_params, self.state,
                 jnp.zeros((nb, length), jnp.int32),
                 jnp.zeros((nb,), jnp.int32))
@@ -859,7 +876,9 @@ class ServingEngine:
         return out
 
     def _kv_bytes(self) -> Dict[str, Any]:
-        """KV-cache byte accounting by leaf name. Under the ring layout the
+        """KV-cache byte accounting by leaf name (k/v, their int8 scales
+        and slot positions, or an MLA layer's latent ring and its
+        positions). Under the ring layout the
         whole allocation is resident per slot; under paging only *used*
         pages hold live KV — ``kv_resident_bytes`` is what a request
         actually costs, the number the paged-KV bench turns into
@@ -879,7 +898,7 @@ class ServingEngine:
                 n_phys = node.shape[_row_axis(path)]
             elif name == "table":
                 table_bytes += int(node.nbytes)
-            elif name in ("k", "v", "k_scale", "v_scale") \
+            elif name in ("k", "v", "k_scale", "v_scale", "latent") \
                     or (name == "pos" and path != "/pos"):
                 kv_bytes += int(node.nbytes)
 
@@ -952,11 +971,11 @@ class ServingEngine:
                                 for i in range(len(self.slots))], jnp.int32)
             poison = self._poison_array(gen0, n_steps) if use_poison \
                 else jnp.full((len(self.slots),), -1, jnp.int32)
+        args = {"n_steps": n_steps, "rows": len(dec)}
         try:
             self._guard_dispatch("decode", dec)
-            with obs.span("decode_dispatch",
-                          args={"n_steps": n_steps, "rows": len(dec)}):
-                self.state, (toks, bad) = self._loop_fn(
+            with obs.span("decode_dispatch", args=args):
+                self.state, (toks, bad, moe) = self._loop_fn(
                     n_steps, use_mask, stop_w, use_poison)(
                     self._serve_params, self.state,
                     jnp.asarray(self.last_tokens),
@@ -969,8 +988,9 @@ class ServingEngine:
             self._step_end(t_step0, tok0, churn0)
             return done_now
         self.steps += n_steps
+        self._moe_dispatched(moe, args)
         with obs.span("decode_sync"):
-            toks_np, bad_np = np.asarray(toks), np.asarray(bad)
+            toks_np, bad_np = self._moe_fetch(toks, bad)
         with obs.span("collect"):
             done_now = done_now + self._collect(toks_np, bad_np)
         self._step_end(t_step0, tok0, churn0)
@@ -1264,11 +1284,34 @@ class ServingEngine:
             donate = (1,) if jax.default_backend() != "cpu" else ()
 
             def impl(params, state, tokens, lengths):
-                return prefill_chunk(params, cfg, state, {"tokens": tokens},
-                                     lengths)
+                logits, state = prefill_chunk(params, cfg, state,
+                                              {"tokens": tokens}, lengths)
+                moe = state.pop("moe_stats", None)
+                return logits, state, moe
 
             self._prefill_cache[length] = jax.jit(impl, donate_argnums=donate)
         return self._prefill_cache[length]
+
+    def _moe_dispatched(self, moe, args: Dict[str, Any]) -> None:
+        """Hold a dispatch's MoE counts (a device array, or None for a
+        model without experts) until the next sync reads them."""
+        if moe is not None:
+            self._moe_pending.append((moe, args))
+
+    def _moe_fetch(self, *arrays):
+        """Fetch ``arrays`` from the device together with the held MoE
+        counts, in one transfer at a sync the step makes anyway; each
+        dispatch's counts go to the counters and to its span's args
+        (``moe_routed_rows``, ``moe_tile_rows``). Returns the arrays as
+        numpy, in order."""
+        held, self._moe_pending = self._moe_pending, []
+        got, counts = jax.device_get((arrays, [m for m, _ in held]))
+        for (routed, tiles), (_, args) in zip(counts, held):
+            self.moe_routed_rows += int(routed)
+            self.moe_tile_rows += int(tiles)
+            args["moe_routed_rows"], args["moe_tile_rows"] = (int(routed),
+                                                              int(tiles))
+        return got
 
     def _reset_rows(self, mask: np.ndarray, pos0=None):
         if self._reset_jit is None:
@@ -1395,11 +1438,11 @@ class ServingEngine:
             if self._tables_dirty:
                 self._page_maintenance()
         t_pf0 = self._clock()
+        args = {"bucket": length, "rows": len(pf)}
         try:
             self._guard_dispatch("prefill", pf)
-            with obs.span("prefill_dispatch",
-                          args={"bucket": length, "rows": len(pf)}):
-                logits, self.state = self._prefill_fn(length)(
+            with obs.span("prefill_dispatch", args=args):
+                logits, self.state, moe = self._prefill_fn(length)(
                     self._serve_params, self.state, jnp.asarray(tokens),
                     jnp.asarray(lengths))
         except EngineCrash as exc:  # engine death escapes containment
@@ -1408,6 +1451,7 @@ class ServingEngine:
         except Exception as exc:  # cursors untouched: survivors retry as-is
             return self._contain("prefill", pf, exc)
         t_pf1 = self._clock()
+        self._moe_dispatched(moe, args)
         obs.h_prefill_chunk.observe(t_pf1 - t_pf0)
         self.prefill_steps += 1
         self.prefill_tokens += int(lengths.sum())
@@ -1430,7 +1474,7 @@ class ServingEngine:
         # non-finite logits are contained *before* sampling: the offending
         # row retires with "error", finite rows sample from untouched logits
         with obs.span("prefill_sync"):
-            row_ok = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+            row_ok, = self._moe_fetch(jnp.all(jnp.isfinite(logits), axis=-1))
         if self.paged:
             # registration rides the finisher sync that happens anyway — a
             # per-chunk publish would cost a blocking device round-trip on

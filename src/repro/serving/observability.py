@@ -99,6 +99,7 @@ class MetricSpec:
     help: str
     buckets: Optional[Tuple[float, ...]] = None  # histograms only
     paged_only: bool = False     # registered only under kv_layout="paged"
+    moe_only: bool = False       # registered only for a model with experts
 
 
 def _phase_specs() -> Tuple[MetricSpec, ...]:
@@ -198,6 +199,13 @@ SERVING_METRICS: Tuple[MetricSpec, ...] = (
     MetricSpec("serving_page_churn_pages", "histogram", "pages",
                "page alloc+release events per engine step", COUNT_BUCKETS,
                paged_only=True),
+    # ---- routed experts (registered only for a model with MoE layers)
+    MetricSpec("serving_moe_routed_rows_total", "counter", "1",
+               "token-expert assignments computed (top-k x routed tokens "
+               "x MoE layers; none dropped)", moe_only=True),
+    MetricSpec("serving_moe_tile_rows_total", "counter", "1",
+               "rows of the expert tiles the grouped kernel ran, padding "
+               "included", moe_only=True),
     # ---- supervised recovery (v1.5; registered by EngineSupervisor)
     MetricSpec("serving_engine_restarts_total", "counter", "1",
                "engine rebuilds performed by the supervisor"),
@@ -798,6 +806,13 @@ class Observability:
                 reg.gauge(name, poll=fn, unit=SPEC_BY_NAME[name].unit,
                           help=SPEC_BY_NAME[name].help)
             self.h_page_churn = self._hist(reg, "serving_page_churn_pages")
+        if getattr(e, "moe", False):
+            for name, fn in (
+                ("serving_moe_routed_rows_total", lambda: e.moe_routed_rows),
+                ("serving_moe_tile_rows_total", lambda: e.moe_tile_rows),
+            ):
+                reg.counter(name, poll=fn, unit=SPEC_BY_NAME[name].unit,
+                            help=SPEC_BY_NAME[name].help)
 
     @staticmethod
     def _hist(reg: MetricsRegistry, name: str) -> Histogram:

@@ -135,7 +135,7 @@ class TestDryRunSubprocess:
 
 def test_dryrun_results_complete():
     """The committed dry-run cache must cover every runnable cell × mesh
-    (33 × 2) plus the PTQTP-quantized inference variants (23)."""
+    (36 × 2) plus the PTQTP-quantized inference variants (23)."""
     d = REPO / "benchmarks" / "results" / "dryrun"
     if not d.exists():
         pytest.skip("dry-run cache not generated yet")

@@ -24,6 +24,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -289,6 +290,121 @@ def test_chat_programs_keep_the_ring_in_place(one_chip, monkeypatch):
         assert _ring_shaped_ops(compiled, b, cap) == [], name
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < leaf_bytes // 4, (name, temp, leaf_bytes)
+
+
+# deepseek-v2-lite: (n, d) of the routed experts' gate/up and down, and its
+# latent ring: 48 slots x 3072, 16 heads, 512 + 64 lanes, 26 scanned layers
+EXPERTS, N_EXPERTS, TOP_K = [(1408, 2048), (2048, 1408)], 64, 6
+MLA_SLOTS, MLA_HEADS, LATENT, ROPE = 48, 16, 512, 64
+
+
+@pytest.mark.parametrize("n,d", EXPERTS)
+@pytest.mark.parametrize("tokens", [48, 48 * 32])   # a decode step, a chunk
+def test_expert_matmul_compiles(one_chip, monkeypatch, n, d, tokens):
+    """The grouped expert kernel at its real stack and worst-case tile
+    count, reading the planes in their stored layout (no relayout of the
+    stack), named so that the trace reader's ternary mark misses it."""
+    from repro.kernels.expert_matmul import TILE_ROWS, expert_matmul, n_tiles
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tiles = n_tiles(tokens * TOP_K, N_EXPERTS)
+    compiled = _compile(
+        lambda x, t1, t2, a, te, nl: expert_matmul(
+            x, t1, t2, a, te, nl, group_size=G, backend="pallas"),
+        _spec(one_chip, (tiles * TILE_ROWS, d), jnp.bfloat16),
+        _spec(one_chip, (N_EXPERTS, n, d // 4), jnp.uint8),
+        _spec(one_chip, (N_EXPERTS, n, d // 4), jnp.uint8),
+        _spec(one_chip, (N_EXPERTS, n, d // G, 2), jnp.float32),
+        _spec(one_chip, (tiles,), jnp.int32),
+        _spec(one_chip, (1,), jnp.int32))
+    [op] = _kernel_ops(compiled)
+    assert _instruction(op).startswith("expert_matmul")
+    assert "ternary" not in op.lower()
+    moved = [ln for ln in compiled.as_text().splitlines()
+             if re.search(r" (copy|transpose)\(", ln)
+             and re.search(r"u8\[%d," % N_EXPERTS, ln)]
+    assert not moved, moved[:2]
+
+
+@pytest.mark.parametrize("L", [1, 32])
+def test_latent_attention_compiles(one_chip, L):
+    from repro.kernels.chunk_attention.latent import (latent_attention_pallas,
+                                                      latent_tile)
+
+    c, cap = LATENT + ROPE, 3072
+    compiled = _compile(
+        lambda q, kn, ring, pb, pos, lens, layer: latent_attention_pallas(
+            q, kn, ring, pb, pos, lens, layer, scale=0.1147, v_dim=LATENT,
+            tile=latent_tile(cap, L, True), interpret=False),
+        _spec(one_chip, (MLA_SLOTS, L, MLA_HEADS, c), jnp.bfloat16),
+        _spec(one_chip, (MLA_SLOTS, L, c), jnp.bfloat16),
+        _spec(one_chip, (26, MLA_SLOTS, cap, c), jnp.bfloat16),
+        _spec(one_chip, (26, MLA_SLOTS, cap), jnp.int32),
+        _spec(one_chip, (MLA_SLOTS, L), jnp.int32),
+        _spec(one_chip, (MLA_SLOTS,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))
+    [op] = _kernel_ops(compiled)
+    assert _instruction(op).startswith("latent_attention")
+
+
+def test_reasoning_programs_keep_the_latent_ring_in_place(one_chip,
+                                                          monkeypatch):
+    """deepseek-v2-lite's engine programs at published widths (48 slots x
+    3072 latent ring slots, the dense layer and two MoE layers, trit-plane
+    weights): a padded prefill chunk of 32 and the fused decode loop of 2
+    steps run both new kernels, and neither copies, fills or slices the
+    stacked latent ring."""
+    from repro import configs
+    from repro.core.quantize_model import default_predicate, quantize_kernel
+    from repro.models import init_decode_state, init_params, prefill_chunk
+    from repro.serving.engine import _decode_loop
+
+    b, cap, chunk = MLA_SLOTS, 3072, 32
+    cfg = configs.get_config("deepseek-v2-lite").scaled(
+        n_layers=LAYERS + 1, param_dtype="bfloat16",
+        activation_dtype="bfloat16", remat="none")
+    qcfg = ptqtp.PTQTPConfig(group_size=G)
+
+    def planes(path, leaf):       # the quantizer's choice, as shapes only
+        name = "".join(f"/{k.key}" for k in path)
+        if default_predicate(name, np.broadcast_to(np.int8(0), leaf.shape),
+                             G):
+            return jax.eval_shape(lambda: quantize_kernel(
+                jnp.zeros(leaf.shape, leaf.dtype), qcfg))
+        return leaf
+
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    shapes = jax.tree_util.tree_map_with_path(planes, shapes)
+    spec = lambda tree: jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    params = spec(shapes)
+    state = spec(jax.eval_shape(lambda: init_decode_state(cfg, b, cap)))
+    rows = lambda dtype, *shape: _spec(one_chip, (b,) + shape, dtype)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prefill = jax.jit(
+        lambda p, s, t, n: prefill_chunk(p, cfg, s, {"tokens": t}, n),
+        donate_argnums=1).lower(
+            params, state, rows(jnp.int32, chunk), rows(jnp.int32)).compile()
+    decode = jax.jit(functools.partial(
+        _decode_loop, cfg=cfg, n_steps=2, use_mask=False),
+        donate_argnums=1).lower(
+            params, state, rows(jnp.int32), rows(jnp.float32),
+            rows(jnp.bool_), rows(jnp.uint32), rows(jnp.int32),
+            rows(jnp.int32), rows(jnp.float32), rows(jnp.int32, 1),
+            rows(jnp.int32)).compile()
+
+    leaf = state["blocks"]["b0"]["latent"]
+    leaf_bytes = leaf.size * leaf.dtype.itemsize
+    for name, compiled in (("prefill", prefill), ("decode", decode)):
+        names = {_instruction(op).split(".")[0]
+                 for op in _kernel_ops(compiled)}
+        assert {"expert_matmul", "latent_attention",
+                "ternary_matmul"} <= names, (name, names)
+        assert _ring_shaped_ops(compiled, b, cap) == [], name
+        # the routed rows' buffers (a 48 x 32 chunk: 640 tiles of 16 rows)
+        # are the largest temporaries, below one stacked ring leaf
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < leaf_bytes, (name, temp, leaf_bytes)
 
 
 def test_quantizer_fits_hbm_at_lm_head(one_chip):
