@@ -327,17 +327,20 @@ def _deepseek_parity(rng, versus_xla):
     rows = np.concatenate([first[e] + np.arange(c)
                            for e, c in enumerate(counts) if c])
     for n, d in EXPERT_SHAPES:
-        planes = [jnp.stack([pack_trits(jnp.asarray(
-            rng.integers(-1, 2, (n, d)), jnp.int8)) for _ in range(EXPERTS)])
+        # a two-layer stack read at layer 1; layer 0 holds the experts in
+        # reverse order, so reading it shows
+        two = lambda a: jnp.stack([a[::-1], a])
+        planes = [two(jnp.stack([pack_trits(jnp.asarray(
+            rng.integers(-1, 2, (n, d)), jnp.int8)) for _ in range(EXPERTS)]))
             for _ in range(2)]
-        alpha = jnp.asarray(rng.uniform(0.01, 0.03, (EXPERTS, n, d // 128, 2)),
-                            jnp.float32)
+        alpha = two(jnp.asarray(
+            rng.uniform(0.01, 0.03, (EXPERTS, n, d // 128, 2)), jnp.float32))
         x = np.zeros(((live + 2) * TILE_ROWS, d), np.float32)
         x[rows] = rng.standard_normal((len(rows), d))
         x = jnp.asarray(x, jnp.bfloat16)
 
         def op(x, backend):
-            y = expert_matmul(x, *planes, alpha, te, jnp.asarray([live]),
+            y = expert_matmul(x, *planes, alpha, te, jnp.asarray([live]), 1,
                               backend=backend)
             return y[:live * TILE_ROWS]                 # live tiles only
         errs[("expert_matmul", f"{n}x{d} rows={len(rows)}")] = versus_xla(

@@ -41,6 +41,7 @@ def block_n(n: int) -> int:
 
 
 def _xla(x, t1p, t2p, alpha, tile_expert, group_size, tile_rows):
+    """One layer's planes (E, n, d // 4) and scales, every expert's rows."""
     row_expert = jnp.repeat(tile_expert, tile_rows)
 
     def one(e, y):
@@ -51,32 +52,35 @@ def _xla(x, t1p, t2p, alpha, tile_expert, group_size, tile_rows):
     return jax.lax.fori_loop(0, t1p.shape[0], one, y0)
 
 
-def expert_matmul(x, t1p, t2p, alpha, tile_expert, n_live, *,
+def expert_matmul(x, t1p, t2p, alpha, tile_expert, n_live, layer, *,
                   group_size: int = 128, tile_rows: int = TILE_ROWS,
                   backend: str = "auto", out_dtype=None):
     """y[r] = x[r] @ Ŵ_{e(r)}ᵀ, row r of tile t under expert
-    ``tile_expert[t]``. x (R, d) with R a multiple of ``tile_rows``;
-    planes (E, n, d // 4) uint8 (int8 where the caller unpacked them,
-    served by the XLA path); alpha (E, n, d // G, 2); n_live (1,) int32.
-    Returns (R, n)."""
+    ``tile_expert[t]`` of layer ``layer``. x (R, d) with R a multiple of
+    ``tile_rows``; planes the stack of every layer's experts, (L, E, n,
+    d // 4) uint8 (int8 where the caller unpacked them, served by the XLA
+    path), read in place at ``layer`` (an int or an int32 scalar; a
+    single layer's (E, ...) leaves come as a stack of one and layer 0);
+    alpha (L, E, n, d // G, 2); n_live (1,) int32. Returns (R, n)."""
     if backend in (None, "auto"):
         backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     if t1p.dtype != jnp.uint8:
         backend = "xla"
     if backend == "pallas":
-        e, n, db = t1p.shape
+        n, db = t1p.shape[2:]
         # the orientation the TPU stores each stack in (kernel docstring):
         # transposing to it is a bitcast, not a copy
         planes_t = db % 128 != 0
         if planes_t:
-            t1p, t2p = (jnp.swapaxes(t, 1, 2) for t in (t1p, t2p))
-        alpha_t = alpha.transpose(0, 2, 3, 1).reshape(e, -1, n)
+            t1p, t2p = (jnp.swapaxes(t, 2, 3) for t in (t1p, t2p))
+        alpha_t = alpha.transpose(0, 1, 3, 4, 2)
         y = expert_matmul_pallas(
-            x, t1p, t2p, alpha_t, tile_expert, n_live, group_size=group_size,
-            tile_rows=tile_rows, block_n=block_n(n), planes_t=planes_t,
-            interpret=jax.default_backend() != "tpu")
+            x, t1p, t2p, alpha_t, tile_expert, n_live, layer,
+            group_size=group_size, tile_rows=tile_rows, block_n=block_n(n),
+            planes_t=planes_t, interpret=jax.default_backend() != "tpu")
     elif backend in ("xla", "grouped", "ref"):
-        y = _xla(x, t1p, t2p, alpha, tile_expert, group_size, tile_rows)
+        y = _xla(x, t1p[layer], t2p[layer], alpha[layer], tile_expert,
+                 group_size, tile_rows)
     else:
         raise ValueError(f"unknown expert-matmul backend {backend!r}")
     return y.astype(out_dtype) if out_dtype is not None else y

@@ -152,10 +152,10 @@ def _gates(probs, moe: MoEConfig):
     return top_p, top_e
 
 
-def _expert_mm(p, x, tile_expert, n_live, tile_rows):
+def _expert_mm(p, x, tile_expert, n_live, tile_rows, layer):
     """Rows sorted by expert (tiles of ``tile_rows``) times their own
-    expert's matrix: the grouped kernel for trit-planes, a per-tile
-    gather of the stack for float kernels."""
+    expert's matrix of layer ``layer`` of the stack: the grouped kernel
+    for trit-planes, a per-tile gather of the stack for float kernels."""
     from repro.core.quantize_model import QuantizedKernel
     from repro.kernels.expert_matmul import expert_matmul
     from repro.models.common import matmul_backend
@@ -163,10 +163,12 @@ def _expert_mm(p, x, tile_expert, n_live, tile_rows):
     k = p["kernel"]
     if isinstance(k, QuantizedKernel):
         return expert_matmul(x, k.t1p, k.t2p, k.alpha, tile_expert, n_live,
-                             group_size=k.group_size, tile_rows=tile_rows,
-                             backend=matmul_backend(), out_dtype=x.dtype)
+                             layer, group_size=k.group_size,
+                             tile_rows=tile_rows, backend=matmul_backend(),
+                             out_dtype=x.dtype)
     xt = x.reshape(-1, tile_rows, x.shape[-1])
-    y = jnp.einsum("tmd,tdf->tmf", xt, k[tile_expert].astype(x.dtype))
+    y = jnp.einsum("tmd,tdf->tmf", xt,
+                   k[layer, tile_expert].astype(x.dtype))
     return y.reshape(x.shape[0], -1)
 
 
@@ -200,8 +202,13 @@ def expert_tiles(flat_e: jax.Array, e: int, tm: int):
 
 
 def moe_serve(params: Dict[str, Any], x: jax.Array, moe: MoEConfig,
-              mlp_type: str = "swiglu", valid: Optional[jax.Array] = None):
+              mlp_type: str = "swiglu", valid: Optional[jax.Array] = None,
+              layer=None):
     """Serving MoE without capacity: x (T, D) -> ((T, D), stats).
+
+    ``params["experts"]`` is a layer scan's whole stack of routed experts
+    (leaves (L, E, ...)), read in place at ``layer``; with layer None it
+    is one layer's (E, ...) leaves, a stack of one (no copy).
 
     Every valid token (``valid`` (T,) bool; all when None) sends its k
     assignments to their experts, none dropped; invalid tokens send none
@@ -233,9 +240,12 @@ def moe_serve(params: Dict[str, Any], x: jax.Array, moe: MoEConfig,
             jnp.repeat(x, k, axis=0), mode="drop")
     with jax.named_scope("moe_experts"):
         ex = params["experts"]
-        h = (jax.nn.silu(_expert_mm(ex["wg"], xs, tile_expert, n_live, tm))
-             * _expert_mm(ex["wi"], xs, tile_expert, n_live, tm))
-        ys = _expert_mm(ex["wo"], h, tile_expert, n_live, tm)
+        if layer is None:
+            ex, layer = jax.tree.map(lambda a: a[None], ex), 0
+        mm = lambda m, v: _expert_mm(ex[m], v, tile_expert, n_live, tm,
+                                     layer)
+        h = jax.nn.silu(mm("wg", xs)) * mm("wi", xs)
+        ys = mm("wo", h)
     y = jnp.zeros((t, d), jnp.float32)
     for j in range(k):   # top-k order, a token's own: batch-independent
         r = row[:, j]

@@ -140,15 +140,16 @@ def _block_cache_init(kind: str, cfg, batch: int, capacity: int, dtype,
                            dtype, kv_spec=kv_spec)
 
 
-def _ffn_serve(p, cfg, h, valid=None):
+def _ffn_serve(p, cfg, h, valid=None, layer=None):
     """A block's FFN at serving time: (y, MoE stats or None). Experts
     route without capacity (``moe.moe_serve``); ``valid`` (h's leading
-    shape) marks the tokens routed at all."""
+    shape) marks the tokens routed at all; in a scan the routed experts
+    are the whole stack, read at ``layer`` (``_run_blocks``)."""
     if "moe" in p:
         d = h.shape[-1]
         y, stats = moe_mod.moe_serve(
             p["moe"], h.reshape(-1, d), cfg.moe, cfg.mlp_type,
-            None if valid is None else valid.reshape(-1))
+            None if valid is None else valid.reshape(-1), layer=layer)
         return y.reshape(h.shape), stats
     return mlp_mod.mlp_forward(p["mlp"], h, cfg.mlp_type), None
 
@@ -188,7 +189,8 @@ def _block_prefill(kind: str, p, cfg, x, positions, cache, layer=None):
             return_kv=True)
         cache = attn.cache_prefill(cfg, cache, k, v, pos_bs, layer=layer)
     x = x + y
-    y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x, cfg.norm_eps))
+    y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x, cfg.norm_eps),
+                          layer=layer)
     return x + y, cache, stats
 
 
@@ -234,7 +236,7 @@ def _block_decode(kind: str, p, cfg, cache, x_t, pos, active=None,
                                          active=active, layer=layer)
     x_t = x_t + y
     y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x_t, cfg.norm_eps),
-                          active)
+                          active, layer)
     return x_t + y, cache, stats
 
 
@@ -264,8 +266,12 @@ def _run_blocks(cfg, blocks, caches, x, block_fn, *, remat=False):
     on with the layer index: the kernel reads the layer in place and the
     block writes only the slots its tokens land in, so no ring-sized
     slice, fill or copy exists (a ring in ``xs → ys`` would be copied into
-    a fresh stack on every call). Returns (x, caches, summed MoE stats or
-    None).
+    a fresh stack on every call). The routed experts (``moe/experts``) stay
+    out of ``xs`` for the same reason: the expert kernel is a custom call,
+    which XLA cannot fuse a slice into, so each layer's planes would be
+    copied out of the stack; the block gets the whole stack, read-only,
+    and reads it at the layer index. Returns (x, caches, summed MoE stats
+    or None).
     """
     names = [f"b{i}" for i in range(len(cfg.block_pattern))]
     kinds = dict(zip(names, cfg.block_pattern))
@@ -273,18 +279,25 @@ def _run_blocks(cfg, blocks, caches, x, block_fn, *, remat=False):
     rec = {n: caches[n] for n in names if _recurrent(kinds[n])}
     acc = (jnp.zeros((2,), jnp.int32)
            if any(k.endswith("moe") for k in cfg.block_pattern) else None)
+    experts, blocks = {}, dict(blocks)
+    for n in ring:                  # a recurrent block gets no layer index
+        if "experts" in blocks[n].get("moe", {}):
+            moe = dict(blocks[n]["moe"])
+            experts[n] = moe.pop("experts")
+            blocks[n] = {**blocks[n], "moe": moe}
 
     def period_fn(carry, xs):
         x, ring, acc = carry
         period_params, rec_p, layer = xs
         ring, new_rec = dict(ring), {}
         for n in names:
+            p = period_params[n]
+            if n in experts:
+                p = {**p, "moe": {**p["moe"], "experts": experts[n]}}
             if n in ring:
-                x, ring[n], st = block_fn(kinds[n], period_params[n],
-                                          ring[n], x, layer)
+                x, ring[n], st = block_fn(kinds[n], p, ring[n], x, layer)
             else:
-                x, new_rec[n], st = block_fn(kinds[n], period_params[n],
-                                             rec_p[n], x, None)
+                x, new_rec[n], st = block_fn(kinds[n], p, rec_p[n], x, None)
             acc = _add_stats(acc, st)
         return (constrain(x, "hidden"), ring, acc), new_rec
 
@@ -514,7 +527,7 @@ def _block_prefill_chunk(kind: str, p, cfg, x, positions, lengths, valid,
             window=_mixer_window(kind, cfg), layer=layer)
     x = x + y
     y, stats = _ffn_serve(p, cfg, rms_norm(p["mlp_norm"], x, cfg.norm_eps),
-                          valid)
+                          valid, layer)
     return x + y, cache, stats
 
 

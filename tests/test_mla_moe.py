@@ -7,9 +7,10 @@ MoE serving routing (DeepSeek-V2), at test size on the CPU.
   engine path equals its training forward.
 * YaRN: the inverse-frequency ramp bounds and the softmax scale against
   the published formulas, written out here.
-* ``expert_matmul`` (Pallas, interpret mode) against the per-expert
-  ternary oracle, with empty experts, one expert taking every row, and a
-  row count that is not a multiple of the tile.
+* ``expert_matmul`` (Pallas, interpret mode, and XLA) against the
+  per-expert ternary oracle, with empty experts, one expert taking every
+  row, and a row count that is not a multiple of the tile, each read in
+  place at every layer of a three-layer stack.
 * ``moe_serve``: a token's output is bit-equal whatever shares its batch
   (padding rows included), the top-k gates are not renormalized, and
   every valid token's k assignments are computed.
@@ -206,26 +207,32 @@ def _tiles(row_experts, e):
 @pytest.mark.parametrize("backend", ["pallas", "xla"])
 @pytest.mark.parametrize("case", ["spread", "one_expert", "ragged"])
 @pytest.mark.parametrize("n,d", [(256, 384), (384, 256)])
-def test_expert_matmul_matches_per_expert_oracle(backend, case, n, d):
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_expert_matmul_matches_per_expert_oracle(backend, case, n, d, layer):
+    """A stack of three layers' experts, read in place at ``layer``: every
+    row equals the oracle of that layer's own planes."""
     rng = np.random.default_rng(7)
     e = 4
     rows = {"spread": np.array([0, 3, 3, 0, 3] * 7),   # experts 1, 2 empty
             "one_expert": np.full(40, 2),
             "ragged": rng.integers(0, e, 37)}[case]
-    t1, t2, alpha = _stack(rng, e, n, d)
+    t1, t2, alpha = (jnp.stack(a) for a in zip(
+        *(_stack(rng, e, n, d) for _ in range(3))))
     x_rows = rng.standard_normal((len(rows), d)).astype(np.float32)
     slot, te, live, r = _tiles(rows, e)
     x = np.zeros((r, d), np.float32)
     x[slot] = x_rows
     y = np.asarray(expert_matmul(jnp.asarray(x), t1, t2, alpha,
                                  jnp.asarray(te), jnp.asarray([live]),
+                                 jnp.asarray(layer, jnp.int32),
                                  group_size=128, backend=backend))
     for ex in range(e):
         mine = rows == ex
         if not mine.any():
             continue
-        want = ternary_matmul_packed_ref(jnp.asarray(x_rows[mine]), t1[ex],
-                                         t2[ex], alpha[ex], 128)
+        want = ternary_matmul_packed_ref(
+            jnp.asarray(x_rows[mine]), t1[layer, ex], t2[layer, ex],
+            alpha[layer, ex], 128)
         np.testing.assert_allclose(y[slot[mine]], np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 

@@ -296,34 +296,56 @@ def test_chat_programs_keep_the_ring_in_place(one_chip, monkeypatch):
 # latent ring: 48 slots x 3072, 16 heads, 512 + 64 lanes, 26 scanned layers
 EXPERTS, N_EXPERTS, TOP_K = [(1408, 2048), (2048, 1408)], 64, 6
 MLA_SLOTS, MLA_HEADS, LATENT, ROPE = 48, 16, 512, 64
+MOE_LAYERS = 26
+
+# ops that copy, slice or relay out a buffer; none may produce one layer's
+# routed-expert planes or scales ((E, ...), or (1, E, ...) before its
+# bitcast) or a whole stack of them ((L, E, ...))
+_EXPERT_OPS = ("copy", "dynamic-slice", "transpose")
+
+
+def _expert_shaped_ops(compiled):
+    """(opcode, result type) of every HLO instruction, fused ones included,
+    of a kind in ``_EXPERT_OPS`` whose result is expert-shaped: u8 planes
+    or f32 scales with the expert axis first or after one leading axis."""
+    experts = re.compile(r"^(?:u8|f32)\[(?:\d+,)?%d," % N_EXPERTS)
+    found = []
+    for ln in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\S+?)(?:\{[^}]*\})? "
+                     r"([a-z-]+)\(", ln)
+        if m and m.group(2) in _EXPERT_OPS and experts.search(m.group(1)):
+            found.append((m.group(2), m.group(1)))
+    return found
 
 
 @pytest.mark.parametrize("n,d", EXPERTS)
 @pytest.mark.parametrize("tokens", [48, 48 * 32])   # a decode step, a chunk
 def test_expert_matmul_compiles(one_chip, monkeypatch, n, d, tokens):
-    """The grouped expert kernel at its real stack and worst-case tile
-    count, reading the planes in their stored layout (no relayout of the
-    stack), named so that the trace reader's ternary mark misses it."""
+    """The grouped expert kernel at its real 26-layer stack and worst-case
+    tile count, read at a layer index: planes and scales in their stored
+    layout (no relayout or slice of the stack, and no temporaries beyond
+    the activations' in VMEM), named so that the trace reader's ternary
+    mark misses it."""
     from repro.kernels.expert_matmul import TILE_ROWS, expert_matmul, n_tiles
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     tiles = n_tiles(tokens * TOP_K, N_EXPERTS)
+    stack = (MOE_LAYERS, N_EXPERTS, n)
     compiled = _compile(
-        lambda x, t1, t2, a, te, nl: expert_matmul(
-            x, t1, t2, a, te, nl, group_size=G, backend="pallas"),
+        lambda x, t1, t2, a, te, nl, layer: expert_matmul(
+            x, t1, t2, a, te, nl, layer, group_size=G, backend="pallas"),
         _spec(one_chip, (tiles * TILE_ROWS, d), jnp.bfloat16),
-        _spec(one_chip, (N_EXPERTS, n, d // 4), jnp.uint8),
-        _spec(one_chip, (N_EXPERTS, n, d // 4), jnp.uint8),
-        _spec(one_chip, (N_EXPERTS, n, d // G, 2), jnp.float32),
+        _spec(one_chip, stack + (d // 4,), jnp.uint8),
+        _spec(one_chip, stack + (d // 4,), jnp.uint8),
+        _spec(one_chip, stack + (d // G, 2), jnp.float32),
         _spec(one_chip, (tiles,), jnp.int32),
-        _spec(one_chip, (1,), jnp.int32))
+        _spec(one_chip, (1,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))
     [op] = _kernel_ops(compiled)
     assert _instruction(op).startswith("expert_matmul")
     assert "ternary" not in op.lower()
-    moved = [ln for ln in compiled.as_text().splitlines()
-             if re.search(r" (copy|transpose)\(", ln)
-             and re.search(r"u8\[%d," % N_EXPERTS, ln)]
-    assert not moved, moved[:2]
+    assert _expert_shaped_ops(compiled) == []
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("L", [1, 32])
@@ -353,7 +375,8 @@ def test_reasoning_programs_keep_the_latent_ring_in_place(one_chip,
     3072 latent ring slots, the dense layer and two MoE layers, trit-plane
     weights): a padded prefill chunk of 32 and the fused decode loop of 2
     steps run both new kernels, and neither copies, fills or slices the
-    stacked latent ring."""
+    stacked latent ring, nor copies, slices or relays out the stacked
+    routed experts: the expert kernel reads them at the layer index."""
     from repro import configs
     from repro.core.quantize_model import default_predicate, quantize_kernel
     from repro.models import init_decode_state, init_params, prefill_chunk
@@ -401,6 +424,7 @@ def test_reasoning_programs_keep_the_latent_ring_in_place(one_chip,
         assert {"expert_matmul", "latent_attention",
                 "ternary_matmul"} <= names, (name, names)
         assert _ring_shaped_ops(compiled, b, cap) == [], name
+        assert _expert_shaped_ops(compiled) == [], name
         # the routed rows' buffers (a 48 x 32 chunk: 640 tiles of 16 rows)
         # are the largest temporaries, below one stacked ring leaf
         temp = compiled.memory_analysis().temp_size_in_bytes
